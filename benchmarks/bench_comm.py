@@ -226,6 +226,8 @@ def dtype_sweep(n=512, bw=24, K=20, n_shards=DEFAULT_SHARDS, backends=None,
                               json_path, check)
 
     env = dict(os.environ)
+    # a CPU count run: the child must never contend for this process's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_shards} "
         + env.get("XLA_FLAGS", ""))

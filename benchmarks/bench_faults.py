@@ -266,6 +266,8 @@ def run(n=256, bw=8, K=10, n_shards=DEFAULT_SHARDS, backend=DEFAULT_BACKEND,
         return _measure(n, bw, K, n_shards, backend, probs, solve_iters,
                         json_path, do_check)
     env = dict(os.environ)
+    # a CPU count run: the child must never contend for this process's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_shards} "
         + env.get("XLA_FLAGS", ""))

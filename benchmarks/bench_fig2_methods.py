@@ -263,6 +263,8 @@ def run(n: int = None, budget: int = 20, backend: str = DEFAULT_BACKEND,
         return _measure(n, budget, backend, n_shards, json_path, check)
 
     env = dict(os.environ)
+    # a CPU count run: the child must never contend for this process's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_shards} "
         + env.get("XLA_FLAGS", ""))

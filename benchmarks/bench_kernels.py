@@ -12,12 +12,12 @@ and B in {1, 64}, and writes the repo-root ``BENCH_kernels.json`` whose
 top-level ``speedup_sweep_vs_step`` (geometric mean over configs) the CI
 smoke step gates at >= 1.0 via ``--check``.  Each config also records the
 mixed-precision sweep's VMEM footprint model (``vmem_bytes_f32`` /
-``vmem_bytes_bf16``): bf16 blocks + iterate scratch roughly halve the
-footprint, and ``--check`` additionally gates the config-geomean
-``vmem_bf16_capacity_ratio`` at >= 1.8x (wall-time for the bf16 kernel is
-a TPU effect; the capacity ratio is what decides which problems fit under
-the sweep guard — ~2x where structure/iterates dominate, less at eta > 1
-with large B where the deliberately-f32 accumulator is the biggest tile).
+``vmem_bytes_bf16``, tiled bytes with the batch on 128 lanes) and their
+config-geomean ``vmem_bf16_capacity_ratio``.  bf16 halves the x operand,
+the t_k pair and the blocks but not the eta f32 accumulator planes, so
+the ratio sits well under 2x and falls as eta grows; it is recorded, not
+gated (``tests/test_sweep.py`` pins the exact halving of each
+scratch-width term, and wall-time for the bf16 kernel is a TPU effect).
 
     PYTHONPATH=src python -m benchmarks.bench_kernels \
         [--n 500] [--ks 5,20,50] [--etas 1,3] [--batches 1,64] \
@@ -106,8 +106,9 @@ def sweep_vs_step(n=500, Ks=DEFAULT_KS, etas=DEFAULT_ETAS,
                 # footprint model vs f32 (wall-time is a TPU effect the CPU
                 # cannot measure; the footprint ratio is what decides which
                 # problems fit under the sweep guard at all)
-                v32 = ops.cheb_sweep_vmem_bytes(A, A.padded_n, eta, K, B)
-                v16 = ops.cheb_sweep_vmem_bytes(A, A.padded_n, eta, K, B,
+                shape = A.blocks.shape
+                v32 = ops.cheb_sweep_vmem_bytes(shape, A.padded_n, eta, B)
+                v16 = ops.cheb_sweep_vmem_bytes(shape, A.padded_n, eta, B,
                                                 scratch_dtype="bf16")
                 configs[f"K{K}_eta{eta}_B{B}"] = {
                     "per_order_us": us_step,
@@ -129,9 +130,8 @@ def sweep_vs_step(n=500, Ks=DEFAULT_KS, etas=DEFAULT_ETAS,
         "path": "ref",
         "configs": configs,
         "speedup_sweep_vs_step": geomean,
-        # geomean over configs: ~2x where structure/iterates dominate,
-        # less at eta > 1 + large B where the deliberately-f32 accumulator
-        # (eta*B*n*4, numerical-safety floor) is the biggest tile
+        # geomean over configs: under 2x, since the deliberately-f32
+        # accumulator planes (eta * n * B128 * 4) do not shrink
         "vmem_bf16_capacity_ratio": float(
             np.exp(np.mean(np.log(vmem_ratios)))),
     }
@@ -259,12 +259,9 @@ def main():
         assert speedup >= args.check_min, (
             f"sweep geomean speedup {speedup:.3f}x < {args.check_min}x — "
             "the single-launch sweep regresses the per-order path")
-        vr = payload["vmem_bf16_capacity_ratio"]
-        assert vr >= 1.8, (
-            f"bf16-scratch VMEM capacity ratio {vr:.3f}x < 1.8x — the "
-            "mixed-precision sweep no longer roughly doubles the ceiling")
         print(f"# sweep gate OK: {speedup:.2f}x vs per-order, "
-              f"bf16 VMEM capacity {vr:.2f}x", flush=True)
+              f"bf16 VMEM capacity {payload['vmem_bf16_capacity_ratio']:.2f}x "
+              "(recorded)", flush=True)
 
 
 if __name__ == "__main__":

@@ -183,6 +183,8 @@ def run(backends=None, json_dir=".", sizes=None, n_shards=DEFAULT_SHARDS,
                         check=check)
 
     env = dict(os.environ)
+    # a CPU count run: the child must never contend for this process's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_shards} "
         + env.get("XLA_FLAGS", ""))

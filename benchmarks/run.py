@@ -50,6 +50,10 @@ def main() -> None:
                     help="directory for per-backend JSON results")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (bench_comm, bench_faults, bench_fig1_denoising,
                    bench_fig2_methods, bench_kernels, bench_lasso,
                    bench_scaling, bench_serving, bench_throughput)

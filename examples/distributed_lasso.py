@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SENSOR500
 from repro.core import filters, graph, wavelets
 from repro.core.multiplier import graph_multiplier
@@ -40,6 +41,7 @@ def main():
                     "halo with --sharded)")
     ap.add_argument("--iters", type=int, default=150)
     args = ap.parse_args()
+    enable_compile_cache()
 
     p = SENSOR500
     key = jax.random.PRNGKey(11)

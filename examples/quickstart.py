@@ -23,6 +23,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SENSOR500
 from repro.core import filters, graph
 from repro.data.pipeline import graph_signal_batch
@@ -48,6 +49,7 @@ def main():
                     choices=["zero_fill", "hold_last"],
                     help="receiver-side substitute for dropped tiles")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.drop_prob > 0:
         if args.backend not in ("halo", "pallas_halo"):
@@ -56,7 +58,9 @@ def main():
                      "meaningless without links")
         if len(jax.devices()) == 1:
             # one device = one shard = no links to drop; re-exec with
-            # forced host devices so the exchange (and its faults) exist
+            # forced host devices so the exchange (and its faults) exist;
+            # the new image runs on the CPU, off this process's chip
+            os.environ["JAX_PLATFORMS"] = "cpu"
             os.environ["XLA_FLAGS"] = (
                 "--xla_force_host_platform_device_count=8 "
                 + os.environ.get("XLA_FLAGS", ""))

@@ -16,6 +16,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import filters, graph, ssl
 from repro.dist import available_backends
 
@@ -26,6 +27,7 @@ def main():
                     choices=available_backends(),
                     help="execution backend for the label propagation")
     args = ap.parse_args()
+    enable_compile_cache()
     key = jax.random.PRNGKey(3)
     g, labels = graph.two_cluster_graph(key, n_per=25, p_in=0.85, p_out=0.06)
     mask = jnp.zeros(50, bool).at[jnp.array([0, 1, 25, 26])].set(True)

@@ -1,9 +1,1 @@
-"""Distributed graph signal processing via Chebyshev polynomial approximation.
-
-Importing any ``repro`` submodule first installs the jax version-compat
-aliases (see :mod:`repro._compat`) so the modern jax spellings used across
-the codebase work on the pinned container jax as well.
-"""
-from . import _compat  # noqa: F401  (side effect: jax compat aliases)
-
-__all__ = ["_compat"]
+"""Distributed graph signal processing via Chebyshev polynomial approximation."""

@@ -268,40 +268,39 @@ def check_fault_schedule(clean_plan, faulted_plan,
 # ---------------------------------------------------------------------------
 # VMEM budget
 # ---------------------------------------------------------------------------
-def _block_bytes(block_shape, dtype) -> int:
-    n = 1
-    for d in block_shape:
-        if isinstance(d, (int, np.integer)):
-            n *= int(d)
-        # pallas Mapped/Squeezed dims contribute 1 element
-    return n * np.dtype(dtype).itemsize
+def _vmem_aval_bytes(aval) -> int:
+    """Tiled VMEM bytes of one kernel-side ref aval; 0 for SMEM refs."""
+    from ..kernels.layout import tile_bytes
+
+    inner = getattr(aval, "inner_aval", aval)
+    shape = getattr(inner, "shape", None)
+    dtype = getattr(inner, "dtype", None)
+    if shape is None or dtype is None:
+        return 0
+    if "smem" in str(getattr(aval, "memory_space", "")).lower():
+        return 0
+    return tile_bytes(shape, dtype)
 
 
 def pallas_footprint(eqn) -> Dict[str, int]:
     """Recomputed VMEM footprint of one ``pallas_call`` equation.
 
-    Sums the per-grid-step block bytes of every operand/output BlockSpec
-    plus all scratch allocations — the resident VMEM one grid step needs,
-    the same model as `repro.kernels.ops.cheb_sweep_vmem_bytes` but
-    recovered from the *traced* GridMapping rather than the launch
-    parameters, so it audits what was actually staged.
+    Sums the per-grid-step block bytes of every VMEM operand/output
+    BlockSpec plus all VMEM scratch, under the TPU (8, 128) tiling
+    (`kernels.layout.tile_bytes`) — the same model as
+    `repro.kernels.ops.cheb_sweep_vmem_bytes`, but recovered from the
+    *traced* GridMapping rather than the launch parameters, so it audits
+    what was actually staged.  SMEM operands (scalar tables, indices) do
+    not count.
     """
     gm = eqn.params["grid_mapping"]
-    block = 0
-    for bm in gm.block_mappings:
-        sds = bm.array_shape_dtype
-        block += _block_bytes(bm.block_shape, sds.dtype)
+    block = sum(_vmem_aval_bytes(bm.block_aval) for bm in gm.block_mappings)
     scratch = 0
     kernel_jaxpr = eqn.params.get("jaxpr")
     n_scratch = int(getattr(gm, "num_scratch_operands", 0) or 0)
     if kernel_jaxpr is not None and n_scratch:
         for var in kernel_jaxpr.invars[-n_scratch:]:
-            aval = var.aval
-            inner = getattr(aval, "inner_aval", aval)
-            shape = getattr(inner, "shape", None)
-            dtype = getattr(inner, "dtype", None)
-            if shape is not None and dtype is not None:
-                scratch += _block_bytes(shape, dtype)
+            scratch += _vmem_aval_bytes(var.aval)
     return {"block_bytes": block, "scratch_bytes": scratch,
             "total_bytes": block + scratch}
 

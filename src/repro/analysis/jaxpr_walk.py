@@ -37,7 +37,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, Iterable, List, Mapping, Tuple
 
-import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 #: Collective primitives the communication analyses care about (moved here
 #: from `dist.commstats`, which re-exports it for compatibility).
@@ -75,9 +75,9 @@ class EqnContext:
 
 def subjaxprs(value: Any) -> Iterable[Any]:
     """Yield every Jaxpr reachable from one eqn param value."""
-    if isinstance(value, jax.core.Jaxpr):
+    if isinstance(value, Jaxpr):
         yield value
-    elif isinstance(value, jax.core.ClosedJaxpr):
+    elif isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, (list, tuple)):
         for v in value:
@@ -111,7 +111,7 @@ def walk_jaxpr(jaxpr, visit: Callable[[Any, EqnContext], None],
     bodies — so a flat list of visited collectives *is* the static
     collective schedule (what the batch-invariance check compares).
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     if ctx is None:
         ctx = EqnContext()
@@ -161,7 +161,7 @@ def source_location(eqn) -> Tuple[str, int]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             return str(frame.file_name), int(frame.start_line)
     except Exception:

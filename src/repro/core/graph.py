@@ -222,12 +222,23 @@ class BlockELL:
       indices: (n_row_blocks, max_slots) int32 column-block index per slot
       mask:    (n_row_blocks, max_slots) bool slot validity
       n:       logical (unpadded) dimension
+      panels:  (n_row_blocks, bs_r, max_slots * bs_c) the same values as
+               row panels (`block_panels`), the layout the Pallas SpMV
+               streams; laid out from `blocks` when the structure is
+               made (once per plan on one device; once per application,
+               outside the order loop, for a shard's structure made
+               inside shard_map), unless given
     """
 
     blocks: Array
     indices: Array
     mask: Array
     n: int
+    panels: Optional[Array] = None
+
+    def __post_init__(self):
+        if self.panels is None:
+            object.__setattr__(self, "panels", block_panels(self.blocks))
 
     @property
     def block_shape(self) -> Tuple[int, int]:
@@ -255,6 +266,15 @@ class BlockELL:
                         rb * bs_r : (rb + 1) * bs_r, cb * bs_c : (cb + 1) * bs_c
                     ].add(self.blocks[rb, s])
         return out[: self.n, : self.n]
+
+
+def block_panels(blocks):
+    """(..., nrb, slots, br, bc) Block-ELL blocks -> (..., nrb, br,
+    slots * bc) row panels: a row block's slots side by side, so a narrow
+    bc does not pad every block to 128 lanes in HBM and one row block is
+    one MXU product.  Works on numpy and jax arrays."""
+    *lead, nrb, slots, br, bc = blocks.shape
+    return blocks.swapaxes(-3, -2).reshape(*lead, nrb, br, slots * bc)
 
 
 def to_block_ell(
@@ -299,6 +319,7 @@ def to_block_ell(
         indices=jnp.asarray(indices),
         mask=jnp.asarray(mask),
         n=n,
+        panels=jnp.asarray(block_panels(blocks)),
     )
 
 

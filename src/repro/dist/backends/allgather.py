@@ -14,9 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ... import _compat  # noqa: F401
 from ...core import chebyshev as cheb
 from ...kernels.ops import pad_trailing
+from ..sharding import auto_mesh
 from . import register_backend
 from .halo import _sharded, _vspec
 
@@ -117,8 +117,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
     from ..operator import ExecutionPlan
 
     del partition  # allgather shards rows directly from the dense P
-    if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), ("graph",))
+    mesh = auto_mesh(mesh)
     if callable(op.P):
         raise ValueError("allgather backend needs a dense P")
     axis = axis or mesh.axis_names[0]

@@ -34,10 +34,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ... import _compat  # noqa: F401  (jax.shard_map / axis_size on old jax)
 from ...core import chebyshev as cheb
 from ...core.lasso import soft_threshold
 from .. import faults, quantize
+from ..sharding import auto_mesh
 from . import register_backend
 
 shard_map = jax.shard_map
@@ -519,8 +519,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
     quantize.validate_exchange_dtype(exchange_dtype)
     faults.validate_degradation(degradation)
     fault_spec = faults.resolve_fault_spec(fault_spec)
-    if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), ("graph",))
+    mesh = auto_mesh(mesh)
     axis = axis or mesh.axis_names[0]
     n_shards = int(mesh.shape[axis])
     general = resolve_partition_arg(op, partition, n_shards,

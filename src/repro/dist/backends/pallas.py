@@ -106,7 +106,7 @@ def build(op, *, mesh=None, partition=None, block: Tuple[int, int] = (8, 128),
                 else nnz_blocks * 2 * block[0] * block[1]),
             "sweep_dtype": sweep_dtype or "f32",
             "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
-                A, total, op.eta, op.K, scratch_dtype=sweep_dtype),
+                A.blocks.shape, total, op.eta, scratch_dtype=sweep_dtype),
             "sweep_vmem_budget": (ops.DEFAULT_SWEEP_VMEM_BUDGET
                                   if vmem_budget is None else vmem_budget),
         },

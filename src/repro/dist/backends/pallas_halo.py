@@ -55,13 +55,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ... import _compat  # noqa: F401  (jax.shard_map / axis_size on old jax)
 from ...core import chebyshev as cheb
 from ...core import graph as graphmod
 from ...core.lasso import soft_threshold
 from ...kernels import ops
 from .. import faults, quantize
-from ..sharding import ShardingRules, make_rules
+from ..sharding import ShardingRules, auto_mesh, make_rules
 from . import register_backend
 from .halo import (BandedPartition, _coupling_bandwidth, _sharded,
                    pad_signal, partition_banded)
@@ -360,8 +359,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
     quantize.validate_exchange_dtype(exchange_dtype)
     faults.validate_degradation(degradation)
     fault_spec = faults.resolve_fault_spec(fault_spec)
-    if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), ("graph",))
+    mesh = auto_mesh(mesh)
     axis = axis or mesh.axis_names[0]
     n_shards = int(mesh.shape[axis])
     general = resolve_partition_arg(op, partition, n_shards, block=block,
@@ -439,10 +437,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
         "fault_key": faults.fault_key(fault_spec, degradation),
         "sweep_dtype": sweep_dtype or "f32",
         "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
-            graphmod.BlockELL(blocks=parts.blocks[0],
-                              indices=parts.indices[0],
-                              mask=parts.mask[0], n=nl),
-            pnl, op.eta, op.K, scratch_dtype=sweep_dtype),
+            parts.blocks.shape[1:], pnl, op.eta, scratch_dtype=sweep_dtype),
         "halo_bytes_per_apply": pallas_halo_bytes_per_apply(
             parts, op.K, 1, exchange_dtype=exchange_dtype),
         "halo_bytes_per_adjoint": pallas_halo_bytes_per_apply(

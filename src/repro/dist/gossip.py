@@ -54,7 +54,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import _compat  # noqa: F401  (jax.lax.axis_size on old jax)
 from ..core import chebyshev as cheb
 from . import faults
 from . import quantize as q

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from .. import _compat  # noqa: F401  (jax.shard_map / axis_size on old jax)
 from ..core import chebyshev as cheb
 from ..core.lasso import soft_threshold
 from ..core import graph as graphmod
@@ -513,8 +512,11 @@ class GeneralPartition:
     def _order_jnp(self):
         cached = self.__dict__.get("_order_j")
         if cached is None:
-            cached = (jnp.asarray(self.order, jnp.int32),
-                      jnp.asarray(self.inv_order, jnp.int32))
+            # concrete even when first asked for inside a jit trace: a
+            # cached tracer would leak into every later trace
+            with jax.ensure_compile_time_eval():
+                cached = (jnp.asarray(self.order, jnp.int32),
+                          jnp.asarray(self.inv_order, jnp.int32))
             self.__dict__["_order_j"] = cached
         return cached
 
@@ -952,10 +954,8 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             "nnz_blocks": parts.nnz_blocks,
             "sweep_dtype": sweep_dtype or "f32",
             "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
-                graphmod.BlockELL(blocks=parts.blocks[0],
-                                  indices=parts.indices[0],
-                                  mask=parts.mask[0], n=nl),
-                dl, op.eta, op.K, scratch_dtype=sweep_dtype),
+                parts.blocks.shape[1:], dl, op.eta,
+                scratch_dtype=sweep_dtype),
         })
 
     def _pin(x):

@@ -143,6 +143,22 @@ class ShardingRules:
 
 
 @functools.lru_cache(maxsize=None)
+def auto_mesh(mesh=None, axis: str = "graph"):
+    """`mesh` with every axis in Auto mode (None: a 1-D `axis` mesh over
+    all devices).  The sharded graph backends index the vertex axis of
+    shard_map outputs with global gathers, which Explicit-mode axes (the
+    `jax.make_mesh` default) refuse to type."""
+    from jax.sharding import AxisType, Mesh
+
+    if mesh is None:
+        mesh = jax.make_mesh((len(jax.devices()),), (axis,))
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(getattr(mesh, "axis_types", auto)) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+
+
+@functools.lru_cache(maxsize=None)
 def make_rules(mesh, scheme: str = "default") -> ShardingRules:
     """Build the rules for a named scheme bound to `mesh` (cached)."""
     try:

@@ -16,14 +16,15 @@ collectives.
 
 Halo-aware tiling: the kernel is also the per-shard recurrence step of the
 `pallas_halo` backend, where it runs inside a shard_map on each shard's
-local block (size nl, generally *not* a 128 multiple).  The internal
-zero-pad-to-128 below is what makes the same tiling serve both the global
-(padded_n) and the per-shard (nl) iterate shapes.
+local block (size nl, generally *not* a 128 multiple).  The grid is
+``cdiv``-sized over (batch, lane) tiles, so the same tiling serves the
+global (padded_n) and the per-shard (nl) iterate shapes without padding.
 
 Batched iterates ((..., n) under the repo-wide (..., N) signal contract)
-take a second tile path with grid (B, n/blk): one kernel launch advances
-every batch signal one Chebyshev order, keeping the per-order HBM traffic
-at one round-trip for the whole batch.
+flatten to one (B, n) operand whose (bb, blk) tiles keep the batch on
+sublanes: one kernel launch advances every batch signal one Chebyshev
+order, keeping the per-order HBM traffic at one round-trip for the whole
+batch.
 """
 from __future__ import annotations
 
@@ -36,30 +37,43 @@ from jax.experimental import pallas as pl
 Array = jax.Array
 
 _BLOCK = 1024
+#: Elements of one (batch, lane) iterate tile: with the eta accumulator
+#: planes (padded to 8 sublanes) double-buffered in and out, a tile this
+#: size keeps a launch near 5 MiB of VMEM, inside the default scoped limit.
+_TILE_ELEMS = 32 * 1024
+#: Batch rows per tile once the batch outgrows one tile (a multiple of 8,
+#: the f32 sublane count).
+_BATCH_TILE = 64
 
 
 def pick_block(n: int, maximum: int = _BLOCK) -> int:
-    """Largest 128-multiple block size <= maximum that divides n.
-
-    Callers with arbitrary n never see the ValueError: `cheb_step` pads its
-    iterates to a 128 multiple before tiling and strips the padding from the
-    outputs.
-    """
+    """Largest 128-multiple block size <= maximum that divides n."""
     for b in range(min(maximum, n), 127, -128):
         if n % b == 0 and b % 128 == 0:
             return b
     raise ValueError(f"pad n (={n}) to a multiple of 128")
 
 
+def batch_tiles(batch: int, n: int):
+    """(bb, blk) tile of a (batch, n) iterate for the elementwise kernels.
+
+    The whole batch rides one tile up to :data:`_BATCH_TILE` rows (a block
+    dim equal to the array dim is always legal), else 64-row tiles; the
+    lane tile is a 128 multiple sized so bb * blk stays near
+    :data:`_TILE_ELEMS`.  Neither needs to divide the array: the grid is
+    ``cdiv``-sized and Pallas masks the ragged edge tiles.
+    """
+    bb = batch if batch <= _BATCH_TILE else _BATCH_TILE
+    cap = max(128, min(_BLOCK, _TILE_ELEMS // bb // 128 * 128))
+    return bb, min(cap, -(-n // 128) * 128)
+
+
 def _cheb_step_kernel(coef_ref, pt_ref, t1_ref, t2_ref, acc_ref,
                       tk_out_ref, acc_out_ref, *, two_over_alpha):
-    pt = pt_ref[0]                  # (block,) — one signal's tile
-    t1 = t1_ref[0]
-    t2 = t2_ref[0]
-    tk = two_over_alpha * pt - 2.0 * t1 - t2
-    tk_out_ref[0] = tk
-    # coef_ref: (eta, 1) broadcast against tk (block,)
-    acc_out_ref[0] = acc_ref[0] + coef_ref[...] * tk[None, :]
+    tk = two_over_alpha * pt_ref[...] - 2.0 * t1_ref[...] - t2_ref[...]
+    tk_out_ref[...] = tk                                   # (bb, blk)
+    # coef_ref: (eta, 1), broadcast against tk over the (bb, eta, blk) tile
+    acc_out_ref[...] = acc_ref[...] + coef_ref[...][None] * tk[:, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "interpret"))
@@ -75,52 +89,33 @@ def cheb_step(
 ):
     """Returns (t_k, acc + outer(coef, t_k)).
 
-    pt, t_km1, t_km2: (..., n) — any n; iterates are zero-padded to a
-    multiple of the 128 lane width for tiling and the padding is stripped
-    from both outputs.  acc: (..., eta, n); coef: (eta,).  Leading batch
-    dims take the batched tile path (grid over (B, n/blk)) so the whole
-    batch advances one Chebyshev order in a single kernel launch.
+    pt, t_km1, t_km2: (..., n) — any n.  acc: (..., eta, n); coef: (eta,).
+    Leading batch dims flatten to one (B, n) iterate tiled by
+    :func:`batch_tiles`, so the whole batch advances one Chebyshev order
+    in a single kernel launch; the accumulator is updated in place
+    (aliased input/output).
     """
-    n_logical = pt.shape[-1]
-    pad = (-n_logical) % 128
-    if pad:
-        widths = [(0, 0)] * (pt.ndim - 1) + [(0, pad)]
-        pt = jnp.pad(pt, widths)
-        t_km1 = jnp.pad(t_km1, widths)
-        t_km2 = jnp.pad(t_km2, widths)
-        acc = jnp.pad(acc, [(0, 0)] * (acc.ndim - 1) + [(0, pad)])
     n = pt.shape[-1]
     eta = acc.shape[-2]
-    blk = pick_block(n)
-    # one tile path for every rank: leading dims flatten to a batch axis
-    # (B=1 for the classic 1-D iterate), grid over (B, tiles)
     batch_shape = pt.shape[:-1]
     B = pt.size // n
-    pt3 = pt.reshape(B, n)
-    t13 = t_km1.reshape(B, n)
-    t23 = t_km2.reshape(B, n)
-    acc3 = acc.reshape(B, eta, n)
+    bb, blk = batch_tiles(B, n)
+    it = pl.BlockSpec((bb, blk), lambda b, i: (b, i))
+    acc_spec = pl.BlockSpec((bb, eta, blk), lambda b, i: (b, 0, i))
     kernel = functools.partial(_cheb_step_kernel, two_over_alpha=2.0 / alpha)
     tk, acc_out = pl.pallas_call(
         kernel,
-        grid=(B, n // blk),
-        in_specs=[
-            pl.BlockSpec((eta, 1), lambda b, i: (0, 0)),
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, eta, blk), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, eta, blk), lambda b, i: (b, 0, i)),
-        ],
+        grid=(pl.cdiv(B, bb), pl.cdiv(n, blk)),
+        in_specs=[pl.BlockSpec((eta, 1), lambda b, i: (0, 0)),
+                  it, it, it, acc_spec],
+        out_specs=[it, acc_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B, n), pt.dtype),
             jax.ShapeDtypeStruct((B, eta, n), acc.dtype),
         ],
+        input_output_aliases={4: 1},
         interpret=interpret,
-    )(coef[:, None], pt3, t13, t23, acc3)
-    tk = tk[..., :n_logical].reshape(batch_shape + (n_logical,))
-    acc_out = acc_out[..., :n_logical].reshape(batch_shape + (eta, n_logical))
-    return tk, acc_out
+    )(coef[:, None], pt.reshape(B, n), t_km1.reshape(B, n),
+      t_km2.reshape(B, n), acc.reshape(B, eta, n))
+    return (tk.reshape(batch_shape + (n,)),
+            acc_out.reshape(batch_shape + (eta, n)))

@@ -1,6 +1,6 @@
 """Single-launch persistent Chebyshev / Jacobi sweep Pallas kernels.
 
-The per-order hot path (`bcsr_spmv.block_ell_spmv` + `cheb_step.cheb_step`)
+The per-order hot path (`bcsr_spmv.block_ell_spmv_batched` + `cheb_step`)
 launches two kernels per Chebyshev order and round-trips the iterates
 ``t_k, t_{k-1}, acc`` through HBM between them: O(K * (3 + eta) * n)
 iterate traffic for a K-order union.  The sweep kernels here move the
@@ -8,29 +8,30 @@ order loop *inside* the kernel body instead:
 
   * `cheb_sweep` — the full Algorithm-1 recurrence as ONE `pallas_call`.
     A `lax.fori_loop` over the K orders runs in-kernel; ``t_k / t_{k-1}``
-    and the SpMV product live in VMEM scratch across all orders, the
-    accumulator is the (VMEM-resident) output ref, and the Block-ELL
-    blocks + per-order coefficients stream through.  Iterate HBM traffic
-    drops to one load (x) + one store (acc) per application, and kernel
-    launches from 2K to 1.
+    live in a VMEM ping-pong pair across all orders, the accumulator is
+    the (VMEM-resident) output ref, and the Block-ELL blocks stay
+    resident.  Iterate HBM traffic drops to one load (x) + one store
+    (acc) per application, and kernel launches from 2K to 1.
   * `jacobi_sweep` — the Section-V analog: a whole (accelerated-)Jacobi
     solve of ``den(P) x = b`` in one launch, the Horner evaluation of
-    ``den(P) x`` (deg(den) in-kernel SpMVs) and the Eq. (24)/(25) update
-    fused per round, iterates pinned in VMEM for all ``n_iters`` rounds.
+    ``den(P) x`` (deg(den) in-kernel SpMV passes) and the Eq. (24)/(25)
+    update fused into the last pass, iterates pinned in VMEM for all
+    ``n_iters`` rounds.
 
 Everything must fit in VMEM at once — iterates, accumulator, and the
-Block-ELL structure — so the `kernels.ops` dispatchers guard on the
-``(3 + eta) * B * n * 4 bytes + blocks`` footprint and fall back to the
-per-order kernels when the budget is exceeded (see
-``docs/ARCHITECTURE.md`` "Perf accounting" for the full model, and
-`ops.fused_cheb_sweep` / `ops.fused_jacobi_sweep` for the dispatch).
+Block-ELL structure — so the `kernels.ops` dispatchers guard on the tiled
+footprint of :func:`cheb_sweep_buffers` / :func:`jacobi_sweep_buffers`
+and fall back to the per-order kernels when the budget is exceeded (see
+``docs/ARCHITECTURE.md`` "Perf accounting", and `ops.fused_cheb_sweep` /
+`ops.fused_jacobi_sweep` for the dispatch).
 
-Layout notes: coefficients ride in order-major ``(K+1, eta)`` so the
-in-kernel dynamic index is on the leading (sublane) axis; the Block-ELL
-column indices are scalar-prefetched exactly as in `bcsr_spmv`, so the
-in-kernel SpMV gathers ``(B, bc)`` iterate tiles with `pl.ds` dynamic
-slices and hits them with the same ``(B, bc) x (bc, br)``-shaped products
-as the batched per-order kernel.
+Layout: iterates are (n, B) — vertices on sublanes, the batch on the
+128 lanes (padded up to whole vregs).  Each order is one pass over the
+row blocks: a row block's SpMV gathers (bc, B) tiles at bc-aligned
+sublane offsets, then the recurrence and the accumulate run on that
+(br, B) row block and store it at a br-aligned offset, overwriting
+t_{k-2} in place.  The coefficient table, the Jacobi weights and the
+Block-ELL column indices ride flat in SMEM.
 """
 from __future__ import annotations
 
@@ -42,78 +43,121 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .layout import lane_pad, mxu_precision, pad_lanes
+
 Array = jax.Array
 
 #: Sanctioned sweep scratch dtypes.  "bf16" is the mixed-precision mode:
-#: iterates / SpMV product / Block-ELL blocks live in bfloat16 VMEM (half
-#: the pinned footprint, so the ops-layer VMEM guard admits ~2x larger
-#: (B, n, eta) tiles), while every accumulator update runs in f32 — the
-#: MXU products via ``preferred_element_type=jnp.float32`` in
-#: :func:`_spmv_into`, the Chebyshev accumulator by explicit widening
-#: casts before each AXPY.
+#: the x operand, the t_k pair and the Block-ELL blocks live in bfloat16
+#: VMEM (halving those terms of the guarded footprint), while every
+#: update runs in f32 — the MXU products via
+#: ``preferred_element_type=jnp.float32`` in :func:`_row_product`, the
+#: recurrence and the accumulator by explicit widening casts.
 SCRATCH_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 
 
-def _spmv_into(idx_ref, blocks_ref, src_ref, dst_ref, *, nrb: int, slots: int,
-               br: int, bc: int) -> None:
-    """In-kernel Block-ELL SpMV: dst <- A @ src along the last axis.
+def cheb_sweep_buffers(n: int, batch: int, eta: int, blocks_shape,
+                       scratch_dtype, dtype=jnp.float32):
+    """Every VMEM buffer one `cheb_sweep` launch holds: the x operand and
+    the t_k ping-pong pair at the scratch dtype, the eta accumulator
+    planes at `dtype`, and the Block-ELL blocks.  The (K+1, eta) table
+    and the column indices live in SMEM."""
+    return ([((n, batch), scratch_dtype)] * 3
+            + [((n, batch), dtype)] * eta
+            + [(tuple(blocks_shape), scratch_dtype)])
 
-    src_ref / dst_ref: (B, n) VMEM refs (n = nrb * br = ncb * bc).  Each
-    row block accumulates its slot products in registers and stores once;
-    padded slots hold zero blocks, so they contribute nothing.
+
+def jacobi_sweep_buffers(n: int, batch: int, n_den: int, blocks_shape,
+                         scratch_dtype, dtype=jnp.float32):
+    """Every VMEM buffer one `jacobi_sweep` launch holds: b, D^{-1} and
+    the x ping-pong pair (which x0 enters through, aliased) at `dtype`,
+    the Horner pair at the scratch dtype (only when deg(den) >= 2), and
+    the Block-ELL blocks."""
+    n_h = 2 if n_den > 2 else 0
+    return ([((n, batch), dtype)] * 4 + [((n, batch), scratch_dtype)] * n_h
+            + [(tuple(blocks_shape), scratch_dtype)])
+
+
+def _row_product(idx_ref, blocks_ref, src, rb, *, slots: int, bc: int):
+    """Row block `rb` of the in-kernel Block-ELL SpMV ``A @ src``.
+
+    src: a (n, B) VMEM ref view, vertices on sublanes and the batch on
+    lanes.  Every slot gathers the (bc, B) tile of its scalar-prefetched
+    column block at a bc-aligned sublane offset and hits it with one
+    (br, bc) x (bc, B) MXU product; padded slots hold zero blocks.
+    Returns the (br, B) f32 product.
     """
-    B = src_ref.shape[0]
+    br = blocks_ref.shape[2]
 
-    def row_body(rb, _):
-        def slot_body(s, acc_row):
-            col = idx_ref[rb, s]
-            blk = blocks_ref[rb, s]                      # (br, bc)
-            xb = pl.load(src_ref, (slice(None), pl.ds(col * bc, bc)))
-            return acc_row + jax.lax.dot_general(
-                xb, blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        acc_row = jax.lax.fori_loop(0, slots, slot_body,
-                                    jnp.zeros((B, br), jnp.float32))
-        pl.store(dst_ref, (slice(None), pl.ds(rb * br, br)),
-                 acc_row.astype(dst_ref.dtype))
+    def slot_body(s, acc):
+        start = pl.multiple_of(idx_ref[rb * slots + s] * bc, bc)
+        blk = blocks_ref[rb, s]
+        tile = src[pl.ds(start, bc), :].astype(blk.dtype)
+        return acc + jnp.dot(blk, tile, preferred_element_type=jnp.float32,
+                             precision=mxu_precision(blk.dtype))
+
+    return jax.lax.fori_loop(0, slots, slot_body,
+                             jnp.zeros((br, src.shape[-1]), jnp.float32))
+
+
+def _rows(rb, br: int):
+    return pl.ds(pl.multiple_of(rb * br, br), br)
+
+
+def _cheb_sweep_kernel(idx_ref, coef_ref, blocks_ref, x_ref, acc_ref, t_ref,
+                       *, K: int, alpha: float, nrb: int, slots: int,
+                       br: int, bc: int):
+    """t_ref: (2, n, B) ping-pong pair; t_ref[k % 2] holds t_k.  Each row
+    block of order k reads P t_{k-1} (the other half, whole) and overwrites
+    t_{k-2} with t_k row by row: t_{k-2} is only read at its own rows."""
+    eta = acc_ref.shape[0]
+    prod = functools.partial(_row_product, idx_ref, blocks_ref,
+                             slots=slots, bc=bc)
+    f32 = jnp.float32
+    out_dt = acc_ref.dtype
+
+    # orders 0 and 1: acc = (c_0/2) x + c_1 t_1,  t_1 = (P x)/alpha - x
+    def first_row(rb, _):
+        r = _rows(rb, br)
+        x = x_ref[r, :].astype(f32)
+        t1 = prod(x_ref, rb) / alpha - x
+        t_ref[0, r, :] = x_ref[r, :]
+        t_ref[1, r, :] = t1.astype(t_ref.dtype)
+        for j in range(eta):
+            acc_ref[j, r, :] = (0.5 * coef_ref[j] * x
+                                + coef_ref[eta + j] * t1).astype(out_dt)
         return 0
 
-    jax.lax.fori_loop(0, nrb, row_body, 0)
+    jax.lax.fori_loop(0, nrb, first_row, 0)
 
-
-def _cheb_sweep_kernel(idx_ref, coef_ref, blocks_ref, x_ref, acc_ref,
-                       t1_ref, t0_ref, pt_ref, *, K: int, alpha: float,
-                       nrb: int, slots: int, br: int, bc: int):
-    spmv = functools.partial(_spmv_into, idx_ref, blocks_ref,
-                             nrb=nrb, slots=slots, br=br, bc=bc)
-    # iterates (and x) may live in bf16 scratch; the accumulator output is
-    # always the wide dtype, so every AXPY widens its term explicitly —
-    # mixed precision by convert_element_type, never implicit promotion
-    out_dt = acc_ref.dtype
-    x = x_ref[...]                                       # (B, n)
-    # order 0: acc = (c_0 / 2) x                         (Algorithm 1 line 4)
-    acc_ref[...] = (0.5 * coef_ref[0][None, :, None]
-                    * x.astype(out_dt)[:, None, :])
-    # order 1: t_1 = (P x) / alpha - x                   (line 5)
-    spmv(x_ref, pt_ref)
-    t1 = pt_ref[...] / alpha - x
-    t0_ref[...] = x
-    t1_ref[...] = t1
-    acc_ref[...] = acc_ref[...] + (coef_ref[1][None, :, None]
-                                   * t1.astype(out_dt)[:, None, :])
-
+    # t_k = (2/alpha) P t_{k-1} - 2 t_{k-1} - t_{k-2}      (Algorithm 1 l.9)
     def order_body(k, _):
-        # t_k = (2/alpha) P t_{k-1} - 2 t_{k-1} - t_{k-2}     (line 9)
-        spmv(t1_ref, pt_ref)
-        tk = ((2.0 / alpha) * pt_ref[...] - 2.0 * t1_ref[...] - t0_ref[...])
-        ck = pl.load(coef_ref, (pl.ds(k, 1), slice(None)))[0]     # (eta,)
-        acc_ref[...] = acc_ref[...] + (ck[None, :, None]
-                                       * tk.astype(out_dt)[:, None, :])
-        t0_ref[...] = t1_ref[...]
-        t1_ref[...] = tk
+        cur = k % 2
+        prev = 1 - cur
+
+        def row(rb, _):
+            r = _rows(rb, br)
+            tk = ((2.0 / alpha) * prod(t_ref.at[prev], rb)
+                  - 2.0 * t_ref[prev, r, :].astype(f32)
+                  - t_ref[cur, r, :].astype(f32))
+            t_ref[cur, r, :] = tk.astype(t_ref.dtype)
+            for j in range(eta):
+                acc_ref[j, r, :] = acc_ref[j, r, :] + (
+                    coef_ref[k * eta + j] * tk).astype(out_dt)
+            return 0
+
+        jax.lax.fori_loop(0, nrb, row, 0)
         return 0
 
     jax.lax.fori_loop(2, K + 1, order_body, 0)
+
+
+def _vmem():
+    return pl.BlockSpec(memory_space=pltpu.VMEM)
+
+
+def _smem():
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 @functools.partial(jax.jit,
@@ -130,7 +174,8 @@ def cheb_sweep(
 ) -> Array:
     """Full K-order shifted-Chebyshev recurrence in one kernel launch.
 
-    blocks/indices: Block-ELL structure as in `bcsr_spmv.block_ell_spmv`.
+    blocks/indices: Block-ELL structure as in
+    `bcsr_spmv.block_ell_spmv_batched`.
     x: (..., n) with n the Block-ELL padded size (n = nrb * br); leading
     batch dims flatten to one VMEM-resident (B, n) iterate that advances
     through all orders without touching HBM.  coeffs: (eta, K+1), K >= 1.
@@ -154,9 +199,13 @@ def cheb_sweep(
     eta, K1 = coeffs.shape
     batch_shape = x.shape[:-1]
     B = x.size // n
-    x2 = x.reshape(B, n).astype(sdt)
-    blocks_k = blocks.astype(sdt)
-    coefsT = jnp.asarray(coeffs, x.dtype).T              # (K+1, eta)
+    Bp = lane_pad(B)
+    # (B, n) -> (n, Bp): vertices on sublanes, so row-block stores land at
+    # br-aligned sublane offsets and column gathers at bc-aligned ones
+    xt = pad_lanes(x.reshape(B, n).T, Bp).astype(sdt)
+    # SMEM pads 2-D arrays to 128-word rows: the (K+1, eta) order-major
+    # table and the (nrb, slots) indices ride flat
+    coef_flat = jnp.asarray(coeffs, jnp.float32).T.reshape(-1)
 
     kernel = functools.partial(
         _cheb_sweep_kernel, K=K1 - 1, alpha=float(alpha),
@@ -164,54 +213,63 @@ def cheb_sweep(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec((K1, eta), lambda g, idx: (0, 0)),
-            pl.BlockSpec((nrb, slots, br, bc), lambda g, idx: (0, 0, 0, 0)),
-            pl.BlockSpec((B, n), lambda g, idx: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((B, eta, n), lambda g, idx: (0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((B, n), sdt),                     # t_{k-1}
-            pltpu.VMEM((B, n), sdt),                     # t_{k-2}
-            pltpu.VMEM((B, n), sdt),                     # P t_{k-1}
-        ],
+        in_specs=[_smem(), _vmem(), _vmem()],
+        out_specs=_vmem(),
+        scratch_shapes=[pltpu.VMEM((2, n, Bp), sdt)],    # t_k ping-pong
     )
     acc = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, eta, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((eta, n, Bp), x.dtype),
         interpret=interpret,
-    )(indices, coefsT, blocks_k, x2)
-    return acc.reshape(batch_shape + (eta, n))
+    )(indices.reshape(-1), coef_flat, blocks.astype(sdt), xt)
+    return acc[..., :B].transpose(2, 0, 1).reshape(batch_shape + (eta, n))
 
 
 def _jacobi_sweep_kernel(idx_ref, ws_ref, blocks_ref, b_ref, invd_ref,
-                         x0_ref, x_ref, xp_ref, q_ref, h_ref,
-                         *, n_iters: int, den: Tuple[float, ...],
-                         nrb: int, slots: int, br: int, bc: int):
-    spmv = functools.partial(_spmv_into, idx_ref, blocks_ref,
-                             nrb=nrb, slots=slots, br=br, bc=bc)
-    # xp / q / h may live in bf16 scratch; the x iterate (the output ref)
-    # and the b / D^{-1} operands stay wide, with explicit casts at every
-    # scratch boundary so the update itself runs at full precision
-    x_ref[...] = x0_ref[...]
-    xp_ref[...] = x0_ref[...].astype(xp_ref.dtype)
+                         x0_ref, x_ref, *h_refs, n_iters: int,
+                         den: Tuple[float, ...], nrb: int, slots: int,
+                         br: int, bc: int):
+    """x_ref: (2, n, B) ping-pong output, aliased to x0_ref = (x0, x0);
+    x_ref[t % 2] holds x_t and the round's last Horner stage overwrites
+    x_{t-1} with x_{t+1} row by row.  h_refs: the (2, n, B) Horner pair,
+    present when deg(den) >= 2."""
+    del x0_ref  # the same buffer as x_ref
+    prod = functools.partial(_row_product, idx_ref, blocks_ref,
+                             slots=slots, bc=bc)
+    deg = len(den) - 1
+    wide = x_ref.dtype
 
     def round_body(t, _):
-        x = x_ref[...]
-        # den(P) x by Horner: deg(den) in-kernel SpMVs, coefficients baked
-        # in as compile-time constants (the rational spec is host-known)
-        h_ref[...] = (den[-1] * x).astype(h_ref.dtype)
-        for c in den[-2::-1]:
-            spmv(h_ref, q_ref)
-            h_ref[...] = q_ref[...] + (c * x).astype(h_ref.dtype)
-        wt = pl.load(ws_ref, (pl.ds(t, 1), slice(None)))[0]       # (2,)
-        # x_next = w (x + D^{-1}(b - den(P) x)) - s x_prev   (Eq. (24)/(25))
-        x_next = (wt[0] * (x + invd_ref[...]
-                           * (b_ref[...] - h_ref[...].astype(x.dtype)))
-                  - wt[1] * xp_ref[...].astype(x.dtype))
-        xp_ref[...] = x.astype(xp_ref.dtype)
-        x_ref[...] = x_next
+        cur = t % 2
+        nxt = 1 - cur
+        w = ws_ref[2 * t]
+        s = ws_ref[2 * t + 1]
+        # den(P) x by Horner, one row-blocked SpMV pass per degree:
+        # h_1 = den[d] P x + den[d-1] x,  h_i = P h_{i-1} + den[d-i] x;
+        # the last pass fuses the Eq. (24)/(25) update
+        # x_next = w (x + D^{-1}(b - den(P) x)) - s x_prev
+        for i in range(1, max(deg, 1) + 1):
+            def stage_row(rb, _, i=i):
+                r = _rows(rb, br)
+                x = x_ref[cur, r, :]
+                if deg == 0:
+                    h = den[0] * x
+                elif i == 1:
+                    h = (den[deg] * prod(x_ref.at[cur], rb)
+                         + den[deg - 1] * x)
+                else:
+                    h = (prod(h_refs[0].at[i % 2], rb).astype(wide)
+                         + den[deg - i] * x)
+                if i < deg:
+                    h_refs[0][(i + 1) % 2, r, :] = h.astype(h_refs[0].dtype)
+                else:
+                    x_ref[nxt, r, :] = (
+                        w * (x + invd_ref[r, :] * (b_ref[r, :] - h))
+                        - s * x_ref[nxt, r, :])
+                return 0
+
+            jax.lax.fori_loop(0, nrb, stage_row, 0)
         return 0
 
     jax.lax.fori_loop(0, n_iters, round_body, 0)
@@ -257,37 +315,34 @@ def jacobi_sweep(
     B = 1
     for d in batch_shape:
         B *= d
-    b2 = jnp.broadcast_to(b, full).reshape(B, n)
-    invd2 = jnp.broadcast_to(inv_d, full).reshape(B, n)
-    x02 = jnp.broadcast_to(x0, full).reshape(B, n)
-    ws = jnp.asarray(weights, b.dtype)
+
+    Bp = lane_pad(B)
+
+    def lanes(a):  # (..., n) -> (n, Bp), the batch on lanes
+        return pad_lanes(jnp.broadcast_to(a, full).reshape(B, n).T, Bp)
+
+    ws = jnp.asarray(weights, jnp.float32)
     n_iters = ws.shape[0]
+    ws = ws.reshape(-1)                                  # (w_0, s_0, w_1, ...)
+    den = tuple(float(c) for c in den)
+    n_h = 2 if len(den) > 2 else 0
 
     kernel = functools.partial(
-        _jacobi_sweep_kernel, n_iters=n_iters,
-        den=tuple(float(c) for c in den),
+        _jacobi_sweep_kernel, n_iters=n_iters, den=den,
         nrb=nrb, slots=slots, br=br, bc=bc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n_iters, 2), lambda g, idx: (0, 0)),
-            pl.BlockSpec((nrb, slots, br, bc), lambda g, idx: (0, 0, 0, 0)),
-            pl.BlockSpec((B, n), lambda g, idx: (0, 0)),
-            pl.BlockSpec((B, n), lambda g, idx: (0, 0)),
-            pl.BlockSpec((B, n), lambda g, idx: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((B, n), lambda g, idx: (0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((B, n), sdt),                     # x_prev
-            pltpu.VMEM((B, n), sdt),                     # SpMV product
-            pltpu.VMEM((B, n), sdt),                     # Horner accumulator
-        ],
+        in_specs=[_smem()] + [_vmem()] * 4,
+        out_specs=_vmem(),
+        scratch_shapes=[pltpu.VMEM((2, n, Bp), sdt)] * (n_h // 2),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, n), b2.dtype),
+        out_shape=jax.ShapeDtypeStruct((2, n, Bp), b.dtype),
+        input_output_aliases={5: 0},
         interpret=interpret,
-    )(indices, ws, blocks.astype(sdt), b2, invd2, x02)
-    return out.reshape(full)
+    )(indices.reshape(-1), ws, blocks.astype(sdt), lanes(b), lanes(inv_d),
+      jnp.stack([lanes(x0).astype(b.dtype)] * 2))
+    return out[n_iters % 2, :, :B].T.reshape(full)

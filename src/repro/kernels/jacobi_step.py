@@ -14,12 +14,12 @@ single-launch `cheb_sweep.jacobi_sweep` kernel subsumes this one when the
 whole solve fits in VMEM; this per-round kernel is the guard fallback and
 the collective-bearing sharded path.
 
-Tiling mirrors `cheb_step`: iterates are zero-padded to the 128 lane width,
-leading batch dims flatten into a grid axis (one kernel launch advances the
-whole (..., n) batch one round), and per-shard sizes (the `pallas_halo`
-backend runs this inside shard_map) need not be 128 multiples.  The
-acceleration weights (w, s) vary per iteration and ride in as a (2, 1)
-operand so the kernel stays trace-once inside `lax.scan`.
+Tiling mirrors `cheb_step` (`cheb_step.batch_tiles`): leading batch dims
+flatten onto sublanes of (bb, blk) tiles over a ``cdiv`` grid (one kernel
+launch advances the whole (..., n) batch one round), and per-shard sizes
+(the `pallas_halo` backend runs this inside shard_map) need not be 128
+multiples.  The acceleration weights (w, s) vary per iteration and ride in
+SMEM so the kernel stays trace-once inside `lax.scan`.
 """
 from __future__ import annotations
 
@@ -28,22 +28,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .cheb_step import pick_block
+from .cheb_step import batch_tiles
 
 Array = jax.Array
 
 
 def _jacobi_step_kernel(ws_ref, qx_ref, x_ref, xp_ref, y_ref, invd_ref,
                         out_ref):
-    w = ws_ref[0, 0]
-    s = ws_ref[1, 0]
-    qx = qx_ref[0]
-    x = x_ref[0]
-    xp = xp_ref[0]
-    y = y_ref[0]
-    invd = invd_ref[0]
-    out_ref[0] = w * (x + invd * (y - qx)) - s * xp
+    w = ws_ref[0]
+    s = ws_ref[1]
+    out_ref[...] = (w * (x_ref[...] + invd_ref[...]
+                         * (y_ref[...] - qx_ref[...])) - s * xp_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -60,55 +57,38 @@ def jacobi_step(
 ) -> Array:
     """Returns ``w * (x + inv_d * (y - qx)) - s * x_prev``.
 
-    qx/x/x_prev: (..., n) — any n (padded to a 128 multiple internally,
-    padding stripped from the output).  y: (..., n) with the same batch
-    shape or unbatched (n,); inv_d likewise (typically the (n,) reciprocal
-    diagonal — zero on padded/virtual rows, which keeps them exactly zero).
-    w/s: scalars, traced or concrete (the accelerated weights change per
-    scan step).
+    qx/x/x_prev: (..., n) — any n.  y: (..., n) with the same batch shape
+    or unbatched (n,); inv_d likewise (typically the (n,) reciprocal
+    diagonal — zero on padded/virtual rows, which keeps them exactly
+    zero).  w/s: scalars, traced or concrete (the accelerated weights
+    change per scan step); they ride in SMEM.
     """
-    n_logical = x.shape[-1]
-    batch_shape = x.shape[:-1]
-    qx, x, x_prev = (jnp.broadcast_to(a, x.shape) for a in (qx, x, x_prev))
-    pad = (-n_logical) % 128
-    if pad:
-        widths = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
-        qx = jnp.pad(qx, widths)
-        x = jnp.pad(x, widths)
-        x_prev = jnp.pad(x_prev, widths)
-        y = jnp.pad(y, [(0, 0)] * (y.ndim - 1) + [(0, pad)])
-        inv_d = jnp.pad(inv_d, [(0, 0)] * (inv_d.ndim - 1) + [(0, pad)])
     n = x.shape[-1]
-    blk = pick_block(n)
+    batch_shape = x.shape[:-1]
+    qx, x_prev = (jnp.broadcast_to(a, x.shape) for a in (qx, x_prev))
     B = x.size // n
-    x3 = x.reshape(B, n)
-    qx3 = qx.reshape(B, n)
-    xp3 = x_prev.reshape(B, n)
-    # y / inv_d keep their own (possibly unbatched) row count; the index
-    # map pins row 0 when they are shared across the batch
-    y2 = y.reshape(-1, n)
-    if y2.shape[0] not in (1, B):
-        y2 = jnp.broadcast_to(y, x.shape).reshape(B, n)
-    d2 = inv_d.reshape(-1, n)
-    if d2.shape[0] not in (1, B):
-        d2 = jnp.broadcast_to(inv_d, x.shape).reshape(B, n)
-    y_row = (lambda b, i: (b, i)) if y2.shape[0] == B else (lambda b, i: (0, i))
-    d_row = (lambda b, i: (b, i)) if d2.shape[0] == B else (lambda b, i: (0, i))
-    ws = jnp.stack([jnp.asarray(w, x.dtype),
-                    jnp.asarray(s, x.dtype)]).reshape(2, 1)
+    bb, blk = batch_tiles(B, n)
+    it = pl.BlockSpec((bb, blk), lambda b, i: (b, i))
+    # y / inv_d keep their own (possibly unbatched) row count; a shared
+    # row is one (1, blk) tile pinned at row 0
+    shared = pl.BlockSpec((1, blk), lambda b, i: (0, i))
+
+    def operand(a):
+        a2 = a.reshape(-1, n)
+        if a2.shape[0] == 1:
+            return a2, shared
+        return jnp.broadcast_to(a, x.shape).reshape(B, n), it
+
+    y2, y_spec = operand(y)
+    d2, d_spec = operand(inv_d)
+    ws = jnp.stack([jnp.asarray(w, x.dtype), jnp.asarray(s, x.dtype)])
     out = pl.pallas_call(
         _jacobi_step_kernel,
-        grid=(B, n // blk),
-        in_specs=[
-            pl.BlockSpec((2, 1), lambda b, i: (0, 0)),
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, blk), lambda b, i: (b, i)),
-            pl.BlockSpec((1, blk), y_row),
-            pl.BlockSpec((1, blk), d_row),
-        ],
-        out_specs=pl.BlockSpec((1, blk), lambda b, i: (b, i)),
+        grid=(pl.cdiv(B, bb), pl.cdiv(n, blk)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  it, it, it, y_spec, d_spec],
+        out_specs=it,
         out_shape=jax.ShapeDtypeStruct((B, n), x.dtype),
         interpret=interpret,
-    )(ws, qx3, x3, xp3, y2, d2)
-    return out[..., :n_logical].reshape(batch_shape + (n_logical,))
+    )(ws, qx.reshape(B, n), x.reshape(B, n), x_prev.reshape(B, n), y2, d2)
+    return out.reshape(batch_shape + (n,))

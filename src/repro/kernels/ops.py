@@ -1,22 +1,22 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 This module is the single dispatch point between the Pallas TPU kernels
-(`bcsr_spmv.block_ell_spmv`, `cheb_step.cheb_step`, ...) and their pure-jnp
-oracles in :mod:`repro.kernels.ref`.  Everything above it — the `pallas`
+(`bcsr_spmv.block_ell_spmv_batched`, `cheb_step.cheb_step`, ...) and
+their pure-jnp oracles in :mod:`repro.kernels.ref`.  Everything above it — the `pallas`
 and `pallas_halo` execution backends, the benchmarks, the tests — calls
 these wrappers and never touches `pallas_call` directly.
 
-Dispatch policy: on TPU the Pallas kernels run natively; on CPU (this
-container) `use_pallas=True` runs them under interpret=True (the kernel body
-executed in Python — used by the kernel test sweeps), and the default takes
-the pure-jnp reference path so smoke tests and benchmarks stay fast.
+Dispatch policy: on TPU the Pallas kernels run natively (the default);
+on CPU `use_pallas=True` runs them under interpret=True (the kernel body
+executed in Python — used by the kernel test sweeps), and the default
+takes the pure-jnp reference path so tests and CPU count runs stay fast.
 
 Sharded use: :func:`fused_cheb_recurrence` is the matvec-generic form of the
 fused recurrence.  The `pallas_halo` backend calls it *inside* a shard_map
 with a halo-exchanging matvec over the per-shard Block-ELL tiles, so the
 same fused Chebyshev-step kernel serves both the single-device and the
 sharded hot path (per-shard sizes need not be 128-multiples — `cheb_step`
-pads its tiles internally).
+tiles a ``cdiv`` grid and masks the ragged edge).
 
 Single-launch sweep dispatch: when the matvec is a *local* Block-ELL
 product (no collectives — the `pallas` backend always, `pallas_halo` on a
@@ -39,19 +39,22 @@ import numpy as np
 
 from ..core.graph import BlockELL
 from . import ref
-from .bcsr_spmv import block_ell_spmv, block_ell_spmv_batched
+from .bcsr_spmv import SMEM_INDEX_WORDS, block_ell_spmv_batched
 from .cheb_step import cheb_step
-from .cheb_sweep import cheb_sweep, jacobi_sweep
+from .cheb_sweep import (SCRATCH_DTYPES, cheb_sweep, cheb_sweep_buffers,
+                         jacobi_sweep, jacobi_sweep_buffers)
 from .jacobi_step import jacobi_step
 from .flash_attention import flash_attention as _flash
+from .layout import vmem_bytes
 from .soft_threshold import ista_shrink
 
 Array = jax.Array
 
 logger = logging.getLogger(__name__)
 
-#: Default VMEM budget for the single-launch sweep kernels: ~16 MB/core on
-#: current TPUs, minus headroom for the compiler's own buffers.
+#: Default VMEM budget for the single-launch sweep kernels: the compiler's
+#: default scoped-VMEM limit (16 MiB on v5e) minus headroom for its own
+#: buffers, against the tiled footprint of `cheb_sweep_vmem_bytes`.
 DEFAULT_SWEEP_VMEM_BUDGET = 12 * 1024 * 1024
 
 
@@ -79,45 +82,43 @@ def spmv(A: BlockELL, x: Array, use_pallas: Optional[bool] = None) -> Array:
     """
     use, interp = _resolve(use_pallas)
     if use:
-        if x.ndim > 1:
-            return block_ell_spmv_batched(A.blocks, A.indices, x,
-                                          interpret=interp)
-        return block_ell_spmv(A.blocks, A.indices, x, interpret=interp)
+        return block_ell_spmv_batched(A.panels, A.indices, x,
+                                      interpret=interp)
     return ref.block_ell_spmv_ref(A.blocks, A.indices, x)
 
 
-def _scratch_itemsize(scratch_dtype: Optional[str], itemsize: int) -> int:
-    """Bytes per element of the sweep scratch/operand buffers: 2 under the
-    bf16 mixed-precision mode, the wide `itemsize` otherwise."""
-    if scratch_dtype is not None and scratch_dtype not in ("f32", "bf16"):
+def _scratch_dtype(scratch_dtype: Optional[str], itemsize: int):
+    """The sweep scratch/operand dtype: bfloat16 under the mixed-precision
+    mode, the wide float of `itemsize` bytes otherwise."""
+    if scratch_dtype is not None and scratch_dtype not in SCRATCH_DTYPES:
         raise ValueError(f"scratch_dtype must be 'f32' or 'bf16', "
                          f"got {scratch_dtype!r}")
-    return 2 if scratch_dtype == "bf16" else itemsize
+    return (jnp.bfloat16 if scratch_dtype == "bf16"
+            else np.dtype(f"float{8 * itemsize}"))
 
 
-def cheb_sweep_vmem_bytes(A: BlockELL, n: int, eta: int, K: int,
+def cheb_sweep_vmem_bytes(blocks_shape, n: int, eta: int,
                           batch: int = 1, itemsize: int = 4,
                           scratch_dtype: Optional[str] = None) -> int:
-    """VMEM footprint model for one `cheb_sweep` launch.
+    """VMEM footprint model for one `cheb_sweep` launch over Block-ELL
+    blocks of shape `blocks_shape` (nrb, slots, br, bc).
 
-    Everything the persistent sweep pins on-chip at once, recomputed from
-    the *actual* buffer dtypes: the three iterates (t_{k-1}, t_{k-2},
-    P t_{k-1}), the x operand and the streamed Block-ELL blocks at the
-    scratch width (2 B under ``scratch_dtype="bf16"``, else `itemsize`);
-    the (B, eta, n) accumulator output, the (K+1, eta) coefficient table
-    at the wide `itemsize`; int32 column indices.  At f32 this is the
-    original ``(3 + eta) * B * n * 4B`` (+ B*n for x) model; under bf16
-    the guarded footprint roughly halves, so `ops.fused_cheb_sweep`'s
-    budget comparison admits ~2x larger (B, n, eta) tiles on the
-    single-launch path.
+    Every VMEM buffer the persistent sweep holds at once
+    (`cheb_sweep.cheb_sweep_buffers`), at its actual dtype and under the
+    TPU tiling (`layout.tile_bytes`): the x operand and the t_k
+    ping-pong pair at the scratch width (2 B under
+    ``scratch_dtype="bf16"``), the eta accumulator planes at `itemsize`,
+    and the Block-ELL blocks at the scratch width.  The batch rides the
+    128 lanes, so B = 1 and B = 128 cost the same.  The budget sits under
+    the compiler's default scoped-VMEM limit, so every launch the guard
+    admits compiles (``tests/test_tpu_compile.py`` compiles one at the
+    limit).  The degree does not enter: the coefficient table and column
+    indices live in SMEM.
     """
-    sb = _scratch_itemsize(scratch_dtype, itemsize)
-    iterates = 3 * batch * n * sb + eta * batch * n * itemsize
-    operand = batch * n * sb
-    structure = (int(np.prod(A.blocks.shape)) * sb
-                 + int(np.prod(A.indices.shape)) * 4)
-    table = (K + 1) * eta * itemsize
-    return iterates + operand + structure + table
+    wide = np.dtype(f"float{8 * itemsize}")
+    return vmem_bytes(cheb_sweep_buffers(
+        n, batch, eta, blocks_shape, _scratch_dtype(scratch_dtype, itemsize),
+        wide))
 
 
 def _per_order_cheb(A: BlockELL, x: Array, coeffs: Array, lmax: float,
@@ -152,7 +153,7 @@ def fused_cheb_sweep(
 
     scratch_dtype: None/"f32" or "bf16" — the mixed-precision kernel mode
     (`cheb_sweep.SCRATCH_DTYPES`); the footprint guard recomputes from
-    the actual scratch width, so bf16 admits ~2x larger tiles.
+    the actual scratch width.
     """
     use, interp = _resolve(use_pallas)
     sdt = scratch_dtype or "f32"
@@ -165,14 +166,16 @@ def fused_cheb_sweep(
             else int(vmem_budget)
         n = x.shape[-1]
         batch = max(1, x.size // n)
-        need = cheb_sweep_vmem_bytes(A, n, eta, K, batch, scratch_dtype=sdt)
+        need = cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, batch,
+                                     scratch_dtype=sdt)
         if K < 2:
             return _per_order_cheb(A, x, c, lmax, use_pallas)
-        if need > budget:
+        if need > budget or A.indices.size > SMEM_INDEX_WORDS:
             logger.info(
-                "cheb_sweep: VMEM footprint %d B exceeds budget %d B "
-                "(n=%d, eta=%d, K=%d, B=%d) — falling back to the "
-                "per-order cheb_step path", need, budget, n, eta, K, batch)
+                "cheb_sweep: VMEM footprint %d B exceeds budget %d B or "
+                "%d column indices exceed SMEM (n=%d, eta=%d, K=%d, B=%d) "
+                "— falling back to the per-order cheb_step path", need,
+                budget, A.indices.size, n, eta, K, batch)
             return _per_order_cheb(A, x, c, lmax, use_pallas)
         return cheb_sweep(A.blocks, A.indices, x, c, alpha=alpha,
                           interpret=interp, scratch_dtype=sdt)
@@ -204,8 +207,8 @@ def fused_cheb_recurrence(
     sweep budget, and an optional ``mv.sweep_dtype`` ("bf16") selects the
     mixed-precision scratch mode of `cheb_sweep`.
 
-    x: (..., n) — any n; `cheb_step` pads its tiles to the 128 lane width
-    internally, and leading batch dims take the batched tile paths (one
+    x: (..., n) — any n (`cheb_step` masks its ragged edge tile), and
+    leading batch dims take the batched tile paths (one
     structure sweep / kernel launch per order for the whole batch).
     coeffs: (eta, K+1) (or (K+1,), treated as eta=1).
     Returns (..., eta, n).
@@ -282,8 +285,8 @@ def fused_cheb_apply(
     """Phi_tilde x with the SpMV + fused-step kernels (Algorithm 1 on TPU).
 
     x: (..., padded_n), last axis matching A's Block-ELL padding; any
-    padded_n works (the fused step kernel pads its tiles to the 128 lane
-    width internally) and leading batch dims share the K structure sweeps.
+    padded_n works (the fused step kernel masks its ragged edge tile)
+    and leading batch dims share the K structure sweeps.
     Returns (..., eta, padded_n).
 
     sweep: None (default) routes through the single-launch
@@ -353,20 +356,19 @@ def jacobi_update(
     return ref.jacobi_step_ref(qx, x, x_prev, y, inv_d, w=w, s=s)
 
 
-def jacobi_sweep_vmem_bytes(A: BlockELL, n: int, batch: int = 1,
-                            itemsize: int = 4,
+def jacobi_sweep_vmem_bytes(blocks_shape, n: int, n_den: int,
+                            batch: int = 1, itemsize: int = 4,
                             scratch_dtype: Optional[str] = None) -> int:
-    """VMEM footprint model for one `jacobi_sweep` launch, from the actual
-    buffer dtypes: x_prev, the SpMV product and the Horner accumulator at
-    the scratch width (2 B under ``scratch_dtype="bf16"``), the x iterate,
-    b and D^{-1} (three wide (B, n) buffers) plus the streamed Block-ELL
-    structure at the scratch width.  At f32 this is the original
-    six-buffer model."""
-    sb = _scratch_itemsize(scratch_dtype, itemsize)
-    buffers = 3 * batch * n * sb + 3 * batch * n * itemsize
-    structure = (int(np.prod(A.blocks.shape)) * sb
-                 + int(np.prod(A.indices.shape)) * 4)
-    return buffers + structure
+    """VMEM footprint model for one `jacobi_sweep` launch over Block-ELL
+    blocks of shape `blocks_shape` (`cheb_sweep.jacobi_sweep_buffers`,
+    tiled): b, D^{-1}, x0 and the x
+    ping-pong pair at `itemsize`, the Horner pair at the scratch width
+    when deg(den) >= 2 (``n_den`` = len(den)), and the Block-ELL blocks
+    at the scratch width."""
+    wide = np.dtype(f"float{8 * itemsize}")
+    return vmem_bytes(jacobi_sweep_buffers(
+        n, batch, n_den, blocks_shape,
+        _scratch_dtype(scratch_dtype, itemsize), wide))
 
 
 def fused_jacobi_sweep(
@@ -411,12 +413,14 @@ def fused_jacobi_sweep(
         budget = DEFAULT_SWEEP_VMEM_BUDGET if vmem_budget is None \
             else int(vmem_budget)
         batch = max(1, bp.size // total)
-        need = jacobi_sweep_vmem_bytes(A, total, batch, scratch_dtype=sdt)
-        if need > budget:
+        need = jacobi_sweep_vmem_bytes(A.blocks.shape, total, len(den), batch,
+                                       scratch_dtype=sdt)
+        if need > budget or A.indices.size > SMEM_INDEX_WORDS:
             logger.info(
-                "jacobi_sweep: VMEM footprint %d B exceeds budget %d B "
-                "(n=%d, B=%d) — falling back to the per-round jacobi_step "
-                "path", need, budget, total, batch)
+                "jacobi_sweep: VMEM footprint %d B exceeds budget %d B or "
+                "%d column indices exceed SMEM (n=%d, B=%d) — falling back "
+                "to the per-round jacobi_step path", need, budget,
+                A.indices.size, total, batch)
         else:
             out = jacobi_sweep(A.blocks, A.indices, bp, invdp, ws, x0p,
                                den=den, interpret=interp, scratch_dtype=sdt)

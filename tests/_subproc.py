@@ -9,6 +9,8 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 def run_payload(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     env = dict(os.environ)
+    # CPU payloads: the child must never contend for this process's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_devices} "
         + env.get("XLA_FLAGS", "")

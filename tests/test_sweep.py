@@ -104,14 +104,21 @@ def test_vmem_guard_falls_back_and_logs(block_ell_500, caplog):
 
 
 def test_vmem_footprint_model(block_ell_500):
-    """The guard formula counts the (3 + eta) iterates + operand + the
-    streamed structure."""
+    """The guard formula counts the x operand, the t_k ping-pong pair and
+    the eta accumulator planes — (n, B) with the batch on 128 lanes —
+    plus the resident Block-ELL blocks, all under the (8, 128) tiling."""
     g, A = block_ell_500
-    n, eta, K, B = A.padded_n, 3, 10, 4
-    got = ops.cheb_sweep_vmem_bytes(A, n, eta, K, B)
-    iterates = (3 + eta) * B * n * 4 + B * n * 4
-    structure = A.blocks.size * 4 + A.indices.size * 4 + (K + 1) * eta * 4
+    n, eta, B = A.padded_n, 3, 4
+    got = ops.cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, B)
+    iterates = (3 + eta) * 128 * n * 4
+    structure = A.blocks.size * 4          # (8, 128) blocks: no tile padding
     assert got == iterates + structure
+    # the batch rides the lanes: B = 1 and B = 128 cost the same, B = 129
+    # takes a second vreg column
+    assert ops.cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, 1) == got
+    assert ops.cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, 128) == got
+    assert (ops.cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, 129)
+            == 2 * iterates + structure)
 
 
 # ---------------------------------------------------------------------------
@@ -157,24 +164,31 @@ def test_jacobi_sweep_bf16_scratch_matches_ref(block_ell_500):
 
 
 def test_vmem_footprint_model_bf16_and_measured_ratio(block_ell_500):
-    """bf16 scratch halves the iterate/operand/structure terms (the f32
-    coef table and int32 indices stay) — the model ratio is >= 1.8, and
-    the TRACED pallas_call footprint (analysis.pallas_footprint, recovered
-    from BlockSpecs + scratch avals) shrinks by >= 1.8x too, so the
-    VMEM-guard ceiling genuinely roughly doubles."""
+    """bf16 scratch halves exactly the scratch-width terms — the x
+    operand, the t_k pair and the resident blocks (the eta f32
+    accumulator planes stay) — and the TRACED pallas_call footprint
+    (analysis.pallas_footprint, recovered from BlockSpecs + scratch avals)
+    equals the model in both modes, so the guard admits what is staged."""
     from repro import analysis as A_
     g, A = block_ell_500
     n, eta, K, B = A.padded_n, 3, 10, 4
-    got16 = ops.cheb_sweep_vmem_bytes(A, n, eta, K, B, scratch_dtype="bf16")
-    iterates = 3 * B * n * 2 + eta * B * n * 4 + B * n * 2  # acc stays f32
-    structure = A.blocks.size * 2 + A.indices.size * 4 + (K + 1) * eta * 4
+    got16 = ops.cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, B,
+                                      scratch_dtype="bf16")
+    iterates = 3 * 128 * n * 2 + eta * 128 * n * 4  # acc stays f32
+    structure = A.blocks.size * 2
     assert got16 == iterates + structure
-    got32 = ops.cheb_sweep_vmem_bytes(A, n, eta, K, B)
-    assert got32 / got16 >= 1.8
-    # jacobi model too
-    j32 = ops.jacobi_sweep_vmem_bytes(A, n, batch=B)
-    j16 = ops.jacobi_sweep_vmem_bytes(A, n, batch=B, scratch_dtype="bf16")
-    assert j32 / j16 >= 1.8
+    got32 = ops.cheb_sweep_vmem_bytes(A.blocks.shape, n, eta, B)
+    assert got32 - got16 == (3 * 128 * n + A.blocks.size) * 2
+    # jacobi model: b, D^-1 and the x pair stay wide; the Horner pair
+    # (deg(den) >= 2) and the blocks halve
+    j32 = ops.jacobi_sweep_vmem_bytes(A.blocks.shape, n, 2, batch=B)
+    j16 = ops.jacobi_sweep_vmem_bytes(A.blocks.shape, n, 2, batch=B,
+                                      scratch_dtype="bf16")
+    assert j32 - j16 == A.blocks.size * 2
+    j32 = ops.jacobi_sweep_vmem_bytes(A.blocks.shape, n, 4, batch=B)
+    j16 = ops.jacobi_sweep_vmem_bytes(A.blocks.shape, n, 4, batch=B,
+                                      scratch_dtype="bf16")
+    assert j32 - j16 == (2 * 128 * n + A.blocks.size) * 2
 
     coeffs = jnp.ones((eta, K + 1), jnp.float32)
     x = jax.ShapeDtypeStruct((B, n), np.float32)
@@ -188,7 +202,8 @@ def test_vmem_footprint_model_bf16_and_measured_ratio(block_ell_500):
         assert len(eqns) == 1
         return A_.pallas_footprint(eqns[0])["total_bytes"]
 
-    assert traced_bytes("f32") / traced_bytes("bf16") >= 1.8
+    assert traced_bytes("f32") == got32
+    assert traced_bytes("bf16") == got16
 
 
 def test_sweep_dtype_tag_survives_with_budget(op120):

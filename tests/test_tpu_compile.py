@@ -1,0 +1,120 @@
+"""Compile-only checks of the main-path kernels for a TPU v5e chip.
+
+Each test lowers and compiles one Pallas kernel at a real width against
+the described (not attached) ``v5e:2x2`` topology, so whatever Mosaic
+refuses — a slice off the (8, 128) tiling, too much VMEM or SMEM — fails
+here without a chip.  Nothing runs.  The topology is described inside a
+module fixture (never at import: only one process may load the TPU
+library), and the tests skip when it cannot be described.
+"""
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ops
+from repro.kernels.bcsr_spmv import block_ell_spmv_batched
+from repro.kernels.cheb_step import cheb_step
+from repro.kernels.cheb_sweep import cheb_sweep, jacobi_sweep
+from repro.kernels.jacobi_step import jacobi_step
+
+ETA, K = 4, 20            # SGWT J=3 bank at the paper's order
+SWEEP_SLOTS = 4           # sensor-graph Block-ELL (8, 128): 4 slots
+N_CHIP = 1_000_000        # community graph, one chip, Block-ELL (8, 8)
+CHIP_SLOTS = 9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no TPU lib"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology compile cannot be read back from the
+    # persistent cache, so keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _ell(n, slots=SWEEP_SLOTS, block=(8, 128)):
+    br, bc = block
+    return types.SimpleNamespace(
+        blocks=jax.ShapeDtypeStruct((n // br, slots, br, bc), jnp.float32),
+        panels=jax.ShapeDtypeStruct((n // br, br, slots * bc), jnp.float32),
+        indices=jax.ShapeDtypeStruct((n // br, slots), jnp.int32))
+
+
+def _largest_admitted(need):
+    """Largest 128-multiple n whose modelled footprint the VMEM guard
+    admits."""
+    budget = ops.DEFAULT_SWEEP_VMEM_BUDGET
+    n = 128
+    while need(n + 128) <= budget:
+        n += 128
+    assert need(n) <= budget < need(n + 128)
+    return n
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_cheb_sweep_compiles_at_guard_limit(one_chip, batch):
+    n = _largest_admitted(lambda m: ops.cheb_sweep_vmem_bytes(
+        _ell(m).blocks.shape, m, ETA, batch))
+    A = _ell(n)
+    _compile(lambda b, i, x, c: cheb_sweep(b, i, x, c, alpha=2.0),
+             _spec(one_chip, A.blocks.shape),
+             _spec(one_chip, A.indices.shape, jnp.int32),
+             _spec(one_chip, (batch, n)), _spec(one_chip, (ETA, K + 1)))
+
+
+@pytest.mark.parametrize("den", [(0.5, 1.0), (0.5, 1.0, 0.25)])
+@pytest.mark.parametrize("batch", [1, 64])
+def test_jacobi_sweep_compiles_at_guard_limit(one_chip, batch, den):
+    n = _largest_admitted(lambda m: ops.jacobi_sweep_vmem_bytes(
+        _ell(m).blocks.shape, m, len(den), batch))
+    A = _ell(n)
+    _compile(lambda b, i, y, d, w, x0: jacobi_sweep(b, i, y, d, w, x0,
+                                                    den=den),
+             _spec(one_chip, A.blocks.shape),
+             _spec(one_chip, A.indices.shape, jnp.int32),
+             _spec(one_chip, (batch, n)), _spec(one_chip, (n,)),
+             _spec(one_chip, (30, 2)), _spec(one_chip, (batch, n)))
+
+
+def test_block_ell_spmv_batched_compiles_at_chip_scale(one_chip):
+    A = _ell(N_CHIP, CHIP_SLOTS, (8, 8))
+    _compile(lambda p, i, x: block_ell_spmv_batched(p, i, x),
+             _spec(one_chip, A.panels.shape),
+             _spec(one_chip, A.indices.shape, jnp.int32),
+             _spec(one_chip, (64, N_CHIP)))
+
+
+def test_cheb_step_compiles_at_chip_scale(one_chip):
+    it = _spec(one_chip, (64, N_CHIP))
+    _compile(lambda *a: cheb_step(*a, alpha=2.0), it, it, it,
+             _spec(one_chip, (64, ETA, N_CHIP)), _spec(one_chip, (ETA,)))
+
+
+def test_jacobi_step_compiles_at_chip_scale(one_chip):
+    it = _spec(one_chip, (64, N_CHIP))
+    _compile(lambda q, x, xp, y, d: jacobi_step(q, x, xp, y, d, w=1.0,
+                                                 s=0.0),
+             it, it, it, it, _spec(one_chip, (N_CHIP,)))

@@ -196,6 +196,8 @@ def jaxpr_findings(shards: int) -> List:
 def _spawn_sharded(shards: int, allowlist_path: str) -> int:
     """Run the jaxpr layer at `shards` host devices in a subprocess."""
     env = dict(os.environ)
+    # a CPU trace: the child must never contend for this process's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={shards} "
         + env.get("XLA_FLAGS", ""))
