@@ -277,6 +277,15 @@ def block_panels(blocks):
     return blocks.swapaxes(-3, -2).reshape(*lead, nrb, br, slots * bc)
 
 
+def block_ell_fill(blocks) -> float:
+    """Stored non-zeros over the entries the Block-ELL layout holds
+    (nrb * slots * br * bc, summed over shards): the share of the bytes
+    an SpMV streams that carry a matrix entry."""
+    count = np.count_nonzero if isinstance(blocks, np.ndarray) \
+        else jnp.count_nonzero
+    return int(count(blocks)) / blocks.size
+
+
 def to_block_ell(
     M: np.ndarray, block_shape: Tuple[int, int] = (8, 128)
 ) -> BlockELL:

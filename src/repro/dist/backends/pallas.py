@@ -69,12 +69,12 @@ def build(op, *, mesh=None, partition=None, block: Tuple[int, int] = (8, 128),
                                    use_pallas=use_pallas, sweep=sweep,
                                    vmem_budget=vmem_budget,
                                    scratch_dtype=sweep_dtype)
-        return out[..., :n]
+        return ops.crop(out, n)
 
     def apply_adjoint(a: Array) -> Array:
         out = cheb.cheb_apply_adjoint(_mv, _pad(a),
                                       jnp.asarray(coeffs, a.dtype), lmax)
-        return out[..., :n]
+        return ops.crop(out, n)
 
     def apply_gram(f: Array) -> Array:
         d = cheb.gram_coeffs(coeffs)
@@ -82,7 +82,7 @@ def build(op, *, mesh=None, partition=None, block: Tuple[int, int] = (8, 128),
                                    use_pallas=use_pallas, sweep=sweep,
                                    vmem_budget=vmem_budget,
                                    scratch_dtype=sweep_dtype)
-        return out[..., 0, :n]
+        return ops.crop(out[..., 0, :], n)
 
     def matvec_runner(fn, signals, consts=()):
         # run the iteration body against the Block-ELL SpMV on the padded
@@ -90,7 +90,7 @@ def build(op, *, mesh=None, partition=None, block: Tuple[int, int] = (8, 128),
         padded = tuple(ops.pad_trailing(jnp.asarray(s), total)
                        for s in signals)
         outs = fn(_mv, *padded, *consts)
-        return jax.tree.map(lambda o: o[..., :n], outs)
+        return jax.tree.map(lambda o: ops.crop(o, n), outs)
 
     nnz_blocks = int(np.asarray(A.mask).sum()) if hasattr(A, "mask") else None
     return ExecutionPlan(
@@ -104,6 +104,7 @@ def build(op, *, mesh=None, partition=None, block: Tuple[int, int] = (8, 128),
             "flops_per_matvec": (
                 None if nnz_blocks is None
                 else nnz_blocks * 2 * block[0] * block[1]),
+            "blockell_fill": graphmod.block_ell_fill(A.blocks),
             "sweep_dtype": sweep_dtype or "f32",
             "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
                 A.blocks.shape, total, op.eta, scratch_dtype=sweep_dtype),

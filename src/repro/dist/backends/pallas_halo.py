@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ... import obs
 from ...core import chebyshev as cheb
 from ...core import graph as graphmod
 from ...core.lasso import soft_threshold
@@ -219,54 +220,59 @@ def _halo_row_matvec(local_A: graphmod.BlockELL, left: Array, right: Array,
     use_ef = dt == "int8" and error_feedback and size > 1
 
     def _run(x, state):
-        head = x[..., :h]
-        tail = x[..., nl - h:nl]
+        with obs.scope("exchange"):
+            head = x[..., :h]
+            tail = x[..., nl - h:nl]
         if inj is not None:
             k, carried, ef_state = state
         else:
             ef_state = state
         if size > 1:
-            if ef_state is None:
-                wire_tail = quantize.encode(tail, dt)
-                wire_head = quantize.encode(head, dt)
-                new_ef = None
-            else:
-                r_tail, r_head = ef_state
-                wire_tail, r_tail = quantize.ef_encode(tail, r_tail, dt)
-                wire_head, r_head = quantize.ef_encode(head, r_head, dt)
-                new_ef = (r_tail, r_head)
-            # (1) boundary-row exchange: shard s receives s-1's tail (read
-            # by `left`) and s+1's head (read by `right`); one ppermute
-            # per direction keeps measured rounds at the paper's 2K|E|
-            from_left = jax.lax.ppermute(
-                wire_tail, axis,
-                perm=[(i, (i + 1) % size) for i in range(size)])
-            from_right = jax.lax.ppermute(
-                wire_head, axis,
-                perm=[(i, (i - 1) % size) for i in range(size)])
+            with obs.scope("exchange"):
+                if ef_state is None:
+                    wire_tail = quantize.encode(tail, dt)
+                    wire_head = quantize.encode(head, dt)
+                    new_ef = None
+                else:
+                    r_tail, r_head = ef_state
+                    wire_tail, r_tail = quantize.ef_encode(tail, r_tail, dt)
+                    wire_head, r_head = quantize.ef_encode(head, r_head, dt)
+                    new_ef = (r_tail, r_head)
+                # (1) boundary-row exchange: shard s receives s-1's tail
+                # (read by `left`) and s+1's head (read by `right`); one
+                # ppermute per direction keeps measured rounds at the
+                # paper's 2K|E|
+                from_left = jax.lax.ppermute(
+                    wire_tail, axis,
+                    perm=[(i, (i + 1) % size) for i in range(size)])
+                from_right = jax.lax.ppermute(
+                    wire_head, axis,
+                    perm=[(i, (i - 1) % size) for i in range(size)])
             # (2) interior Block-ELL SpMV — overlaps the exchange
             y = ops.spmv(local_A, x, use_pallas=use_pallas)
             # (3) decode + boundary couplings on arrival; injected faults
             # perturb only what the receiver consumes — the wire traffic
             # above is already committed
-            if inj is not None:
-                from_left = inj.wire(from_left, k, 0, dt)
-                from_right = inj.wire(from_right, k, 1, dt)
-            from_left = quantize.decode(from_left, dt, x.dtype)
-            from_right = quantize.decode(from_right, dt, x.dtype)
-            if inj is not None:
-                c_l, c_r = carried
-                from_left, c_l = inj.recv(from_left, c_l, k, 0)
-                from_right, c_r = inj.recv(from_right, c_r, k, 1)
-                new_state = (k + 1, (c_l, c_r), new_ef)
-            else:
-                new_state = new_ef
+            with obs.scope("exchange"):
+                if inj is not None:
+                    from_left = inj.wire(from_left, k, 0, dt)
+                    from_right = inj.wire(from_right, k, 1, dt)
+                from_left = quantize.decode(from_left, dt, x.dtype)
+                from_right = quantize.decode(from_right, dt, x.dtype)
+                if inj is not None:
+                    c_l, c_r = carried
+                    from_left, c_l = inj.recv(from_left, c_l, k, 0)
+                    from_right, c_r = inj.recv(from_right, c_r, k, 1)
+                    new_state = (k + 1, (c_l, c_r), new_ef)
+                else:
+                    new_state = new_ef
         else:
             from_left, from_right = tail, head
             new_state = state
             y = ops.spmv(local_A, x, use_pallas=use_pallas)
-        y = y + jnp.einsum("ij,...j->...i", left, from_left)
-        y = y + jnp.einsum("ij,...j->...i", right, from_right)
+        with obs.scope("exchange"):
+            y = y + jnp.einsum("ij,...j->...i", left, from_left)
+            y = y + jnp.einsum("ij,...j->...i", right, from_right)
         return y, new_state
 
     def mv(x, state=None):
@@ -430,6 +436,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
         "exchange_collectives_per_round": 2,
         "block": block,
         "nnz_blocks": parts.nnz_blocks,
+        "blockell_fill": graphmod.block_ell_fill(parts.blocks),
         "exchange_dtype": exchange_dtype,
         "error_feedback": bool(error_feedback),
         "fault_spec": faults.spec_info(fault_spec),
@@ -474,38 +481,38 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
             mv = _mk_mv(blocks, indices, mask, left, right)
             out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, pnl),
                                             c, lmax, use_pallas=use_pallas)
-            return out[..., :nl]
+            return ops.crop(out, nl)
 
         c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
         out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
                        _sig_spec(f.ndim + 1))(*mats,
                                               pad_signal(f, parts),
                                               c2)
-        return out[..., :n]
+        return ops.crop(out, n)
 
     def apply_adjoint(a: Array) -> Array:
         def run(blocks, indices, mask, left, right, al, c):
             mv = _mk_mv(blocks, indices, mask, left, right)
             out = cheb.cheb_apply_adjoint(mv, ops.pad_trailing(al, pnl),
                                           c, lmax)
-            return out[..., :nl]
+            return ops.crop(out, nl)
 
         c = jnp.asarray(coeffs, a.dtype)
-        return _sharded(run, mesh, mat_specs + (_sig_spec(a.ndim), P()),
-                        _sig_spec(a.ndim - 1))(*mats, pad_signal(a, parts),
-                                               c)[..., :n]
+        out = _sharded(run, mesh, mat_specs + (_sig_spec(a.ndim), P()),
+                       _sig_spec(a.ndim - 1))(*mats, pad_signal(a, parts), c)
+        return ops.crop(out, n)
 
     def apply_gram(f: Array) -> Array:
         def run(blocks, indices, mask, left, right, xl, d):
             mv = _mk_mv(blocks, indices, mask, left, right)
             out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, pnl),
                                             d, lmax, use_pallas=use_pallas)
-            return out[..., 0, :nl]
+            return ops.crop(out[..., 0, :], nl)
 
         d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
-        return _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
-                        _sig_spec(f.ndim))(*mats, pad_signal(f, parts),
-                                           d)[..., :n]
+        out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
+                       _sig_spec(f.ndim))(*mats, pad_signal(f, parts), d)
+        return ops.crop(out, n)
 
     def solve_lasso(y, mu, gamma, n_iters):
         from ...core.lasso import LassoResult, _mu_threshold
@@ -568,11 +575,11 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
             mv = _mk_mv(blocks, indices, mask, left, right)
             sigs = tuple(ops.pad_trailing(s, pnl) for s in rest[:len(padded)])
             outs = fn(mv, *sigs, *rest[len(padded):])
-            return jax.tree.map(lambda o: o[..., :nl], outs)
+            return jax.tree.map(lambda o: ops.crop(o, nl), outs)
 
         outs = _sharded(run, mesh, in_specs, out_specs)(
             *mats, *padded, *consts)
-        return jax.tree.map(lambda o: o[..., :n], outs)
+        return jax.tree.map(lambda o: ops.crop(o, n), outs)
 
     return ExecutionPlan(
         op=op, backend="pallas_halo",
@@ -609,17 +616,17 @@ def _build_single_shard(op, parts, pnl, left_p, right_p, use_pallas,
         c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
         out = ops.fused_cheb_recurrence(mv, _pad(f), c2, lmax,
                                         use_pallas=use_pallas)
-        return out[..., :n]
+        return ops.crop(out, n)
 
     def apply_adjoint(a: Array) -> Array:
         c = jnp.asarray(coeffs, a.dtype)
-        return cheb.cheb_apply_adjoint(mv, _pad(a), c, lmax)[..., :n]
+        return ops.crop(cheb.cheb_apply_adjoint(mv, _pad(a), c, lmax), n)
 
     def apply_gram(f: Array) -> Array:
         d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
         out = ops.fused_cheb_recurrence(mv, _pad(f), d, lmax,
                                         use_pallas=use_pallas)
-        return out[..., 0, :n]
+        return ops.crop(out[..., 0, :], n)
 
     def solve_lasso(y, mu, gamma, n_iters):
         c = jnp.asarray(coeffs, y.dtype)
@@ -643,7 +650,7 @@ def _build_single_shard(op, parts, pnl, left_p, right_p, use_pallas,
     def matvec_runner(fn, signals, consts=()):
         padded = tuple(_pad(s) for s in signals)
         outs = fn(mv, *padded, *consts)
-        return jax.tree.map(lambda o: o[..., :n], outs)
+        return jax.tree.map(lambda o: ops.crop(o, n), outs)
 
     return ExecutionPlan(
         op=op, backend="pallas_halo",

@@ -24,11 +24,13 @@ without touching any caller.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Any, Callable, Dict, Optional
 
 import jax
 
+from .. import obs
 from ..core.multiplier import UnionMultiplier
 
 Array = jax.Array
@@ -71,6 +73,20 @@ def canonical_solve_items(solve_kwargs: Dict[str, Any]):
                  for k, v in sorted(solve_kwargs.items()))
 
 
+def _scoped(name: str, fn: Callable) -> Callable:
+    """`fn` with every op it traces under the ``repro.<name>`` scope."""
+    if getattr(fn, "obs_scope", None) == name:
+        return fn
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with obs.scope(name):
+            return fn(*args, **kwargs)
+
+    run.obs_scope = name
+    return run
+
+
 @dataclasses.dataclass(frozen=True)
 class ExecutionPlan:
     """A compiled-strategy view of one GraphOperator.
@@ -101,6 +117,11 @@ class ExecutionPlan:
     #: Backends that leave it None fall back to the single-device reference
     #: matvec in `plan.solve` (logged at INFO).
     matvec_runner: Optional[Callable] = None
+
+    def __post_init__(self):
+        # the plan entries name their device phase once, for every backend
+        for kind in ("apply", "apply_adjoint", "apply_gram"):
+            object.__setattr__(self, kind, _scoped(kind, getattr(self, kind)))
 
     # compiled-callable memoization ----------------------------------------
     def _jit_cache(self) -> Dict[Any, Any]:
@@ -282,7 +303,8 @@ class ExecutionPlan:
         """
         from .solvers import solve_plan
 
-        return solve_plan(self, y, method, **kwargs)
+        with obs.scope("solve"):
+            return solve_plan(self, y, method, **kwargs)
 
     # Algorithm 3 -----------------------------------------------------------
     def solve_lasso(self, y: Array, mu, gamma: Optional[float] = None,
@@ -323,6 +345,7 @@ class ExecutionPlan:
                 blocking["mu"] = f"per-vertex, shape {mu_arr.shape}"
             if not blocking:
                 return self.solve_lasso_fn(y, mu, gamma, n_iters)
+            obs.count("lasso.unfused")
             logger.info(
                 "solve_lasso[%s]: %s forfeit the fused in-shard_map "
                 "ISTA; running the generic (unfused) loop",

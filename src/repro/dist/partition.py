@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..core import chebyshev as cheb
 from ..core.lasso import soft_threshold
 from ..core import graph as graphmod
@@ -522,11 +523,13 @@ class GeneralPartition:
 
     def to_partition_order(self, x: Array) -> Array:
         """Permute the trailing (vertex) axis into partition order."""
-        return jnp.take(x, self._order_jnp()[0], axis=-1)
+        with obs.scope("layout"):
+            return jnp.take(x, self._order_jnp()[0], axis=-1)
 
     def from_partition_order(self, y: Array) -> Array:
         """Inverse of :meth:`to_partition_order` (trailing axis length n)."""
-        return jnp.take(y, self._order_jnp()[1], axis=-1)
+        with obs.scope("layout"):
+            return jnp.take(y, self._order_jnp()[1], axis=-1)
 
     def dense_diag(self) -> np.ndarray:
         """(S, nl, nl) dense per-shard diagonal blocks — the `halo`
@@ -740,51 +743,56 @@ def make_exchange_matvec(interior, sends, couplings, axis: str, size: int,
         else:
             ef_state = state
         if exchanging:
-            tiles = [jnp.take(x, idx, axis=-1) for idx, _ in sends]
-            if ef_state is None:
-                wires = [quantize.encode(t, dt) for t in tiles]
-                new_ef = None
-            else:
-                wires, new_ef = [], []
-                for t, r in zip(tiles, ef_state):
-                    wt, rt = quantize.ef_encode(t, r, dt)
-                    wires.append(wt)
-                    new_ef.append(rt)
-                new_ef = tuple(new_ef)
-            # (1) one complete-bijection ppermute per ring offset — the
-            # multi-peer generalization of the banded left/right pair
-            recvs = [
-                jax.lax.ppermute(
-                    wt, axis,
-                    perm=[(i, (i + off) % size) for i in range(size)])
-                for wt, (_, off) in zip(wires, sends)
-            ]
+            with obs.scope("exchange"):
+                tiles = [jnp.take(x, idx, axis=-1) for idx, _ in sends]
+                if ef_state is None:
+                    wires = [quantize.encode(t, dt) for t in tiles]
+                    new_ef = None
+                else:
+                    wires, new_ef = [], []
+                    for t, r in zip(tiles, ef_state):
+                        wt, rt = quantize.ef_encode(t, r, dt)
+                        wires.append(wt)
+                        new_ef.append(rt)
+                    new_ef = tuple(new_ef)
+                # (1) one complete-bijection ppermute per ring offset —
+                # the multi-peer generalization of the banded left/right
+                # pair
+                recvs = [
+                    jax.lax.ppermute(
+                        wt, axis,
+                        perm=[(i, (i + off) % size) for i in range(size)])
+                    for wt, (_, off) in zip(wires, sends)
+                ]
             # (2) interior product overlaps the exchange
             y = interior(x)
             # (3) decode on arrival; injected faults perturb only what the
             # receiver consumes — the wire traffic is already committed
-            if inj is not None:
-                recvs = [inj.wire(rv, k, j, dt)
-                         for j, rv in enumerate(recvs)]
-            recvs = [quantize.decode(rv, dt, x.dtype) for rv in recvs]
-            if inj is not None:
-                new_carried = []
-                faulted = []
-                for j, (rv, c) in enumerate(zip(recvs, carried)):
-                    rv, c = inj.recv(rv, c, k, j)
-                    faulted.append(rv)
-                    new_carried.append(c)
-                recvs = faulted
-                new_state = (k + 1, tuple(new_carried), new_ef)
-            else:
-                new_state = new_ef
+            with obs.scope("exchange"):
+                if inj is not None:
+                    recvs = [inj.wire(rv, k, j, dt)
+                             for j, rv in enumerate(recvs)]
+                recvs = [quantize.decode(rv, dt, x.dtype) for rv in recvs]
+                if inj is not None:
+                    new_carried = []
+                    faulted = []
+                    for j, (rv, c) in enumerate(zip(recvs, carried)):
+                        rv, c = inj.recv(rv, c, k, j)
+                        faulted.append(rv)
+                        new_carried.append(c)
+                    recvs = faulted
+                    new_state = (k + 1, tuple(new_carried), new_ef)
+                else:
+                    new_state = new_ef
         else:
-            recvs = [jnp.take(x, idx, axis=-1) for idx, _ in sends]
+            with obs.scope("exchange"):
+                recvs = [jnp.take(x, idx, axis=-1) for idx, _ in sends]
             new_state = state
             y = interior(x)
-        for (rows, cols, vals), rv in zip(couplings, recvs):
-            y = y.at[..., rows].add(
-                vals.astype(x.dtype) * jnp.take(rv, cols, axis=-1))
+        with obs.scope("exchange"):
+            for (rows, cols, vals), rv in zip(couplings, recvs):
+                y = y.at[..., rows].add(
+                    vals.astype(x.dtype) * jnp.take(rv, cols, axis=-1))
         return y, new_state
 
     def mv(x, state=None):
@@ -952,6 +960,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             "block": (int(parts.blocks.shape[3]),
                       int(parts.blocks.shape[4])),
             "nnz_blocks": parts.nnz_blocks,
+            "blockell_fill": graphmod.block_ell_fill(parts.blocks),
             "sweep_dtype": sweep_dtype or "f32",
             "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
                 parts.blocks.shape[1:], dl, op.eta,
@@ -965,7 +974,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
 
     def _pout(y):
         """Partition order (padded) -> vertex order (logical n)."""
-        return parts.from_partition_order(y[..., :n])
+        return parts.from_partition_order(ops.crop(y, n))
 
     if S == 1:
         mv = _mk_mv(tuple(m[0] for m in mats), 1)
@@ -1034,7 +1043,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             xl, c = args[len(mats):]
             out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, dl),
                                             c, lmax, use_pallas=use_pallas)
-            return out[..., :nl]
+            return ops.crop(out, nl)
 
         c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
         out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
@@ -1047,7 +1056,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             al, c = args[len(mats):]
             out = cheb.cheb_apply_adjoint(mv, ops.pad_trailing(al, dl),
                                           c, lmax)
-            return out[..., :nl]
+            return ops.crop(out, nl)
 
         c = jnp.asarray(coeffs, a.dtype)
         out = _sharded(run, mesh, mat_specs + (_sig_spec(a.ndim), P()),
@@ -1060,7 +1069,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             xl, d = args[len(mats):]
             out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, dl),
                                             d, lmax, use_pallas=use_pallas)
-            return out[..., 0, :nl]
+            return ops.crop(out[..., 0, :], nl)
 
         d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
         out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
@@ -1121,7 +1130,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             sigs = tuple(ops.pad_trailing(s, dl)
                          for s in rest[:len(pinned)])
             outs = fn(mv, *sigs, *rest[len(pinned):])
-            return jax.tree.map(lambda o: o[..., :nl], outs)
+            return jax.tree.map(lambda o: ops.crop(o, nl), outs)
 
         outs = _sharded(run, mesh, in_specs, out_specs)(
             *mats, *pinned, *consts)

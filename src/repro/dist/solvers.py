@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import arma as _arma
 from ..core import chebyshev as cheb
 from ..core import jacobi as _jacobi
@@ -341,6 +342,7 @@ def solve_plan(
 
     runner = plan.matvec_runner
     if runner is None:
+        obs.count("solve.reference_matvec")
         logger.info(
             "solve[%s]: backend provides no matvec_runner; falling back to "
             "the single-device reference matvec (results are exact, but the "
@@ -598,6 +600,7 @@ def _solve_jacobi(plan, runner, y, num, den, K, method, rho, den_diag, x0,
                 # the in-kernel round loop unrolls the Horner chain; past
                 # this many SpMVs the trace/compile cost outweighs the
                 # launch savings — logged like every other fallback
+                obs.count("solve.unroll_fallback")
                 logger.info(
                     "solve[%s]: %d rounds x %d matvecs exceeds the "
                     "single-launch unroll budget (256) — running the "

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .layout import lane_pad, mxu_precision, pad_lanes
 
 Array = jax.Array
@@ -128,12 +129,14 @@ def block_ell_spmv_batched(
     # transpose of a B=1 batch very slowly)
     Bp = lane_pad(B)
     n = x.shape[-1]
-    xt = pad_lanes(x.reshape(B, n).T, Bp).reshape(n // bc, bc, Bp)
+    with obs.scope("layout"):
+        xt = pad_lanes(x.reshape(B, n).T, Bp).reshape(n // bc, bc, Bp)
     rows = max(1, SMEM_INDEX_WORDS // slots)
     y = None
     for r0 in range(0, nrb, rows):
         r1 = min(nrb, r0 + rows)
         y = _sweep_rows(panels, indices[r0:r1].reshape(-1), xt, y, r0, r1,
                         slots, interpret)
-    y = y.reshape(nrb * br, Bp)[:, :B].T
-    return y.reshape(batch_shape + (nrb * br,))
+    with obs.scope("layout"):
+        y = y.reshape(nrb * br, Bp)[:, :B].T
+        return y.reshape(batch_shape + (nrb * br,))

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from .layout import lane_pad, mxu_precision, pad_lanes
 
 Array = jax.Array
@@ -202,10 +203,11 @@ def cheb_sweep(
     Bp = lane_pad(B)
     # (B, n) -> (n, Bp): vertices on sublanes, so row-block stores land at
     # br-aligned sublane offsets and column gathers at bc-aligned ones
-    xt = pad_lanes(x.reshape(B, n).T, Bp).astype(sdt)
-    # SMEM pads 2-D arrays to 128-word rows: the (K+1, eta) order-major
-    # table and the (nrb, slots) indices ride flat
-    coef_flat = jnp.asarray(coeffs, jnp.float32).T.reshape(-1)
+    with obs.scope("layout"):
+        xt = pad_lanes(x.reshape(B, n).T, Bp).astype(sdt)
+        # SMEM pads 2-D arrays to 128-word rows: the (K+1, eta)
+        # order-major table and the (nrb, slots) indices ride flat
+        coef_flat = jnp.asarray(coeffs, jnp.float32).T.reshape(-1)
 
     kernel = functools.partial(
         _cheb_sweep_kernel, K=K1 - 1, alpha=float(alpha),
@@ -223,7 +225,9 @@ def cheb_sweep(
         out_shape=jax.ShapeDtypeStruct((eta, n, Bp), x.dtype),
         interpret=interpret,
     )(indices.reshape(-1), coef_flat, blocks.astype(sdt), xt)
-    return acc[..., :B].transpose(2, 0, 1).reshape(batch_shape + (eta, n))
+    with obs.scope("layout"):
+        return acc[..., :B].transpose(2, 0, 1).reshape(
+            batch_shape + (eta, n))
 
 
 def _jacobi_sweep_kernel(idx_ref, ws_ref, blocks_ref, b_ref, invd_ref,
@@ -319,7 +323,8 @@ def jacobi_sweep(
     Bp = lane_pad(B)
 
     def lanes(a):  # (..., n) -> (n, Bp), the batch on lanes
-        return pad_lanes(jnp.broadcast_to(a, full).reshape(B, n).T, Bp)
+        with obs.scope("layout"):
+            return pad_lanes(jnp.broadcast_to(a, full).reshape(B, n).T, Bp)
 
     ws = jnp.asarray(weights, jnp.float32)
     n_iters = ws.shape[0]
@@ -337,6 +342,8 @@ def jacobi_sweep(
         out_specs=_vmem(),
         scratch_shapes=[pltpu.VMEM((2, n, Bp), sdt)] * (n_h // 2),
     )
+    with obs.scope("layout"):
+        x0t = jnp.stack([lanes(x0).astype(b.dtype)] * 2)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -344,5 +351,6 @@ def jacobi_sweep(
         input_output_aliases={5: 0},
         interpret=interpret,
     )(indices.reshape(-1), ws, blocks.astype(sdt), lanes(b), lanes(inv_d),
-      jnp.stack([lanes(x0).astype(b.dtype)] * 2))
-    return out[n_iters % 2, :, :B].T.reshape(full)
+      x0t)
+    with obs.scope("layout"):
+        return out[n_iters % 2, :, :B].T.reshape(full)

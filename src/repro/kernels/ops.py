@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.graph import BlockELL
 from . import ref
 from .bcsr_spmv import SMEM_INDEX_WORDS, block_ell_spmv_batched
@@ -81,10 +82,11 @@ def spmv(A: BlockELL, x: Array, use_pallas: Optional[bool] = None) -> Array:
     backend if you want padding handled for you.
     """
     use, interp = _resolve(use_pallas)
-    if use:
-        return block_ell_spmv_batched(A.panels, A.indices, x,
-                                      interpret=interp)
-    return ref.block_ell_spmv_ref(A.blocks, A.indices, x)
+    with obs.scope("spmv"):
+        if use:
+            return block_ell_spmv_batched(A.panels, A.indices, x,
+                                          interpret=interp)
+        return ref.block_ell_spmv_ref(A.blocks, A.indices, x)
 
 
 def _scratch_dtype(scratch_dtype: Optional[str], itemsize: int):
@@ -171,15 +173,19 @@ def fused_cheb_sweep(
         if K < 2:
             return _per_order_cheb(A, x, c, lmax, use_pallas)
         if need > budget or A.indices.size > SMEM_INDEX_WORDS:
+            obs.count("cheb_sweep.fallback")
             logger.info(
                 "cheb_sweep: VMEM footprint %d B exceeds budget %d B or "
                 "%d column indices exceed SMEM (n=%d, eta=%d, K=%d, B=%d) "
                 "— falling back to the per-order cheb_step path", need,
                 budget, A.indices.size, n, eta, K, batch)
             return _per_order_cheb(A, x, c, lmax, use_pallas)
-        return cheb_sweep(A.blocks, A.indices, x, c, alpha=alpha,
-                          interpret=interp, scratch_dtype=sdt)
-    return ref.cheb_sweep_ref(A.blocks, A.indices, x, c, alpha=alpha)
+        obs.count("cheb_sweep.launch")
+        with obs.scope("sweep"):
+            return cheb_sweep(A.blocks, A.indices, x, c, alpha=alpha,
+                              interpret=interp, scratch_dtype=sdt)
+    with obs.scope("sweep"):
+        return ref.cheb_sweep_ref(A.blocks, A.indices, x, c, alpha=alpha)
 
 
 def fused_cheb_recurrence(
@@ -221,7 +227,7 @@ def fused_cheb_recurrence(
             use_pallas=use_pallas,
             vmem_budget=getattr(matvec, "vmem_budget", None),
             scratch_dtype=getattr(matvec, "sweep_dtype", None))
-        return out[..., :n_logical]
+        return crop(out, n_logical)
     return _cheb_recurrence_loop(matvec, x, coeffs, lmax, use_pallas)
 
 
@@ -239,6 +245,11 @@ def _cheb_recurrence_loop(
     exchange): a matvec exposing ``init_state(x)`` threads its state
     through the scan carry; plain matvecs get an empty-state shim.
     """
+    with obs.scope("recurrence"):
+        return _recurrence(matvec, x, coeffs, lmax, use_pallas)
+
+
+def _recurrence(matvec, x, coeffs, lmax, use_pallas):
     use, interp = _resolve(use_pallas)
     from ..core.chebyshev import _stateful_matvec
 
@@ -260,14 +271,18 @@ def _cheb_recurrence_loop(
     def body(carry, ck):
         t_km1, t_km2, acc, st = carry
         pt, st = mv2(t_km1, st)
-        if use:
-            tk, acc = cheb_step(pt, t_km1, t_km2, acc, ck,
-                                alpha=alpha, interpret=interp)
-        else:
-            tk, acc = ref.cheb_step_ref(pt, t_km1, t_km2, acc, ck, alpha=alpha)
+        with obs.scope("step"):
+            if use:
+                tk, acc = cheb_step(pt, t_km1, t_km2, acc, ck,
+                                    alpha=alpha, interpret=interp)
+            else:
+                tk, acc = ref.cheb_step_ref(pt, t_km1, t_km2, acc, ck,
+                                            alpha=alpha)
         return (tk, t_km1, acc, st), None
 
-    (_, _, acc, _), _ = jax.lax.scan(body, (t1, t0, acc, st), c[:, 2:].T)
+    with obs.scope("layout"):
+        orders = c[:, 2:].T
+    (_, _, acc, _), _ = jax.lax.scan(body, (t1, t0, acc, st), orders)
     return acc
 
 
@@ -350,10 +365,11 @@ def jacobi_update(
     back to the jnp oracle.
     """
     use, interp = _resolve(use_pallas)
-    if use and not jnp.iscomplexobj(x):
-        return jacobi_step(qx, x, x_prev, y, inv_d, w=w, s=s,
-                           interpret=interp)
-    return ref.jacobi_step_ref(qx, x, x_prev, y, inv_d, w=w, s=s)
+    with obs.scope("step"):
+        if use and not jnp.iscomplexobj(x):
+            return jacobi_step(qx, x, x_prev, y, inv_d, w=w, s=s,
+                               interpret=interp)
+        return ref.jacobi_step_ref(qx, x, x_prev, y, inv_d, w=w, s=s)
 
 
 def jacobi_sweep_vmem_bytes(blocks_shape, n: int, n_den: int,
@@ -416,15 +432,18 @@ def fused_jacobi_sweep(
         need = jacobi_sweep_vmem_bytes(A.blocks.shape, total, len(den), batch,
                                        scratch_dtype=sdt)
         if need > budget or A.indices.size > SMEM_INDEX_WORDS:
+            obs.count("jacobi_sweep.fallback")
             logger.info(
                 "jacobi_sweep: VMEM footprint %d B exceeds budget %d B or "
                 "%d column indices exceed SMEM (n=%d, B=%d) — falling back "
                 "to the per-round jacobi_step path", need, budget,
                 A.indices.size, total, batch)
         else:
-            out = jacobi_sweep(A.blocks, A.indices, bp, invdp, ws, x0p,
-                               den=den, interpret=interp, scratch_dtype=sdt)
-            return out[..., :n_logical]
+            with obs.scope("sweep"):
+                out = jacobi_sweep(A.blocks, A.indices, bp, invdp, ws, x0p,
+                                   den=den, interpret=interp,
+                                   scratch_dtype=sdt)
+            return crop(out, n_logical)
         # per-round fallback: one SpMV chain + one fused update per round
 
         def body(carry, ws_row):
@@ -437,12 +456,14 @@ def fused_jacobi_sweep(
                                    use_pallas=use_pallas)
             return (x_next, x), None
 
-        (x_final, _), _ = jax.lax.scan(
-            body, (x0p, x0p), jnp.asarray(ws, bp.dtype))
-        return x_final[..., :n_logical]
-    out = ref.jacobi_sweep_ref(A.blocks, A.indices, bp, invdp, ws, x0p,
-                               den=den)
-    return out[..., :n_logical]
+        with obs.scope("recurrence"):
+            (x_final, _), _ = jax.lax.scan(
+                body, (x0p, x0p), jnp.asarray(ws, bp.dtype))
+        return crop(x_final, n_logical)
+    with obs.scope("sweep"):
+        out = ref.jacobi_sweep_ref(A.blocks, A.indices, bp, invdp, ws, x0p,
+                                   den=den)
+    return crop(out, n_logical)
 
 
 def ista_update(
@@ -475,7 +496,17 @@ def pad_trailing(x: Array, total: int) -> Array:
     pad = total - x.shape[-1]
     if pad == 0:
         return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    with obs.scope("layout"):
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def crop(x: Array, n: int) -> Array:
+    """The first `n` entries of the last (vertex) axis: the inverse of
+    :func:`pad_trailing`."""
+    if x.shape[-1] == n:
+        return x
+    with obs.scope("layout"):
+        return x[..., :n]
 
 
 def pad_for_kernels(x: Array, multiple: int = 1024) -> Array:
