@@ -269,7 +269,8 @@ def check_fault_schedule(clean_plan, faulted_plan,
 # VMEM budget
 # ---------------------------------------------------------------------------
 def _vmem_aval_bytes(aval) -> int:
-    """Tiled VMEM bytes of one kernel-side ref aval; 0 for SMEM refs."""
+    """Tiled VMEM bytes of one kernel-side ref aval; 0 for refs outside
+    VMEM (SMEM tables, operands left in HBM, DMA semaphores)."""
     from ..kernels.layout import tile_bytes
 
     inner = getattr(aval, "inner_aval", aval)
@@ -277,7 +278,8 @@ def _vmem_aval_bytes(aval) -> int:
     dtype = getattr(inner, "dtype", None)
     if shape is None or dtype is None:
         return 0
-    if "smem" in str(getattr(aval, "memory_space", "")).lower():
+    space = str(getattr(aval, "memory_space", "")).lower()
+    if any(k in space for k in ("smem", "any", "hbm", "semaphore")):
         return 0
     return tile_bytes(shape, dtype)
 
@@ -336,8 +338,8 @@ def _float_dtypes(vars_) -> List[np.dtype]:
     out = []
     for v in vars_:
         dt = getattr(getattr(v, "aval", None), "dtype", None)
-        if dt is None:
-            continue
+        if dt is None or jnp.issubdtype(dt, jax.dtypes.extended):
+            continue  # no dtype, or a semaphore / PRNG key
         dt = np.dtype(dt)
         # jnp.issubdtype, not np.: the ml_dtypes floats (bfloat16, fp8)
         # are exactly the ones implicit promotion bites

@@ -228,6 +228,10 @@ class BlockELL:
                made (once per plan on one device; once per application,
                outside the order loop, for a shard's structure made
                inside shard_map), unless given
+      band:    static bound on how far a valid slot's column block lies
+               from its row block's own (`block_ell_band`), or None when
+               unknown; a known band lets the SpMV read each group of
+               row blocks' column tiles from one VMEM window
     """
 
     blocks: Array
@@ -235,6 +239,7 @@ class BlockELL:
     mask: Array
     n: int
     panels: Optional[Array] = None
+    band: Optional[int] = None
 
     def __post_init__(self):
         if self.panels is None:
@@ -286,6 +291,23 @@ def block_ell_fill(blocks) -> float:
     return int(count(blocks)) / blocks.size
 
 
+def block_ell_band(indices, mask, block: Tuple[int, int]) -> int:
+    """The band of a Block-ELL structure: the largest distance, in column
+    blocks, from a valid slot's column block to the column blocks that
+    its row block's own rows span (row block rb spans column blocks
+    rb * br // bc to ((rb + 1) * br - 1) // bc; for square blocks, rb).
+    Padded slots do not count.  indices/mask: (..., nrb, slots) numpy
+    arrays, leading shard dims included (the max over shards); 0 for a
+    structure with no valid slot."""
+    br, bc = block
+    indices = np.asarray(indices, np.int64)
+    mask = np.asarray(mask, bool)
+    rb = np.arange(indices.shape[-2], dtype=np.int64)[:, None]
+    lo, hi = rb * br // bc, ((rb + 1) * br - 1) // bc
+    dist = np.maximum(lo - indices, indices - hi)
+    return int(np.max(dist, initial=0, where=mask))
+
+
 def to_block_ell(
     M: np.ndarray, block_shape: Tuple[int, int] = (8, 128)
 ) -> BlockELL:
@@ -329,6 +351,7 @@ def to_block_ell(
         mask=jnp.asarray(mask),
         n=n,
         panels=jnp.asarray(block_panels(blocks)),
+        band=block_ell_band(indices, mask, block_shape),
     )
 
 

@@ -105,6 +105,7 @@ def build(op, *, mesh=None, partition=None, block: Tuple[int, int] = (8, 128),
                 None if nnz_blocks is None
                 else nnz_blocks * 2 * block[0] * block[1]),
             "blockell_fill": graphmod.block_ell_fill(A.blocks),
+            "spmv_band": A.band,
             "sweep_dtype": sweep_dtype or "f32",
             "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
                 A.blocks.shape, total, op.eta, scratch_dtype=sweep_dtype),

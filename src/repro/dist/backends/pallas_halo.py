@@ -414,10 +414,12 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
                                pnl).swapaxes(-1, -2)
     coeffs = op.coeffs
     lmax = op.lmax
+    band = graphmod.block_ell_band(parts.indices, parts.mask,
+                                   parts.blocks.shape[3:])
 
     def _mk_mv(blocks, indices, mask, left, right):
         local_A = graphmod.BlockELL(blocks=blocks[0], indices=indices[0],
-                                    mask=mask[0], n=nl)
+                                    mask=mask[0], n=nl, band=band)
         return _halo_row_matvec(local_A, left[0], right[0], nl, h, axis,
                                 use_pallas, vmem_budget, n_shards,
                                 exchange_dtype, error_feedback, sweep_dtype,
@@ -437,6 +439,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
         "block": block,
         "nnz_blocks": parts.nnz_blocks,
         "blockell_fill": graphmod.block_ell_fill(parts.blocks),
+        "spmv_band": band,
         "exchange_dtype": exchange_dtype,
         "error_feedback": bool(error_feedback),
         "fault_spec": faults.spec_info(fault_spec),
@@ -604,7 +607,8 @@ def _build_single_shard(op, parts, pnl, left_p, right_p, use_pallas,
     lmax = op.lmax
     local_A = graphmod.BlockELL(blocks=parts.blocks[0],
                                 indices=parts.indices[0],
-                                mask=parts.mask[0], n=nl)
+                                mask=parts.mask[0], n=nl,
+                                band=info["spmv_band"])
     mv = _halo_row_matvec(local_A, left_p[0], right_p[0], nl, h,
                           info["mesh_axis"], use_pallas, vmem_budget,
                           n_shards=1, sweep_dtype=sweep_dtype)

@@ -894,6 +894,8 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
     if interior == "block_ell":
         base_mats: Tuple[Array, ...] = (parts.blocks, parts.indices,
                                         parts.mask)
+        band = graphmod.block_ell_band(parts.indices, parts.mask,
+                                       parts.blocks.shape[3:])
     else:
         base_mats = (jnp.asarray(parts.dense_diag()),)
     nbase = len(base_mats)
@@ -907,7 +909,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
         base, ex = local_mats[:nbase], local_mats[nbase:]
         if interior == "block_ell":
             local_A = graphmod.BlockELL(blocks=base[0], indices=base[1],
-                                        mask=base[2], n=nl)
+                                        mask=base[2], n=nl, band=band)
 
             def interior_mv(x):
                 return ops.spmv(local_A, x, use_pallas=use_pallas)
@@ -961,6 +963,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
                       int(parts.blocks.shape[4])),
             "nnz_blocks": parts.nnz_blocks,
             "blockell_fill": graphmod.block_ell_fill(parts.blocks),
+            "spmv_band": band,
             "sweep_dtype": sweep_dtype or "f32",
             "sweep_vmem_bytes": ops.cheb_sweep_vmem_bytes(
                 parts.blocks.shape[1:], dl, op.eta,

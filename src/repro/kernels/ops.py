@@ -11,6 +11,13 @@ on CPU `use_pallas=True` runs them under interpret=True (the kernel body
 executed in Python — used by the kernel test sweeps), and the default
 takes the pure-jnp reference path so tests and CPU count runs stay fast.
 
+SpMV dispatch: :func:`spmv` takes the band-windowed kernel
+(`bcsr_spmv.block_ell_spmv_window`) when the structure's band is known
+(`BlockELL.band`, set at plan build) and the window fits
+:data:`DEFAULT_SPMV_WINDOW_VMEM_BUDGET` at the batch's lane width, else
+the gather kernel (`bcsr_spmv.block_ell_spmv_batched`); each choice is
+counted (``spmv.window`` / ``spmv.gather``) at trace time.
+
 Sharded use: :func:`fused_cheb_recurrence` is the matvec-generic form of the
 fused recurrence.  The `pallas_halo` backend calls it *inside* a shard_map
 with a halo-exchanging matvec over the per-shard Block-ELL tiles, so the
@@ -40,13 +47,14 @@ import numpy as np
 from .. import obs
 from ..core.graph import BlockELL
 from . import ref
-from .bcsr_spmv import SMEM_INDEX_WORDS, block_ell_spmv_batched
+from .bcsr_spmv import (SMEM_INDEX_WORDS, block_ell_spmv_batched,
+                        block_ell_spmv_window, window_buffers, window_starts)
 from .cheb_step import cheb_step
 from .cheb_sweep import (SCRATCH_DTYPES, cheb_sweep, cheb_sweep_buffers,
                          jacobi_sweep, jacobi_sweep_buffers)
 from .jacobi_step import jacobi_step
 from .flash_attention import flash_attention as _flash
-from .layout import vmem_bytes
+from .layout import lane_pad, vmem_bytes
 from .soft_threshold import ista_shrink
 
 Array = jax.Array
@@ -57,6 +65,17 @@ logger = logging.getLogger(__name__)
 #: default scoped-VMEM limit (16 MiB on v5e) minus headroom for its own
 #: buffers, against the tiled footprint of `cheb_sweep_vmem_bytes`.
 DEFAULT_SWEEP_VMEM_BUDGET = 12 * 1024 * 1024
+
+#: VMEM budget of the band-windowed SpMV (`bcsr_spmv.block_ell_spmv_window`):
+#: the same headroom under the default scoped-VMEM limit, against the
+#: tiled footprint of `bcsr_spmv.window_buffers`.
+DEFAULT_SPMV_WINDOW_VMEM_BUDGET = 12 * 1024 * 1024
+
+#: Row blocks per group the windowed SpMV tries, largest first: the
+#: largest whose buffers fit the budget.  The window's overlap with its
+#: neighbours (2 * band column blocks) is read again by each group, so
+#: larger groups read less of x twice.
+SPMV_WINDOW_ROWS = (128, 64, 32, 16)
 
 
 def _on_tpu() -> bool:
@@ -84,9 +103,45 @@ def spmv(A: BlockELL, x: Array, use_pallas: Optional[bool] = None) -> Array:
     use, interp = _resolve(use_pallas)
     with obs.scope("spmv"):
         if use:
+            rows = spmv_window_rows(A, x)
+            if rows is not None:
+                obs.count("spmv.window")
+                return block_ell_spmv_window(A.panels, A.indices, x,
+                                             band=A.band, rows=rows,
+                                             interpret=interp)
+            obs.count("spmv.gather")
             return block_ell_spmv_batched(A.panels, A.indices, x,
                                           interpret=interp)
         return ref.block_ell_spmv_ref(A.blocks, A.indices, x)
+
+
+def spmv_window_rows(A: BlockELL, x) -> Optional[int]:
+    """Row blocks per group for the band-windowed SpMV of A over the
+    signals `x` (an array or shape-dtype), or None for the gather path.
+
+    The window needs a known band (`BlockELL.band`); then the largest
+    group of :data:`SPMV_WINDOW_ROWS` (at most the structure's row
+    blocks) whose two x windows, panels and output blocks fit
+    :data:`DEFAULT_SPMV_WINDOW_VMEM_BUDGET` at the batch's lane width and
+    dtype, and whose window starts and two index blocks fit the SMEM
+    words of `bcsr_spmv.SMEM_INDEX_WORDS`.
+    """
+    if A.band is None:
+        return None
+    nrb, br, width = A.panels.shape
+    slots = A.indices.shape[-1]
+    bc = width // slots
+    lanes = lane_pad(max(1, int(np.prod(x.shape[:-1]))))
+    for rows in SPMV_WINDOW_ROWS:
+        rows = min(rows, nrb)
+        starts, span = window_starts(nrb, br, bc, A.band, rows)
+        vmem = vmem_bytes(window_buffers(rows, span, (br, bc), slots, lanes,
+                                         x.dtype, A.panels.dtype))
+        smem = starts.size + 2 * rows * lane_pad(slots)
+        if (vmem <= DEFAULT_SPMV_WINDOW_VMEM_BUDGET
+                and smem <= SMEM_INDEX_WORDS):
+            return rows
+    return None
 
 
 def _scratch_dtype(scratch_dtype: Optional[str], itemsize: int):

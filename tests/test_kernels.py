@@ -1,14 +1,19 @@
 """Pallas kernel sweeps (interpret mode) vs the pure-jnp oracles in ref.py."""
+import dataclasses
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import chebyshev as cheb
 from repro.core import filters, graph
 from repro.kernels import ops, ref
 from repro.kernels import bcsr_spmv
-from repro.kernels.bcsr_spmv import block_ell_spmv_batched
+from repro.kernels.bcsr_spmv import (block_ell_spmv_batched,
+                                     block_ell_spmv_window, window_starts)
 from repro.kernels.cheb_step import cheb_step
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.soft_threshold import ista_shrink
@@ -50,6 +55,96 @@ def test_block_ell_spmv_smem_chunks(monkeypatch, rows_per_launch):
         A.panels, A.indices, x, interpret=True)
     y_r = ref.block_ell_spmv_ref(A.blocks, A.indices, x)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), atol=1e-4)
+
+
+def banded_block_ell(n, block, seed=0):
+    """A spatially sorted sensor graph's Laplacian in Block-ELL: a known,
+    narrow band, and row blocks with fewer valid slots than the widest
+    (so padded slots)."""
+    g, _ = graph.connected_sensor_graph(jax.random.PRNGKey(seed), n=n,
+                                        theta=0.15, kappa=0.15)
+    gs, _ = graph.spatial_sort(g)
+    return graph.to_block_ell(np.asarray(gs.laplacian(), np.float32), block)
+
+
+@pytest.mark.parametrize("n,block,rows", [(300, (8, 8), 5),
+                                          (513, (8, 128), 7),
+                                          (1024, (8, 128), 24)])
+@pytest.mark.parametrize("batch", [(), (3,), (64,)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_block_ell_spmv_window(n, block, rows, batch, dtype):
+    """The band-windowed grid against the oracle: groups that do not
+    divide the row blocks (a partial last group), windows clamped at
+    both ends of the matrix, padded slots, and B = 1, 3, 64."""
+    A = banded_block_ell(n, block)
+    nrb, br, bc = A.n_row_blocks, *block
+    starts, span = window_starts(nrb, br, bc, A.band, rows)
+    assert nrb % rows and span < nrb * br // bc
+    assert starts[0] == 0 and starts[-1] == nrb * br // bc - span
+    assert not np.asarray(A.mask).all()
+    blocks = A.blocks.astype(dtype)
+    x = jax.random.normal(jax.random.PRNGKey(1), batch + (A.padded_n,),
+                          dtype)
+    y_k = block_ell_spmv_window(graph.block_panels(blocks), A.indices, x,
+                                band=A.band, rows=rows, interpret=True)
+    assert y_k.shape == x.shape
+    y_r = ref.block_ell_spmv_ref(blocks, A.indices, x)
+    tol = 1e-4 if dtype == jnp.float32 else 2e-1
+    np.testing.assert_allclose(np.asarray(y_k, np.float32),
+                               np.asarray(y_r, np.float32), atol=tol, rtol=tol)
+
+
+def test_block_ell_spmv_window_needs_the_band():
+    """The window holds only what the band promises: told a band of 0,
+    a row block reads its own column block and drops its neighbours'."""
+    A = banded_block_ell(300, (8, 8))
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, A.padded_n))
+    y_r = ref.block_ell_spmv_ref(A.blocks, A.indices, x)
+    y_k = block_ell_spmv_window(A.panels, A.indices, x, band=0, rows=1,
+                                interpret=True)
+    assert float(jnp.abs(y_k - y_r).max()) > 1e-2
+
+
+@pytest.mark.parametrize("case,counted", [("banded", "spmv.window"),
+                                          ("no_band", "spmv.gather"),
+                                          ("over_budget", "spmv.gather")])
+def test_spmv_dispatch_follows_structure(monkeypatch, case, counted):
+    """`ops.spmv` takes the window where the band is known and the
+    window fits the VMEM budget, else the gather path; both match the
+    oracle, and each choice is counted."""
+    A = banded_block_ell(300, (8, 8))
+    if case == "no_band":
+        A = dataclasses.replace(A, band=None)
+    if case == "over_budget":
+        monkeypatch.setattr(ops, "DEFAULT_SPMV_WINDOW_VMEM_BUDGET", 64 * 1024)
+    x = jax.random.normal(jax.random.PRNGKey(4), (3, A.padded_n))
+    obs.reset()
+    y_k = ops.spmv(A, x, use_pallas=True)
+    other = ({"spmv.window", "spmv.gather"} - {counted}).pop()
+    assert obs.snapshot().get(counted) == 1 and other not in obs.snapshot()
+    y_r = ref.block_ell_spmv_ref(A.blocks, A.indices, x)
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), atol=1e-4)
+
+
+def _chip_ell(band, slots=23, block=(8, 8), n=1_000_000, dtype=jnp.float32):
+    br, bc = block
+    return types.SimpleNamespace(
+        panels=jax.ShapeDtypeStruct((n // br, br, slots * bc), dtype),
+        indices=jax.ShapeDtypeStruct((n // br, slots), jnp.int32), band=band)
+
+
+@pytest.mark.parametrize("band,batch,rows", [
+    (265, 64, 128),    # sensor1m: a 658-block window of 4 KiB tiles
+    (265, 129, 64),    # two lane tiles a column tile: smaller groups
+    (700, 64, 32),     # a wider band: smaller groups
+    (1500, 64, None),  # even 16 rows: two 3,016-block windows, 24 MiB
+    (None, 64, None),  # no band known
+])
+def test_spmv_window_rows_follow_the_footprint(band, batch, rows):
+    """The group size comes from the structure, the batch's lanes and
+    the budget; a window that cannot fit takes the gather path."""
+    x = jax.ShapeDtypeStruct((batch, 1_000_000), jnp.float32)
+    assert ops.spmv_window_rows(_chip_ell(band), x) == rows
 
 
 @pytest.mark.parametrize("n,eta", [(1024, 1), (2048, 3), (896, 7)])

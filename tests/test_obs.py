@@ -210,3 +210,31 @@ def test_blockell_fill_is_a_hand_count(backend, options):
         options["mesh"] = one_shard_mesh()
     plan = op.plan(backend, block=(2, 2), **options)
     assert plan.info["blockell_fill"] == pytest.approx(13 / 36)
+
+
+#: The ring 0-1-...-7-0: the edge 7-0 couples row block 0 to the last
+#: column block.
+RING8 = (2.0 * np.eye(8) - np.eye(8, k=1) - np.eye(8, k=-1)
+         - np.eye(8, k=7) - np.eye(8, k=-7)).astype(np.float32)
+
+
+@pytest.mark.parametrize("backend,options", [
+    ("pallas", {}),
+    ("pallas_halo", {"partition": "banded"}),
+    ("pallas_halo", {"partition": "general"}),
+], ids=["pallas", "pallas_halo-banded", "pallas_halo-general"])
+def test_spmv_band_is_a_hand_count(backend, options):
+    # (2, 2) blocks: row block 0 (rows 0-1) reaches column 7, in column
+    # block 3, three blocks away; every other slot is at most one away
+    op = GraphOperator(P=RING8, multipliers=wavelets.sgwt_multipliers(4.0,
+                                                                      J=1),
+                       lmax=4.0, K=3)
+    if options.get("partition") == "general":
+        options = {"partition": partition_general(
+            RING8, 1, order=np.arange(8), block=(2, 2))}
+    if backend == "pallas_halo":
+        options["mesh"] = one_shard_mesh()
+    plan = op.plan(backend, block=(2, 2), **options)
+    assert plan.info["spmv_band"] == 3
+    assert graph.block_ell_band(np.array([[0, 3]]), np.array([[True, False]]),
+                                (2, 2)) == 0

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops
-from repro.kernels.bcsr_spmv import block_ell_spmv_batched
+from repro.kernels.bcsr_spmv import (block_ell_spmv_batched,
+                                     block_ell_spmv_window)
 from repro.kernels.cheb_step import cheb_step
 from repro.kernels.cheb_sweep import cheb_sweep, jacobi_sweep
 from repro.kernels.jacobi_step import jacobi_step
@@ -24,6 +25,8 @@ ETA, K = 4, 20            # SGWT J=3 bank at the paper's order
 SWEEP_SLOTS = 4           # sensor-graph Block-ELL (8, 128): 4 slots
 N_CHIP = 1_000_000        # community graph, one chip, Block-ELL (8, 8)
 CHIP_SLOTS = 9
+SENSOR_SLOTS = 23         # sensor1m: Block-ELL (8, 8), 23 slots, band 265
+SENSOR_BAND = 265
 
 
 @pytest.fixture(scope="module")
@@ -105,6 +108,20 @@ def test_block_ell_spmv_batched_compiles_at_chip_scale(one_chip):
              _spec(one_chip, A.panels.shape),
              _spec(one_chip, A.indices.shape, jnp.int32),
              _spec(one_chip, (64, N_CHIP)))
+
+
+def test_block_ell_spmv_window_compiles_at_chip_scale(one_chip):
+    """The windowed SpMV at sensor1m's shapes, with the group size the
+    dispatch guard picks, fits VMEM and SMEM."""
+    A = _ell(N_CHIP, SENSOR_SLOTS, (8, 8))
+    A.band = SENSOR_BAND
+    x = _spec(one_chip, (64, N_CHIP))
+    rows = ops.spmv_window_rows(A, x)
+    assert rows == 128
+    _compile(lambda p, i, x: block_ell_spmv_window(
+        p, i, x, band=SENSOR_BAND, rows=rows),
+        _spec(one_chip, A.panels.shape),
+        _spec(one_chip, A.indices.shape, jnp.int32), x)
 
 
 def test_cheb_step_compiles_at_chip_scale(one_chip):
