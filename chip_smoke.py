@@ -112,7 +112,10 @@ def compile_path(fn, *args, expect):
     import jax
 
     t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(*args).compile()
+    # a plan's compiled entry lowers itself (with its structure as
+    # arguments); anything else is jitted here
+    lower = getattr(fn, "lower", None) or jax.jit(fn).lower
+    compiled = lower(*args).compile()
     secs = time.perf_counter() - t0
     kernels = kernels_in(compiled)
     log(f"  compiled in {secs:.1f} s; tpu_custom_call kernels: {kernels}")

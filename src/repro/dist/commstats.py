@@ -59,10 +59,15 @@ import numpy as np
 
 # The shared jaxpr visitor (Layer-1 substrate of `repro.analysis`); this
 # module re-exports COLLECTIVE_PRIMITIVES from it for compatibility.
+from .. import obs
 from ..analysis.jaxpr_walk import (COLLECTIVE_PRIMITIVES, eqn_payload,
                                    walk_jaxpr)
 
 logger = logging.getLogger(__name__)
+
+#: The scope of a general plan's moves between vertex and partition order
+#: (`partition.sharded_reorder`): not an exchange round.
+REORDER_SCOPE = obs.PREFIX + "reorder"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,6 +103,10 @@ class CommStats:
     n_shards: int
     batch: int = 1
     ppermutes_per_round: Optional[int] = None
+    #: collectives under the ``repro.reorder`` scope (a general plan moving
+    #: signals between vertex and partition order): tallied apart, never
+    #: exchange rounds or their bytes
+    reorder: Tuple[CollectiveCall, ...] = ()
 
     @property
     def n_collectives(self) -> int:
@@ -139,6 +148,12 @@ class CommStats:
     def bytes_per_shard(self) -> int:
         """Payload bytes one shard sends per application."""
         return sum(c.count * c.nbytes for c in self.collectives)
+
+    @property
+    def reorder_bytes_per_shard(self) -> int:
+        """Payload bytes one shard sends moving signals between vertex
+        and partition order, per application."""
+        return sum(c.count * c.nbytes for c in self.reorder)
 
     @property
     def bytes_per_round(self) -> float:
@@ -219,6 +234,10 @@ def measure(fn: Callable, *example_args, n_shards: int = 1,
     (+ WARNING log) and counts the site once per enclosing-scan trip, so
     the returned stats are an explicit *lower bound*.
 
+    Collectives under the ``repro.reorder`` scope (a general plan moving
+    its signals between vertex and partition order) are tallied apart, in
+    :attr:`CommStats.reorder`: they are not exchange rounds.
+
     `ppermutes_per_round` forwards a plan-declared
     ``exchange_collectives_per_round`` to :attr:`CommStats.exchange_rounds`
     (how many ppermutes one neighbour-exchange round comprises: 2 for the
@@ -230,11 +249,14 @@ def measure(fn: Callable, *example_args, n_shards: int = 1,
             f"while_loops must be 'error' or 'warn', got {while_loops!r}")
     closed = jax.make_jaxpr(fn)(*example_args)
     tally: Dict[Tuple[str, int, int, Any], int] = {}
+    reorder: Dict[Tuple[str, int, int, Any], int] = {}
 
     def visit(eqn, ctx):
         name = eqn.primitive.name
         if name not in COLLECTIVE_PRIMITIVES:
             return
+        into = (reorder if REORDER_SCOPE in
+                str(eqn.source_info.name_stack).split("/") else tally)
         if ctx.in_while:
             msg = (
                 f"collective `{name}` under a while_loop (path "
@@ -250,16 +272,20 @@ def measure(fn: Callable, *example_args, n_shards: int = 1,
         if perm is not None:
             perm = tuple(tuple(int(v) for v in p) for p in perm)
         key = (name, elems, nbytes, perm)
-        tally[key] = tally.get(key, 0) + ctx.mult
+        into[key] = into.get(key, 0) + ctx.mult
 
     walk_jaxpr(closed, visit)
-    calls = tuple(
-        CollectiveCall(primitive=k[0], count=v, elems=k[1], nbytes=k[2],
-                       perm=k[3])
-        for k, v in sorted(tally.items(),
-                           key=lambda kv: (kv[0][:3], repr(kv[0][3]))))
-    return CommStats(collectives=calls, n_shards=n_shards, batch=batch,
-                     ppermutes_per_round=ppermutes_per_round)
+
+    def calls(t):
+        return tuple(
+            CollectiveCall(primitive=k[0], count=v, elems=k[1], nbytes=k[2],
+                           perm=k[3])
+            for k, v in sorted(t.items(),
+                               key=lambda kv: (kv[0][:3], repr(kv[0][3]))))
+
+    return CommStats(collectives=calls(tally), n_shards=n_shards,
+                     batch=batch, ppermutes_per_round=ppermutes_per_round,
+                     reorder=calls(reorder))
 
 
 def plan_comm_stats(plan, n: int = None, batch: int = None) -> Dict[str, CommStats]:

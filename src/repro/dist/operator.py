@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
@@ -73,6 +73,22 @@ def canonical_solve_items(solve_kwargs: Dict[str, Any]):
                  for k, v in sorted(solve_kwargs.items()))
 
 
+class _Entry:
+    """A jitted ``fn(structure, *args)`` called as ``entry(*args)``, with
+    the plan's structure passed as arguments: ``lower(*args)`` lowers with
+    it too."""
+
+    def __init__(self, fn: Callable, structure: Tuple[Any, ...]):
+        self.fn = jax.jit(fn)
+        self.structure = structure
+
+    def __call__(self, *args):
+        return self.fn(self.structure, *args)
+
+    def lower(self, *args):
+        return self.fn.lower(self.structure, *args)
+
+
 def _scoped(name: str, fn: Callable) -> Callable:
     """`fn` with every op it traces under the ``repro.<name>`` scope."""
     if getattr(fn, "obs_scope", None) == name:
@@ -117,6 +133,16 @@ class ExecutionPlan:
     #: Backends that leave it None fall back to the single-device reference
     #: matvec in `plan.solve` (logged at INFO).
     matvec_runner: Optional[Callable] = None
+    #: Arrays the entries read that a compiled program takes as arguments
+    #: (a multi-shard general plan's structure, laid out on its mesh):
+    #: closed over, they would be compiled in as constants, and a program
+    #: over several devices keeps each constant whole on every one.  Empty
+    #: where the entries close over their graph.
+    structure: Tuple[Any, ...] = ()
+    #: ``over(structure) -> ExecutionPlan``: this plan with every entry
+    #: reading `structure` instead (traced arrays, under `jax.jit`); None
+    #: when `structure` is empty.
+    over: Optional[Callable] = None
 
     def __post_init__(self):
         # the plan entries name their device phase once, for every backend
@@ -137,7 +163,8 @@ class ExecutionPlan:
         trace cache instead of retracing — the failure mode of writing
         ``jax.jit(plan.apply)`` afresh per request, which builds a new
         wrapper (and a new empty cache) every time.  kind: ``"apply"`` |
-        ``"apply_adjoint"`` | ``"apply_gram"``.
+        ``"apply_adjoint"`` | ``"apply_gram"``.  The program takes the
+        plan's `structure` as arguments.
         """
         fns = {"apply": self.apply, "apply_adjoint": self.apply_adjoint,
                "apply_gram": self.apply_gram}
@@ -156,8 +183,16 @@ class ExecutionPlan:
                self.info.get("fault_key", "none"))
         cache = self._jit_cache()
         if key not in cache:
-            cache[key] = jax.jit(fns[kind])
+            def entry(structure, x):
+                return getattr(self._over(structure), kind)(x)
+
+            entry.__name__ = kind  # the program is named after its kind
+            cache[key] = _Entry(entry, self.structure)
         return cache[key]
+
+    def _over(self, structure) -> "ExecutionPlan":
+        """This plan reading `structure` (itself when it has none)."""
+        return self if self.over is None else self.over(structure)
 
     def compiled_solve(self, method: str = "chebyshev", **solve_kwargs):
         """Memoized jitted Section-V solver: ``y -> x`` (or ``(x, history)``
@@ -171,7 +206,9 @@ class ExecutionPlan:
         plans solving different systems never share a cache entry — which
         also means every `compiled_solve` *lookup* re-hashes those arrays:
         hold the returned callable in the request loop rather than calling
-        ``compiled_solve(...)`` per request when passing large arrays.
+        ``compiled_solve(...)`` per request when passing large arrays.  The
+        program takes the plan's `structure` as arguments, as
+        :meth:`compiled`'s do.
         """
         key = (("solve", method, self.info.get("exchange_dtype", "f32"),
                 self.info.get("partition_fingerprint",
@@ -182,11 +219,11 @@ class ExecutionPlan:
         if key not in cache:
             history = bool(solve_kwargs.get("history", False))
 
-            def run(y):
-                res = self.solve(y, method, **solve_kwargs)
+            def run(structure, y):
+                res = self._over(structure).solve(y, method, **solve_kwargs)
                 return (res.x, res.history) if history else res.x
 
-            cache[key] = jax.jit(run)
+            cache[key] = _Entry(run, self.structure)
         return cache[key]
 
     def bucketed_callables(self, buckets, kinds=("apply",),

@@ -38,17 +38,25 @@ the boundary rows that cross the cut — the general-graph form of the
 paper's one-scalar-per-directed-edge-per-order accounting, measured (not
 assumed) by :mod:`repro.dist.commstats` and property-tested in
 ``tests/test_property.py`` / ``tests/test_partition.py``.
+
+A multi-shard plan takes and returns signals in vertex order, sharded over
+the mesh.  Where S divides n it moves them into partition order and back
+shard to shard (:class:`ShardReorder`, :func:`sharded_reorder`: only the
+rows that change shard cross the mesh, under the ``repro.reorder`` scope,
+never counted as exchange rounds); otherwise the whole signal is gathered
+(``jnp.take``) on the way in and out.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..core import chebyshev as cheb
@@ -264,10 +272,20 @@ def _bfs_order(csr: CSRMatrix) -> np.ndarray:
     deg = np.diff(csr.indptr)
     visited = np.zeros(n, bool)
     order = np.empty(n, np.int64)
-    pos = 0
+    # restarts in (degree, id) order: the first unvisited one is the
+    # least-degree unvisited vertex of least id, found by a pointer that
+    # only moves forward (a scan of every vertex per restart is quadratic
+    # in a graph with many isolated vertices)
+    seeds = np.argsort(deg, kind="stable")
+    nxt = pos = 0
     while pos < n:
-        unv = np.flatnonzero(~visited)
-        frontier = np.array([unv[np.argmin(deg[unv])]])
+        while True:
+            free = np.flatnonzero(~visited[seeds[nxt:nxt + 4096]])
+            if free.size:
+                nxt += int(free[0])
+                break
+            nxt += 4096
+        frontier = seeds[nxt:nxt + 1]
         visited[frontier] = True
         while frontier.size:
             order[pos:pos + frontier.size] = frontier
@@ -413,6 +431,140 @@ def _block_ell_shards(shard: np.ndarray, rows: np.ndarray, cols: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# Vertex order <-> partition order, shard to shard
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class ShardReorder:
+    """Static moves between a signal sharded in vertex order and the same
+    signal in partition order, for n = S * nl (numpy, host-side).
+
+    Shard q of the vertex-sharded signal holds vertices [q*nl, (q+1)*nl);
+    shard r in partition order holds slots [r*nl, (r+1)*nl), slot p being
+    vertex ``order[p]``.  A row on the same shard both ways stays; every
+    other row moves at ring offset ``d = (r - q) % S``:
+
+      offsets: ring offsets at which rows move, ascending.
+      rows[k]: (S, h_k) int32 — vertex rows shard q sends to shard
+          ``(q + offsets[k]) % S``, in the order of the slots they fill
+          (padded with row 0).
+      slots[k]: (S, h_k) int32 — the slots shard r fills from the tile it
+          receives at ``offsets[k]``, in the same order (padded with slot
+          0); on the way back shard r sends these slots to
+          ``(r - offsets[k]) % S``.
+      counts[k]: real rows each shard sends at ``offsets[k]``.
+      to_partition: (S, nl) int32 — slot j of shard r is read from
+          position ``to_partition[r, j]`` of its own vertex rows followed
+          by the tiles received at ``offsets[0], offsets[1], ...``.
+      to_vertex: (S, nl) int32 — the same for row i of shard q, from its
+          own slots followed by the tiles received on the way back.
+    """
+
+    offsets: Tuple[int, ...]
+    rows: Tuple[np.ndarray, ...]
+    slots: Tuple[np.ndarray, ...]
+    counts: Tuple[Tuple[int, ...], ...]
+    to_partition: np.ndarray
+    to_vertex: np.ndarray
+
+    @property
+    def tile_widths(self) -> Tuple[int, ...]:
+        return tuple(int(r.shape[1]) for r in self.rows)
+
+    @property
+    def arrays(self) -> Tuple[np.ndarray, ...]:
+        """Every per-shard array, leading axis S: what a plan shards."""
+        return self.rows + self.slots + (self.to_partition, self.to_vertex)
+
+
+def shard_reorder(order: np.ndarray, n_shards: int) -> ShardReorder:
+    """The :class:`ShardReorder` of `order` over `n_shards` shards (n must
+    be a multiple of the shard count)."""
+    order = np.asarray(order, np.int64)
+    n, S = order.size, int(n_shards)
+    if n % S:
+        raise ValueError(f"n = {n} is not a multiple of {S} shards")
+    nl = n // S
+    p = np.arange(n)
+    r, j = p // nl, p % nl            # partition shard and slot
+    q, i = order // nl, order % nl    # vertex shard and row
+    d = (r - q) % S
+    to_partition = np.empty(n, np.int32)   # indexed by r * nl + j
+    to_vertex = np.empty(n, np.int32)      # indexed by q * nl + i
+    stay = d == 0
+    to_partition[stay] = i[stay]
+    to_vertex[order[stay]] = j[stay]
+    offsets, rows, slots, counts = [], [], [], []
+    base = nl
+    for dd in np.unique(d[~stay]).tolist():
+        # ascending p: for one sender q the receiver (q + dd) % S is fixed,
+        # so its rows come contiguous and in the receiver's slot order
+        sel = np.flatnonzero(d == dd)
+        snd = q[sel]
+        cnt = np.bincount(snd, minlength=S)
+        h = int(cnt.max())
+        heads = np.flatnonzero(np.r_[True, snd[1:] != snd[:-1]])
+        first = np.zeros(S, np.int64)
+        first[snd[heads]] = heads
+        rank = np.arange(sel.size) - first[snd]
+        rk = np.zeros((S, h), np.int32)
+        sk = np.zeros((S, h), np.int32)
+        rk[snd, rank] = i[sel]
+        sk[r[sel], rank] = j[sel]
+        to_partition[sel] = base + rank
+        to_vertex[order[sel]] = base + rank
+        offsets.append(int(dd))
+        rows.append(rk)
+        slots.append(sk)
+        counts.append(tuple(int(c) for c in cnt))
+        base += h
+    return ShardReorder(offsets=tuple(offsets), rows=tuple(rows),
+                        slots=tuple(slots), counts=tuple(counts),
+                        to_partition=to_partition.reshape(S, nl),
+                        to_vertex=to_vertex.reshape(S, nl))
+
+
+def sharded_reorder(x: Array, send: Sequence[Array], place: Array,
+                    offsets: Sequence[int], axis: str, size: int,
+                    back: bool = False) -> Array:
+    """One shard's part of a :class:`ShardReorder` (inside ``shard_map``).
+
+    `x` is this shard's (..., nl) block; `send[k]` its row of
+    ``rows[k]`` (``slots[k]`` with ``back``) and `place` its row of
+    ``to_partition`` (``to_vertex``).  Each offset's rows are packed and
+    moved by one complete-bijection ``ppermute`` (``back`` reverses the
+    ring); one gather then places every row.  Only rows that change shard
+    cross the mesh, each tile padded to its offset's largest count.
+    """
+    def move(v):
+        # scoped here too: a loop body's ops do not see the caller's scope
+        with obs.scope("reorder"):
+            tiles = [
+                jax.lax.ppermute(
+                    jnp.take(v, idx, axis=-1), axis,
+                    perm=[(i, (i + (-d if back else d)) % size)
+                          for i in range(size)])
+                for idx, d in zip(send, offsets)]
+            return jnp.take(jnp.concatenate([v, *tiles], axis=-1), place,
+                            axis=-1)
+
+    with obs.scope("reorder"):
+        if x.ndim < 3:
+            return move(x)
+        # a (..., eta, nl) result moves one row of axis -2 at a time: the
+        # tiles and the gather's copies are then a row's, not the whole
+        # result's, and the loop's length does not depend on the batch
+        ax = x.ndim - 2
+
+        def row(out, j):
+            v = jax.lax.dynamic_index_in_dim(x, j, ax, keepdims=False)
+            return jax.lax.dynamic_update_index_in_dim(out, move(v), j,
+                                                       ax), None
+
+        return jax.lax.scan(row, jnp.zeros_like(x),
+                            jnp.arange(x.shape[ax]))[0]
+
+
+# ---------------------------------------------------------------------------
 # The partition contract
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
@@ -438,6 +590,8 @@ class GeneralPartition:
           shard i adds ``vals * tile[cols]`` into its rows, where `tile`
           arrived from shard ``(i - offsets[k]) % S`` (zero-val padding).
       order / n / n_local / edge_cut / method: bookkeeping.
+      reorder: the :class:`ShardReorder` of `order` where S > 1 divides
+          n, else None (signals are then gathered whole).
 
     A banded graph under the identity order reduces exactly to the ring
     plan: offsets (1, S-1) with the tail/head boundary tiles —
@@ -458,6 +612,7 @@ class GeneralPartition:
     n_local: int
     edge_cut: int
     method: str
+    reorder: Optional[ShardReorder] = None
 
     @property
     def n_shards(self) -> int:
@@ -667,6 +822,8 @@ def partition_general(
         n_local=nl,
         edge_cut=int(cut.sum()) // 2,
         method=method,
+        reorder=(shard_reorder(order, n_shards)
+                 if n_shards > 1 and n % n_shards == 0 else None),
     )
 
 
@@ -877,6 +1034,13 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
     into partition order on entry and back on exit, so callers never see
     the relabeling (solver state like Jacobi's 1/diag travels as signals
     and is permuted consistently).
+
+    On several shards, signals enter and leave in vertex order sharded
+    over `axis`.  Where S divides n, :func:`sharded_reorder` moves them to
+    partition order and back inside the shard_map (counted once per trace
+    as ``reorder.sharded``); otherwise the whole signal is gathered
+    (``reorder.gather``).  The structure is laid out on the mesh and is
+    the plan's `structure`: compiled entries take it as arguments.
     """
     from .operator import ExecutionPlan
     from ..core.lasso import LassoResult, _mu_threshold
@@ -889,7 +1053,8 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
     S, n, nl = parts.n_shards, parts.n, parts.n_local
     dl = parts.n_local_padded if interior == "block_ell" else nl
     coeffs, lmax = op.coeffs, op.lmax
-    n_off = len(parts.offsets)
+    offsets = parts.offsets
+    n_off = len(offsets)
 
     if interior == "block_ell":
         base_mats: Tuple[Array, ...] = (parts.blocks, parts.indices,
@@ -920,7 +1085,7 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             def interior_mv(x):
                 return jnp.einsum("ij,...j->...i", dg, x)
 
-        sends = tuple((ex[4 * k], parts.offsets[k]) for k in range(n_off))
+        sends = tuple((ex[4 * k], offsets[k]) for k in range(n_off))
         coupl = tuple((ex[4 * k + 1], ex[4 * k + 2], ex[4 * k + 3])
                       for k in range(n_off))
         mv = make_exchange_matvec(interior_mv, sends, coupl, axis, size,
@@ -1035,56 +1200,105 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
 
     rules = (make_rules(mesh) if axis == "graph"
              else ShardingRules(mapping={"vertex": axis}, mesh=mesh))
-    mat_specs = (rules.spec("vertex"),) * len(mats)
+    # the structure lives sharded on the mesh; every entry reads it as
+    # its first argument, so compiled programs take it as arguments
+    rd = parts.reorder
+    sharding = NamedSharding(mesh, rules.spec("vertex"))
+    structure = tuple(jax.device_put(m, sharding)
+                      for m in mats + (rd.arrays if rd is not None else ()))
+    nmats, ns = len(mats), len(structure)
+    struct_specs = (rules.spec("vertex"),) * ns
+    r_off = rd.offsets if rd is not None else ()
+    nr = len(r_off)
+    if rd is not None:
+        info.update({
+            "reorder": "sharded",
+            "reorder_tile_widths": rd.tile_widths,
+            # one signal in, eta out, f32 on the wire
+            "reorder_bytes_per_apply":
+                4 * (1 + op.eta) * sum(rd.tile_widths),
+        })
+        glob_in, glob_out = jnp.asarray, (lambda y: y)
+    else:
+        info["reorder"] = "gather"
+        glob_in, glob_out = _pin, _pout
+
+    def _count():
+        obs.count("reorder.sharded" if rd is not None else "reorder.gather")
+
+    def _local(args):
+        """A shard's matvec and reorder arrays from its structure."""
+        mine = tuple(a[0] for a in args)
+        return _mk_mv(mine[:nmats], S), mine[nmats:]
+
+    def _enter(x, rl):
+        """Vertex order -> partition order (padded to dl), on a shard."""
+        if rd is not None:
+            x = sharded_reorder(x, rl[:nr], rl[2 * nr], r_off, axis, S)
+        return ops.pad_trailing(x, dl)
+
+    def _leave(y, rl):
+        """Partition order -> vertex order (logical nl), on a shard."""
+        y = ops.crop(y, nl)
+        if rd is not None:
+            y = sharded_reorder(y, rl[nr:2 * nr], rl[2 * nr + 1], r_off,
+                                axis, S, back=True)
+        return y
 
     def _sig_spec(ndim: int) -> P:
         return rules.spec(*([None] * (ndim - 1)), "vertex")
 
-    def apply(f: Array) -> Array:
+    def apply(structure, f: Array) -> Array:
+        _count()
+
         def run(*args):
-            mv = _mk_mv(tuple(a[0] for a in args[:len(mats)]), S)
-            xl, c = args[len(mats):]
-            out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, dl),
-                                            c, lmax, use_pallas=use_pallas)
-            return ops.crop(out, nl)
+            mv, rl = _local(args[:ns])
+            xl, c = args[ns:]
+            out = ops.fused_cheb_recurrence(mv, _enter(xl, rl), c, lmax,
+                                            use_pallas=use_pallas)
+            return _leave(out, rl)
 
         c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
-        out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
-                       _sig_spec(f.ndim + 1))(*mats, _pin(f), c2)
-        return _pout(out)
+        out = _sharded(run, mesh, struct_specs + (_sig_spec(f.ndim), P()),
+                       _sig_spec(f.ndim + 1))(*structure, glob_in(f), c2)
+        return glob_out(out)
 
-    def apply_adjoint(a: Array) -> Array:
+    def apply_adjoint(structure, a: Array) -> Array:
+        _count()
+
         def run(*args):
-            mv = _mk_mv(tuple(x[0] for x in args[:len(mats)]), S)
-            al, c = args[len(mats):]
-            out = cheb.cheb_apply_adjoint(mv, ops.pad_trailing(al, dl),
-                                          c, lmax)
-            return ops.crop(out, nl)
+            mv, rl = _local(args[:ns])
+            al, c = args[ns:]
+            out = cheb.cheb_apply_adjoint(mv, _enter(al, rl), c, lmax)
+            return _leave(out, rl)
 
         c = jnp.asarray(coeffs, a.dtype)
-        out = _sharded(run, mesh, mat_specs + (_sig_spec(a.ndim), P()),
-                       _sig_spec(a.ndim - 1))(*mats, _pin(a), c)
-        return _pout(out)
+        out = _sharded(run, mesh, struct_specs + (_sig_spec(a.ndim), P()),
+                       _sig_spec(a.ndim - 1))(*structure, glob_in(a), c)
+        return glob_out(out)
 
-    def apply_gram(f: Array) -> Array:
+    def apply_gram(structure, f: Array) -> Array:
+        _count()
+
         def run(*args):
-            mv = _mk_mv(tuple(x[0] for x in args[:len(mats)]), S)
-            xl, d = args[len(mats):]
-            out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, dl),
-                                            d, lmax, use_pallas=use_pallas)
-            return ops.crop(out[..., 0, :], nl)
+            mv, rl = _local(args[:ns])
+            xl, d = args[ns:]
+            out = ops.fused_cheb_recurrence(mv, _enter(xl, rl), d, lmax,
+                                            use_pallas=use_pallas)
+            return _leave(out[..., 0, :], rl)
 
         d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
-        out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
-                       _sig_spec(f.ndim))(*mats, _pin(f), d)
-        return _pout(out)
+        out = _sharded(run, mesh, struct_specs + (_sig_spec(f.ndim), P()),
+                       _sig_spec(f.ndim))(*structure, glob_in(f), d)
+        return glob_out(out)
 
-    def solve_lasso(y, mu, gamma, n_iters):
+    def solve_lasso(structure, y, mu, gamma, n_iters):
+        _count()
+
         def run(*args):
-            mv = _mk_mv(tuple(x[0] for x in args[:len(mats)]), S)
-            yl, c, thresh = args[len(mats):]
-            phi_y = ops.fused_cheb_recurrence(mv, ops.pad_trailing(yl, dl),
-                                              c, lmax,
+            mv, rl = _local(args[:ns])
+            yl, c, thresh = args[ns:]
+            phi_y = ops.fused_cheb_recurrence(mv, _enter(yl, rl), c, lmax,
                                               use_pallas=use_pallas)
 
             def body(a, _):
@@ -1097,23 +1311,24 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             a0 = jnp.zeros_like(phi_y)
             a_star, _ = jax.lax.scan(body, a0, None, length=n_iters)
             y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
-            return a_star[..., :nl], y_star[..., :nl]
+            return _leave(a_star, rl), _leave(y_star, rl)
 
         c = jnp.asarray(coeffs, y.dtype)
         thresh = _mu_threshold(mu, op.eta, y.dtype, gamma)
         a_star, y_star = _sharded(
-            run, mesh, mat_specs + (_sig_spec(y.ndim), P(), P()),
+            run, mesh, struct_specs + (_sig_spec(y.ndim), P(), P()),
             (_sig_spec(y.ndim + 1), _sig_spec(y.ndim)),
-        )(*mats, _pin(y), c, thresh)
-        return LassoResult(coeffs=_pout(a_star), signal=_pout(y_star),
+        )(*structure, glob_in(y), c, thresh)
+        return LassoResult(coeffs=glob_out(a_star), signal=glob_out(y_star),
                            objective=jnp.nan, n_iters=n_iters, fused=True)
 
-    def matvec_runner(fn, signals, consts=()):
+    def matvec_runner(structure, fn, signals, consts=()):
         # Section-V solver substrate under the general partition: signals
         # (incl. vertex-indexed solver state such as Jacobi's 1/diag) are
-        # permuted into partition order, padded, sharded; outputs crop and
-        # permute back — so solver bodies are partition-agnostic.
-        pinned = tuple(_pin(s) for s in signals)
+        # moved into partition order and padded on each shard; outputs
+        # crop and move back — so solver bodies are partition-agnostic.
+        _count()
+        pinned = tuple(glob_in(s) for s in signals)
         local = tuple(
             jax.ShapeDtypeStruct(s.shape[:-1] + (dl,), s.dtype)
             for s in pinned)
@@ -1121,25 +1336,32 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             lambda *a: jax.tree.map(
                 lambda o: o[..., :nl], fn(lambda v: v, *a)),
             *local, *consts)
-        in_specs = (mat_specs
+        in_specs = (struct_specs
                     + tuple(_sig_spec(s.ndim) for s in pinned)
                     + tuple(P() for _ in consts))
         out_specs = jax.tree.map(lambda sd: _sig_spec(len(sd.shape)),
                                  out_sds)
 
         def run(*args):
-            mv = _mk_mv(tuple(x[0] for x in args[:len(mats)]), S)
-            rest = args[len(mats):]
-            sigs = tuple(ops.pad_trailing(s, dl)
-                         for s in rest[:len(pinned)])
+            mv, rl = _local(args[:ns])
+            rest = args[ns:]
+            sigs = tuple(_enter(s, rl) for s in rest[:len(pinned)])
             outs = fn(mv, *sigs, *rest[len(pinned):])
-            return jax.tree.map(lambda o: ops.crop(o, nl), outs)
+            return jax.tree.map(lambda o: _leave(o, rl), outs)
 
         outs = _sharded(run, mesh, in_specs, out_specs)(
-            *mats, *pinned, *consts)
-        return jax.tree.map(_pout, outs)
+            *structure, *pinned, *consts)
+        return jax.tree.map(glob_out, outs)
 
-    return ExecutionPlan(op=op, backend=backend_name, apply=apply,
-                         apply_adjoint=apply_adjoint, apply_gram=apply_gram,
-                         solve_lasso_fn=solve_lasso,
-                         matvec_runner=matvec_runner, info=info)
+    def over(structure):
+        def bind(fn):
+            return functools.partial(fn, structure)
+
+        return ExecutionPlan(op=op, backend=backend_name, apply=bind(apply),
+                             apply_adjoint=bind(apply_adjoint),
+                             apply_gram=bind(apply_gram),
+                             solve_lasso_fn=bind(solve_lasso),
+                             matvec_runner=bind(matvec_runner), info=info,
+                             structure=structure, over=over)
+
+    return over(structure)

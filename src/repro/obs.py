@@ -12,6 +12,8 @@ operation.  Each op belongs to its innermost ``repro.*`` scope:
     sweep       a single-launch sweep kernel (or its reference)
     recurrence  the per-order loop's own arithmetic
     exchange    halo tiles packed, permuted, unpacked and masked
+    reorder     a sharded plan's signals moved between vertex and
+                partition order, shard to shard
 
 ``count`` / ``add`` keep process-wide counters in memory; ``snapshot()``
 reads them.  A path change (the sweep falling back to the per-order

@@ -156,3 +156,36 @@ print("OK")
 
 def test_message_scaling_exact_8_shards():
     assert "OK" in run_payload(PAYLOAD, n_devices=8)
+
+
+def _bfs_order_by_scans(csr):
+    """The BFS order written as a scan of every vertex per restart: the
+    reference for the partitioner's forward-moving restart pointer."""
+    n = csr.n
+    deg = np.diff(csr.indptr)
+    visited = np.zeros(n, bool)
+    order = []
+    while len(order) < n:
+        unv = np.flatnonzero(~visited)
+        frontier = np.array([unv[np.argmin(deg[unv])]])
+        visited[frontier] = True
+        while frontier.size:
+            order.extend(frontier.tolist())
+            nbr = pm._ragged_gather(csr.indptr, csr.indices, frontier)
+            frontier = np.unique(nbr[~visited[nbr]])
+            visited[frontier] = True
+    return np.array(order)
+
+
+@pytest.mark.parametrize("n, seed", [(300, 0), (5000, 1), (9000, 2)])
+def test_bfs_restarts_take_least_degree_then_least_id(n, seed):
+    # components of many sizes and isolated vertices, so the BFS
+    # restarts thousands of times
+    rng = np.random.default_rng(seed)
+    lo = rng.integers(0, n, n // 2)
+    hi = rng.integers(0, n, n // 2)
+    keep = lo != hi
+    rows = np.concatenate([lo[keep], hi[keep], np.arange(n)])
+    cols = np.concatenate([hi[keep], lo[keep], np.arange(n)])
+    csr = pm.CSRMatrix.from_coo(n, rows, cols, np.ones(rows.size, np.float32))
+    assert np.array_equal(pm.edge_cut_order(csr, 4), _bfs_order_by_scans(csr))

@@ -3,7 +3,8 @@
 Each test lowers and compiles one Pallas kernel at a real width against
 the described (not attached) ``v5e:2x2`` topology, so whatever Mosaic
 refuses — a slice off the (8, 128) tiling, too much VMEM or SMEM — fails
-here without a chip.  Nothing runs.  The topology is described inside a
+here without a chip; one compiles the four-chip sharded reorder over the
+whole host and checks what it holds on each chip.  Nothing runs.  The topology is described inside a
 module fixture (never at import: only one process may load the TPU
 library), and the tests skip when it cannot be described.
 """
@@ -30,10 +31,9 @@ SENSOR_BAND = 265
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -44,8 +44,25 @@ def one_chip():
     # persistent cache, so keep it out of the cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described host's four chips as one 1-D "graph" mesh."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    return Mesh(np.array(topo.devices), ("graph",),
+                axis_types=(AxisType.Auto,))
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -135,3 +152,72 @@ def test_jacobi_step_compiles_at_chip_scale(one_chip):
     _compile(lambda q, x, xp, y, d: jacobi_step(q, x, xp, y, d, w=1.0,
                                                  s=0.0),
              it, it, it, it, _spec(one_chip, (N_CHIP,)))
+
+
+def test_sharded_reorder_compiles_at_chip_scale(four_chips):
+    """The move of a four-chip sensor field's (B, eta, N) result from
+    partition order back to vertex order, N = 4e6, B = 64, eta = 7, every
+    offset's tile as wide as the largest pair of that cell (449,628 rows):
+    no all-gather, and the move's own buffers stay under 4 GB a chip
+    beside the result it reads and the one it writes."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.dist.partition import sharded_reorder
+
+    S, nl, h, offsets = 4, 1_000_000, 449_628, (1, 2, 3)
+    spec = P("graph")
+    sig = NamedSharding(four_chips, P(None, None, "graph"))
+
+    def back(y, *plan):
+        def run(yl, *pl):
+            mine = [a[0] for a in pl]
+            return sharded_reorder(yl, mine[:3], mine[3], offsets, "graph",
+                                   S, back=True)
+
+        return jax.shard_map(run, mesh=four_chips,
+                             in_specs=(P(None, None, "graph"),) + (spec,) * 4,
+                             out_specs=P(None, None, "graph"),
+                             check_vma=False)(y, *plan)
+
+    idx = [_spec(NamedSharding(four_chips, spec), (S, h), jnp.int32)] * 3
+    place = _spec(NamedSharding(four_chips, spec), (S, nl), jnp.int32)
+    compiled = jax.jit(back).lower(_spec(sig, (64, 7, S * nl)), *idx,
+                                   place).compile()
+    text = compiled.as_text()
+    assert "all-gather" not in text
+    assert "collective-permute" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4e9, mem
+
+
+def test_sharded_solve_takes_its_structure_as_arguments(four_chips,
+                                                         monkeypatch):
+    """A four-chip general plan's compiled Jacobi solve, shapes only: its
+    structure is laid out as shapes on the described chips, so the solve
+    lowers only if it takes the structure as arguments; nothing is
+    gathered whole and each chip's arguments hold its shard of it."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import graph
+    from repro.core.wavelets import sgwt_multipliers
+    from repro.dist import GraphOperator
+    from repro.dist import partition as pm
+
+    g, _ = graph.connected_sensor_graph(jax.random.PRNGKey(0), n=64,
+                                        theta=0.3, kappa=0.35)
+    L = np.asarray(g.laplacian(), np.float32)
+    lmax = float(g.lambda_max_bound())
+    op = GraphOperator(P=L, multipliers=sgwt_multipliers(lmax, 2),
+                       lmax=lmax, K=6)
+    parts = pm.partition_general(L, 4, block=(8, 8))
+    monkeypatch.setattr(jax, "device_put",
+                        lambda a, s: _spec(s, np.shape(a), a.dtype))
+    plan = op.plan("pallas_halo", mesh=four_chips, partition=parts)
+    monkeypatch.undo()
+    shard_bytes = sum(np.prod(s.shape) * s.dtype.itemsize
+                      for s in plan.structure) // 4
+    y = _spec(NamedSharding(four_chips, P(None, "graph")), (3, 64))
+    compiled = plan.compiled_solve("jacobi", tau=0.5).lower(y).compile()
+    assert "all-gather" not in compiled.as_text()
+    assert compiled.memory_analysis().argument_size_in_bytes >= shard_bytes
