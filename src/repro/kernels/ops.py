@@ -293,7 +293,8 @@ def _cheb_recurrence_loop(
     lmax: float,
     use_pallas: Optional[bool] = None,
 ) -> Array:
-    """The per-order recurrence loop (one matvec + one fused step/order).
+    """The per-order recurrence loop (one matvec + one fused step/order),
+    with the coefficient sum deferred: see :func:`_recurrence`.
 
     Supports the dual-signature stateful-matvec protocol of
     `core.chebyshev._stateful_matvec` (the int8 error-feedback halo
@@ -305,40 +306,86 @@ def _cheb_recurrence_loop(
 
 
 def _recurrence(matvec, x, coeffs, lmax, use_pallas):
+    """sum_k c_{j,k} T_k x as (..., eta, n), folded into the accumulator
+    once a chunk of s = eta + 1 orders instead of once an order.
+
+    The iterates of a chunk are held, each its own buffer, and the order
+    that closes the chunk folds them all in one `cheb_step` launch; the
+    other orders compute t_k alone.  With s = eta + 1 the s - 1 held
+    iterates take at most the accumulator's own memory.  The first chunk
+    (at least T_0..T_2) writes the accumulator without reading it; full
+    chunks run in a `lax.scan`, their orders unrolled, and a partial
+    chunk closes the sum.  Inside the loop the accumulator is
+    multiplier-major, (eta, ..., n), as `cheb_step` folds into it.
+    """
     use, interp = _resolve(use_pallas)
     from ..core.chebyshev import _stateful_matvec
 
     c = jnp.atleast_2d(jnp.asarray(coeffs, dtype=x.dtype))
-    K = c.shape[1] - 1
+    eta, K1 = c.shape
+    K = K1 - 1
     alpha = float(lmax) / 2.0
 
-    t0 = x
-    acc = 0.5 * c[:, 0:1] * x[..., None, :]
-    if K == 0:
-        return acc
+    w = c.at[:, 0].multiply(0.5)         # the weight of each T_k
     mv2, st = _stateful_matvec(matvec, x)
+    if K < 2:                            # no order to fold: c_0 T_0 + c_1 T_1
+        acc = w[:, 0:1] * x[..., None, :]
+        if K == 1:
+            px, _ = mv2(x, st)
+            acc = acc + w[:, 1:2] * (px / alpha - x)[..., None, :]
+        return acc
     px, st = mv2(x, st)
     t1 = px / alpha - x
-    acc = acc + c[:, 1:2] * t1[..., None, :]
-    if K == 1:
-        return acc
 
-    def body(carry, ck):
-        t_km1, t_km2, acc, st = carry
-        pt, st = mv2(t_km1, st)
+    s = eta + 1
+    obs.count("step.fold")
+    obs.add("step.fold_width", s)
+
+    def step(pt, t_km1, t_km2, acc=None, coef=None, held=()):
         with obs.scope("step"):
             if use:
-                tk, acc = cheb_step(pt, t_km1, t_km2, acc, ck,
-                                    alpha=alpha, interpret=interp)
-            else:
-                tk, acc = ref.cheb_step_ref(pt, t_km1, t_km2, acc, ck,
-                                            alpha=alpha)
-        return (tk, t_km1, acc, st), None
+                return cheb_step(pt, t_km1, t_km2, acc, coef, alpha=alpha,
+                                 held=held, interpret=interp)
+            return ref.cheb_step_ref(pt, t_km1, t_km2, acc, coef,
+                                     alpha=alpha, held=held)
 
+    def chunk(t_km1, t_km2, held, acc, st, coef):
+        """The orders that complete a chunk of coef.shape[1] terms whose
+        first iterates are `held`; the last order folds them all."""
+        held = list(held)
+        for _ in range(coef.shape[1] - len(held) - 1):
+            pt, st = mv2(t_km1, st)
+            tk = step(pt, t_km1, t_km2)
+            held.append(tk)
+            t_km2, t_km1 = t_km1, tk
+        pt, st = mv2(t_km1, st)
+        tk, acc = step(pt, t_km1, t_km2, acc, coef, tuple(held))
+        return tk, t_km1, acc, st
+
+    first = min(K, max(s - 1, 2))        # the order that closes chunk 0
+    t_km1, t_km2, acc, st = chunk(t1, x, (x, t1), None, st,
+                                  w[:, :first + 1])
+    n_full, rest = divmod(K - first, s)
+    if n_full:
+        with obs.scope("layout"):
+            chunks = w[:, first + 1:first + 1 + n_full * s].reshape(
+                eta, n_full, s).transpose(1, 0, 2)
+
+        def body(carry, coef):
+            t_km1, t_km2, acc, st = carry
+            return chunk(t_km1, t_km2, (), acc, st, coef), None
+
+        (t_km1, t_km2, acc, st), _ = jax.lax.scan(
+            body, (t_km1, t_km2, acc, st), chunks)
+    if rest:
+        _, _, acc, _ = chunk(t_km1, t_km2, (), acc, st, w[:, K - rest + 1:])
+    return _multiplier_minor(acc)
+
+
+def _multiplier_minor(acc: Array) -> Array:
+    """(eta, ..., n) -> (..., eta, n), the layout callers take."""
     with obs.scope("layout"):
-        orders = c[:, 2:].T
-    (_, _, acc, _), _ = jax.lax.scan(body, (t1, t0, acc, st), orders)
-    return acc
+        return jnp.moveaxis(acc, 0, -2)
 
 
 def fused_cheb_apply(

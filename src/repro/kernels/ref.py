@@ -1,6 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
 import jax.numpy as jnp
 
@@ -18,11 +20,28 @@ def block_ell_spmv_ref(blocks: Array, indices: Array, x: Array) -> Array:
     return y.reshape(x.shape[:-1] + (nrb * br,))
 
 
-def cheb_step_ref(pt: Array, t_km1: Array, t_km2: Array, acc: Array,
-                  coef: Array, *, alpha: float):
-    """pt/t_km1/t_km2: (..., n); acc: (..., eta, n); coef: (eta,)."""
+def cheb_fold_ref(terms: Sequence[Array], coef: Array,
+                  acc: Optional[Array] = None) -> Array:
+    """acc[j] + sum_i coef[j, i] * terms[i]: terms (..., n) each, coef
+    (eta, len(terms)), acc (eta, ..., n) or None (a fold with nothing to
+    add to); the terms are added in order, as `cheb_step` adds them."""
+    for i, t in enumerate(terms):
+        term = coef[:, i].reshape((-1,) + (1,) * t.ndim) * t[None]
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def cheb_step_ref(pt: Array, t_km1: Array, t_km2: Array,
+                  acc: Optional[Array] = None, coef: Optional[Array] = None,
+                  *, alpha: float, held: Sequence[Array] = ()):
+    """`cheb_step`'s oracle: t_k, or (t_k, fold) when `coef` ((eta,) or
+    (eta, len(held) + 1)) is given; pt/t_km1/t_km2/held: (..., n); acc:
+    (eta, ..., n) or None."""
     tk = (2.0 / alpha) * pt - 2.0 * t_km1 - t_km2
-    return tk, acc + coef[:, None] * tk[..., None, :]
+    if coef is None:
+        return tk
+    coef = coef.reshape(coef.shape[0], -1)
+    return tk, cheb_fold_ref(tuple(held) + (tk,), coef, acc)
 
 
 #: Above this padded size the sweep oracles keep the gather-based Block-ELL

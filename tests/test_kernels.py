@@ -163,15 +163,121 @@ def test_cheb_step_sweep(n, eta):
                                          ((2, 3), 120, 3)])
 def test_cheb_step_batched_ragged(batch, n, eta):
     """Batched iterates on a cdiv grid: ragged lane and batch edge tiles
-    (n not a 128 multiple, B > one 64-row tile) match the oracle."""
+    (n not a 128 multiple, B > one 64-row tile) and the multiplier-major
+    (eta, ..., n) accumulator match the oracle."""
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     pt, t1, t2 = (jax.random.normal(k, batch + (n,)) for k in ks[:3])
-    acc = jax.random.normal(ks[3], batch + (eta, n))
+    acc = jax.random.normal(ks[3], (eta,) + batch + (n,))
     coef = jax.random.normal(ks[4], (eta,))
     tk_k, acc_k = cheb_step(pt, t1, t2, acc, coef, alpha=0.9, interpret=True)
     tk_r, acc_r = ref.cheb_step_ref(pt, t1, t2, acc, coef, alpha=0.9)
     np.testing.assert_allclose(np.asarray(tk_k), np.asarray(tk_r), atol=1e-5)
     np.testing.assert_allclose(np.asarray(acc_k), np.asarray(acc_r), atol=1e-5)
+
+
+def one_term_per_order(matvec, x, c, lmax):
+    """The schedule before the deferred fold, written out: every order
+    adds its one term c_{j,k} T_k into the whole accumulator."""
+    mv2, st = cheb._stateful_matvec(matvec, x)
+    alpha = lmax / 2.0
+    acc = 0.5 * c[:, 0:1] * x[..., None, :]
+    px, st = mv2(x, st)
+    t_km2, t_km1 = x, px / alpha - x
+    acc = acc + c[:, 1:2] * t_km1[..., None, :]
+    for k in range(2, c.shape[1]):
+        pt, st = mv2(t_km1, st)
+        tk = (2.0 / alpha) * pt - 2.0 * t_km1 - t_km2
+        acc = acc + c[:, k:k + 1] * tk[..., None, :]
+        t_km2, t_km1 = t_km1, tk
+    return acc
+
+
+def dense_matvec(n, seed=0):
+    """t -> t @ A for a symmetric A with its spectrum inside [0, 4]."""
+    a = jax.random.normal(jax.random.PRNGKey(seed), (n, n)) / np.sqrt(n)
+    A = 0.5 * (a + a.T) + 2.0 * jnp.eye(n)
+    return lambda t: jnp.dot(t, A, precision="highest")
+
+
+def error_feedback_matvec(n):
+    """A stateful matvec (the int8 exchange's protocol): each order sends
+    its iterate rounded to 1/64 plus the rounding left over before."""
+    mv = dense_matvec(n)
+
+    def stateful(t, r):
+        v = t + r
+        q = jnp.round(v * 64.0) / 64.0
+        return mv(q), v - q
+
+    stateful.init_state = jnp.zeros_like
+    return stateful
+
+
+def assert_same_sum(got, want):
+    """Within 1e-6 of the largest entry (`max_rel_err`'s measure): the
+    fold adds the same f32 products in another order."""
+    want = np.asarray(want)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                               atol=1e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "reference"])
+@pytest.mark.parametrize("batch", [(), (3,), (2, 5)])
+@pytest.mark.parametrize("K", [2, 3, 8, 9, 20])
+@pytest.mark.parametrize("eta", [1, 4, 7])
+def test_deferred_fold_matches_one_term_per_order(eta, K, batch, use_pallas):
+    """s = eta + 1 terms a fold: K = 2 and 3 end inside the first chunk
+    or just past it, 8 and 9 give exact and partial chunks at eta = 7,
+    20 gives 8 + 8 + 5 there and 3 + 9 x 2 at eta = 1."""
+    n = 200
+    mv = dense_matvec(n)
+    x = jax.random.normal(jax.random.PRNGKey(1), batch + (n,))
+    c = jax.random.normal(jax.random.PRNGKey(2), (eta, K + 1))
+    got = ops._cheb_recurrence_loop(mv, x, c, 4.0, use_pallas=use_pallas)
+    assert_same_sum(got, one_term_per_order(mv, x, c, 4.0))
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "reference"])
+def test_deferred_fold_threads_matvec_state(use_pallas):
+    """A stateful matvec's state rides the unrolled chunks and the scan
+    carry in the order of the matvecs, as it did one order at a time."""
+    n, eta, K = 200, 3, 13
+    mv = error_feedback_matvec(n)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, n))
+    c = jax.random.normal(jax.random.PRNGKey(4), (eta, K + 1))
+    got = ops._cheb_recurrence_loop(mv, x, c, 4.0, use_pallas=use_pallas)
+    want = one_term_per_order(mv, x, c, 4.0)
+    assert_same_sum(got, want)
+    plain = one_term_per_order(dense_matvec(n), x, c, 4.0)
+    assert float(jnp.abs(want - plain).max()) > 1e-3
+
+
+def test_cheb_step_fold_reads_each_iterate_once():
+    """On the order that closes a chunk the iterates held for the fold
+    that are also t_{k-1} and t_{k-2} are one operand each, and the fold
+    matches its oracle with and without an accumulator to read."""
+    ks = jax.random.split(jax.random.PRNGKey(6), 7)
+    held = tuple(jax.random.normal(k, (3, 300)) for k in ks[:4])
+    pt = jax.random.normal(ks[4], (3, 300))
+    acc = jax.random.normal(ks[5], (2, 3, 300))
+    coef = jax.random.normal(ks[6], (2, 5))
+    for a in (None, acc):
+        got = cheb_step(pt, held[-1], held[-2], a, coef, alpha=1.1,
+                        held=held, interpret=True)
+        want = ref.cheb_step_ref(pt, held[-1], held[-2], a, coef, alpha=1.1,
+                                 held=held)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-6, atol=1e-6)
+    jaxpr = jax.make_jaxpr(lambda *h: cheb_step(
+        pt, h[-1], h[-2], None, coef, alpha=1.1, held=h, interpret=True))(
+        *held)
+    (call,) = [e for e in jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(call.invars) == 1 + 1 + len(held)     # coef, pt, held
 
 
 @pytest.mark.parametrize("eta,n", [(2, 1024), (5, 1280)])

@@ -117,6 +117,26 @@ def test_sweep_path_counted_once_per_trace(op120, budget, counted):
     assert other not in obs.snapshot()
 
 
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_deferred_fold_counted_once_per_trace(op120, path):
+    """The per-order loop counts its fold, of eta + 1 terms, once a
+    trace; the single-launch sweep bypasses it."""
+    plan = PATHS[path][0](op120)
+    x = jnp.ones((2, 120), jnp.float32)
+    eta = len(op120.multipliers)
+    obs.reset()
+    for traces in (1, 2):
+        jax.jit(lambda v: plan.apply(v)).lower(x)  # a new function
+        snap = obs.snapshot()
+        if path == "per_order":
+            assert snap.get("step.fold") == traces
+            assert snap["step.fold_width"] == traces * (eta + 1)
+            assert "cheb_sweep.launch" not in snap
+        else:
+            assert snap.get("cheb_sweep.launch") == traces
+            assert "step.fold" not in snap
+
+
 def test_compiles_counted_at_compile_time_only():
     f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 0.25)
     x = jnp.arange(17.0)

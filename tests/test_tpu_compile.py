@@ -144,7 +144,31 @@ def test_block_ell_spmv_window_compiles_at_chip_scale(one_chip):
 def test_cheb_step_compiles_at_chip_scale(one_chip):
     it = _spec(one_chip, (64, N_CHIP))
     _compile(lambda *a: cheb_step(*a, alpha=2.0), it, it, it,
-             _spec(one_chip, (64, ETA, N_CHIP)), _spec(one_chip, (ETA,)))
+             _spec(one_chip, (ETA, 64, N_CHIP)), _spec(one_chip, (ETA,)))
+
+
+@pytest.mark.parametrize("case", ["plain", "first_fold", "fold"])
+def test_cheb_step_fold_compiles_at_chip_scale(one_chip, case):
+    """sensor1m's orders (B = 64, N = 1e6, eta = 7): a plain order, the
+    fold that writes the accumulator and the fold that reads it, each
+    over the chunk's 7 held iterates, fit VMEM and tile as Mosaic asks,
+    and the (eta, B, N) accumulator is updated where it lies."""
+    eta = 7
+    it = _spec(one_chip, (64, N_CHIP))
+    acc = _spec(one_chip, (eta, 64, N_CHIP))
+    coef = _spec(one_chip, (eta, eta + 1))
+
+    def order(pt, acc, coef, *held):
+        if case == "plain":
+            return cheb_step(pt, held[-1], held[-2], alpha=2.0)
+        return cheb_step(pt, held[-1], held[-2],
+                         acc if case == "fold" else None, coef, alpha=2.0,
+                         held=held)
+
+    compiled = jax.jit(order, donate_argnums=1).lower(
+        it, acc, coef, *[it] * eta).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_jacobi_step_compiles_at_chip_scale(one_chip):
