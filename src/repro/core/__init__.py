@@ -1,18 +1,8 @@
 """Core library: the paper's contribution (Chebyshev graph multipliers).
 
-`repro.core.distributed` is a deprecated shim over repro.dist.backends and
-is intentionally not imported eagerly (importing it emits the deprecation
-warning); `from repro.core import distributed` still works.
+The sharded forms live in :mod:`repro.dist` (``GraphOperator.plan``).
 """
 from . import arma, chebyshev, filters, graph, jacobi, lasso, ssl, wavelets
-
-
-def __getattr__(name):  # PEP 562: keep `repro.core.distributed` working
-    if name == "distributed":
-        import importlib
-
-        return importlib.import_module(".distributed", __name__)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 from .chebyshev import (
     cheb_apply,
     cheb_apply_adjoint,
@@ -27,7 +17,7 @@ from .multiplier import ScalarMultiplier, UnionMultiplier, graph_multiplier
 from .wavelets import sgwt_multipliers, sgwt_operator
 
 __all__ = [
-    "arma", "chebyshev", "distributed", "filters", "graph", "jacobi",
+    "arma", "chebyshev", "filters", "graph", "jacobi",
     "lasso", "ssl", "wavelets",
     "cheb_apply", "cheb_apply_adjoint", "cheb_apply_gram", "cheb_coeffs",
     "cheb_coeffs_stack", "cheb_eval", "gram_coeffs",

@@ -18,32 +18,62 @@ Tbar_k(x) = T_k((x - alpha)/alpha), alpha = lmax/2, on x in [0, lmax].
 """
 from __future__ import annotations
 
-from typing import Callable, Sequence, Union
+import dataclasses
+from typing import Callable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .graph import BlockELL
+
 Array = jax.Array
 MatVec = Callable[[Array], Array]
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class LocalMatvec:
+    """A plan's local matvec and what the recurrences may know about it.
+
+    fn: ``x -> y`` applying P along the last axis, or, with `init_state`,
+        the dual-signature ``fn(x) -> y`` / ``fn(x, state) -> (y, state)``
+        of a matvec that carries state across orders (the int8
+        error-feedback exchange, the fault injector's round counter).
+    block_ell: the `core.graph.BlockELL` P is, set only where the product
+        is purely local (no collectives): the single-launch sweep kernels
+        may then run the whole recurrence or solve
+        (`kernels.ops.fused_cheb_recurrence`, `dist.solvers`).
+    vmem_budget: the sweep kernels' VMEM guard (None: their default).
+    sweep_dtype: the sweep kernels' scratch dtype (None: float32).
+    init_state: ``x -> state`` for a stateful `fn`, else None.
+
+    Calling the record calls `fn`.
+    """
+
+    fn: Callable
+    block_ell: Optional[BlockELL] = None
+    vmem_budget: Optional[int] = None
+    sweep_dtype: Optional[str] = None
+    init_state: Optional[Callable] = None
+
+    def __call__(self, x, *state):
+        return self.fn(x, *state)
 
 
 def _stateful_matvec(matvec: MatVec, x: Array):
     """Adapt `matvec` to the dual-signature stateful protocol.
 
-    Matvecs that carry cross-order state (the int8 error-feedback halo
-    exchange in `repro.dist.quantize` / `dist.backends.halo`) expose an
-    ``init_state(x)`` attribute and accept ``matvec(x, state) ->
-    (y, state)``.  Plain matvecs keep their stateless signature and get
-    an empty-state shim, so every recurrence below threads state
-    uniformly through its scan carry at zero cost for the common case.
+    A :class:`LocalMatvec` with an `init_state` accepts ``matvec(x,
+    state) -> (y, state)``; every other matvec keeps its stateless
+    signature and gets an empty-state shim, so every recurrence below
+    threads state uniformly through its scan carry at zero cost for the
+    common case.
 
     Returns ``(mv2, state0)`` with ``mv2(v, s) -> (y, s')``.
     """
-    init_state = getattr(matvec, "init_state", None)
-    if init_state is None:
-        return (lambda v, s: (matvec(v), s)), ()
-    return matvec, init_state(x)
+    if isinstance(matvec, LocalMatvec) and matvec.init_state is not None:
+        return matvec, matvec.init_state(x)
+    return (lambda v, s: (matvec(v), s)), ()
 
 
 # ---------------------------------------------------------------------------

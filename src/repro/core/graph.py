@@ -375,7 +375,7 @@ def spatial_sort(graph: Graph) -> Tuple[Graph, np.ndarray]:
     adjacent vertices differ in y-rank by at most the population of a
     kappa-height strip, so equal contiguous index blocks of size
     nl >> n*kappa couple only with adjacent blocks: W becomes block-
-    tridiagonal and the sharded halo path of `core.distributed` is exact
+    tridiagonal and the sharded banded `halo` plan is exact
     (`partition_banded` reports the residual `leak` so callers can verify).
     """
     assert graph.coords is not None, "spatial_sort needs coordinates"

@@ -96,7 +96,7 @@ def distributed_lasso(
     `op` may be a UnionMultiplier/GraphOperator or an already-built
     ExecutionPlan; passing `backend=` (plus `mesh=` for sharded backends)
     plans the operator here — `backend="halo"` runs the whole ISTA loop
-    inside one shard_map (repro.dist.backends.halo.dist_lasso).
+    inside one shard_map (`repro.dist.operator.plan_from_form`).
 
     The whole ISTA loop is a single lax.scan whose body applies
     Phi~ Phi~* (2*K matvecs via Algorithms 2+1) plus local shrinkage — the

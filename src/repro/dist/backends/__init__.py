@@ -12,6 +12,11 @@ plug in without touching any caller:
         ...
         return ExecutionPlan(op=op, backend="my-backend", ...)
 
+The built-in backends each describe a local form (`repro.dist.operator.
+LocalForm`: the local matvec record, the recurrence, how signals enter
+and leave a shard) and get their plan from the one plan body,
+`repro.dist.operator.plan_from_form`.
+
 Built-in backends (imported at the bottom so their decorators run):
   dense       — matvec against P as given (dense matrix or closure)
   pallas      — Block-ELL SpMV + fused Chebyshev-step Pallas kernels
@@ -50,9 +55,8 @@ def available_backends() -> List[str]:
     return sorted(_REGISTRY)
 
 
-# Import order matters only in that halo must precede allgather and
-# pallas_halo (both reuse halo's shard_map wrapper / partition machinery).
-# Each import registers its builder.
+# Import order matters only in that halo must precede pallas_halo (which
+# reuses halo's banded partition).  Each import registers its builder.
 from . import dense        # noqa: E402,F401
 from . import pallas       # noqa: E402,F401
 from . import halo         # noqa: E402,F401

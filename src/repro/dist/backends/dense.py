@@ -4,46 +4,21 @@ P may be a dense matrix or a matvec closure (applying P along the *last*
 axis, broadcasting over leading batch dims); this is the single-device
 reference path (what `UnionMultiplier.apply` always did) wrapped in the
 uniform ExecutionPlan signature, including the batched (..., N) contract.
+The logical N is the execution domain: no padding, no cropping.
 """
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 from ...core import chebyshev as cheb
 from . import register_backend
 
-Array = jax.Array
-
 
 @register_backend("dense")
 def build(op, *, mesh=None, partition=None, **options):
-    from ..operator import ExecutionPlan
+    from ..operator import LocalForm, plan_from_form
 
     del mesh, partition  # single-device backend
-    mv = op.matvec
-    coeffs = op.coeffs
-    lmax = op.lmax
-
-    def apply(f: Array) -> Array:
-        c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
-        return cheb.cheb_apply(mv, f, c2, lmax)
-
-    def apply_adjoint(a: Array) -> Array:
-        return cheb.cheb_apply_adjoint(mv, a, jnp.asarray(coeffs, a.dtype),
-                                       lmax)
-
-    def apply_gram(f: Array) -> Array:
-        return cheb.cheb_apply_gram(mv, f, coeffs, lmax)
-
-    def matvec_runner(fn, signals, consts=()):
-        # single-device reference: the logical N is the execution domain,
-        # so no padding/cropping and `mv` is P as given
-        return fn(mv, *signals, *consts)
-
-    return ExecutionPlan(
-        op=op, backend="dense",
-        apply=apply, apply_adjoint=apply_adjoint, apply_gram=apply_gram,
-        matvec_runner=matvec_runner,
-        info={"matvecs_per_apply": op.K},
-    )
+    mv = cheb.LocalMatvec(op.matvec)
+    form = LocalForm(local=lambda mine: mv, recurrence=cheb.cheb_apply,
+                     lasso=False)
+    return plan_from_form(op, "dense", form,
+                          info={"matvecs_per_apply": op.K})

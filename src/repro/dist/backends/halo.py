@@ -1,5 +1,5 @@
 """'halo' execution backend: sharded Algorithm 1/2/3 via shard_map with ring
-halo exchange (moved here from repro.core.distributed).
+halo exchange.
 
 TPU adaptation of the paper's distributed model (DESIGN.md §3): one device
 holds a contiguous *block* of vertices instead of one sensor holding one
@@ -19,28 +19,24 @@ wire carries 2h values per shard per order instead of the full 2·nl
 block.  The measured exchange-round count (and hence the paper-level
 2K|E| message count) is unchanged; only the payload shrinks.
 
-The free functions (`dist_cheb_apply` etc.) are the stable low-level API;
-:func:`build` packages them into an :class:`~repro.dist.operator.ExecutionPlan`
-for the `GraphOperator.plan(backend="halo")` path.
+:func:`build` hands the plan body (`repro.dist.operator.plan_from_form`)
+this matvec and the banded partition; the reference recurrence
+(`core.chebyshev.cheb_apply`) runs over it.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
 
 from ...core import chebyshev as cheb
-from ...core.lasso import soft_threshold
+from ...kernels import ops
 from .. import faults, quantize
 from ..sharding import auto_mesh
 from . import register_backend
-
-shard_map = jax.shard_map
 
 Array = jax.Array
 
@@ -160,15 +156,7 @@ def partition_banded(
 def pad_signal(x: Union[np.ndarray, Array], parts: BandedPartition) -> Array:
     """Zero-pad the trailing (vertex) axis up to the partition's padded size;
     leading batch / eta axes pass through untouched."""
-    from ...kernels.ops import pad_trailing
-
-    return pad_trailing(jnp.asarray(x), parts.n_padded)
-
-
-def _vspec(ndim: int, axis: str) -> P:
-    """PartitionSpec sharding only the last of `ndim` axes on `axis` —
-    batch / eta axes replicate, the vertex axis splits across shards."""
-    return P(*((None,) * (ndim - 1) + (axis,)))
+    return ops.pad_trailing(jnp.asarray(x), parts.n_padded)
 
 
 # ---------------------------------------------------------------------------
@@ -193,15 +181,16 @@ def _halo_matvec(diag, left, right, nl: int, h: int, axis: str,
     3. **decode + boundary coupling on arrival** — the received tiles
        widen back to the compute dtype, then two (nl, h) products.
 
-    Under ``exchange_dtype="int8"`` with ``error_feedback=True`` (and a
-    real multi-shard axis) the returned closure is *stateful-capable*:
-    ``mv(x)`` stays the plain stateless signature (plain quantize), while
-    ``mv(x, state) -> (y, state)`` threads the quantization residual of
-    each boundary tile into the next round, and ``mv.init_state(x)``
-    builds the zero residuals.  `core.chebyshev` / `kernels.ops` opt in
-    via ``getattr(matvec, "init_state", None)``.
+    Returns a `core.chebyshev.LocalMatvec`.  Under
+    ``exchange_dtype="int8"`` with ``error_feedback=True`` (and a real
+    multi-shard axis) it is *stateful-capable*: ``mv(x)`` stays the plain
+    stateless signature (plain quantize), while ``mv(x, state) -> (y,
+    state)`` threads the quantization residual of each boundary tile into
+    the next round, and its ``init_state(x)`` builds the zero residuals;
+    `core.chebyshev` / `kernels.ops` thread the state when the record has
+    an `init_state`.
 
-    With an *active* ``fault_spec`` (see `repro.dist.faults`) the closure
+    With an *active* ``fault_spec`` (see `repro.dist.faults`) the matvec
     is stateful for a second reason: the state carries the int32 round
     counter and the last-delivered tile per incoming link, and every
     received tile passes through the injector's wire-noise / stale /
@@ -272,15 +261,6 @@ def _halo_matvec(diag, left, right, nl: int, h: int, axis: str,
         y = y + jnp.einsum("ij,...j->...i", right, from_right)
         return y, new_state
 
-    def mv(x, state=None):
-        if state is None:
-            if inj is not None:
-                # one-shot stateless call under faults: a fresh round-0
-                # state, deterministic per seed, result state discarded
-                return _run(x, mv.init_state(x))[0]
-            return _run(x, None)[0]
-        return _run(x, state)
-
     if inj is not None:
         def init_state(x):
             tail = x[..., nl - h:nl]
@@ -288,183 +268,23 @@ def _halo_matvec(diag, left, right, nl: int, h: int, axis: str,
             ef0 = ((quantize.ef_init(tail), quantize.ef_init(head))
                    if use_ef else None)
             return (inj.init_round(), inj.init_carried((tail, head)), ef0)
-
-        mv.init_state = init_state
     elif use_ef:
         def init_state(x):
             return (quantize.ef_init(x[..., nl - h:nl]),
                     quantize.ef_init(x[..., :h]))
+    else:
+        init_state = None
 
-        mv.init_state = init_state
-    return mv
+    def mv(x, state=None):
+        if state is None:
+            if inj is not None:
+                # one-shot stateless call under faults: a fresh round-0
+                # state, deterministic per seed, result state discarded
+                return _run(x, init_state(x))[0]
+            return _run(x, None)[0]
+        return _run(x, state)
 
-
-def _sharded(fn, mesh, in_specs, out_specs):
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)
-
-
-# ---------------------------------------------------------------------------
-# Public sharded applications
-# ---------------------------------------------------------------------------
-def dist_cheb_apply(
-    mesh: Mesh,
-    parts: BandedPartition,
-    x: Array,
-    coeffs: Union[Array, np.ndarray],
-    lmax: float,
-    axis: str = "graph",
-    exchange_dtype: str = "f32",
-    error_feedback: bool = True,
-    fault_spec=None,
-    degradation: str = "zero_fill",
-) -> Array:
-    """Sharded Phi_tilde x (Algorithm 1). x: (..., n_padded) — leading batch
-    dims ride the same K halo-exchange rounds ((B, nl) boundary tiles move
-    per ppermute, round count unchanged). Returns (..., eta, n_padded) (or
-    (..., n_padded) for 1-D coeffs)."""
-    single = getattr(coeffs, "ndim", None) == 1 or (
-        not hasattr(coeffs, "ndim") and np.asarray(coeffs).ndim == 1)
-    c = jnp.atleast_2d(jnp.asarray(coeffs, dtype=x.dtype))
-    nl, h = parts.n_local, parts.halo
-    left_h, right_h = parts.boundary_couplings()
-
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis), _vspec(x.ndim, axis), P()),
-        out_specs=_vspec(x.ndim + 1, axis),
-        check_vma=False,
-    )
-    def run(diag, left, right, xl, c):
-        mv = _halo_matvec(diag[0], left[0], right[0], nl, h, axis,
-                          exchange_dtype, error_feedback,
-                          fault_spec, degradation)
-        return cheb.cheb_apply(mv, xl, c, lmax)
-
-    out = run(parts.diag, left_h, right_h, x, c)
-    return out[..., 0, :] if single else out
-
-
-def dist_cheb_apply_adjoint(
-    mesh: Mesh,
-    parts: BandedPartition,
-    a: Array,
-    coeffs: Union[Array, np.ndarray],
-    lmax: float,
-    axis: str = "graph",
-    exchange_dtype: str = "f32",
-    error_feedback: bool = True,
-    fault_spec=None,
-    degradation: str = "zero_fill",
-) -> Array:
-    """Sharded Phi_tilde^* a (Algorithm 2). a: (..., eta, n_padded) ->
-    (..., n_padded); one ppermute pair moves all eta streams (and every
-    batch signal) per order."""
-    c = jnp.asarray(coeffs, dtype=a.dtype)
-    nl, h = parts.n_local, parts.halo
-    left_h, right_h = parts.boundary_couplings()
-
-    def run(diag, left, right, al, c):
-        mv = _halo_matvec(diag[0], left[0], right[0], nl, h, axis,
-                          exchange_dtype, error_feedback,
-                          fault_spec, degradation)
-        return cheb.cheb_apply_adjoint(mv, al, c, lmax)
-
-    return _sharded(
-        run, mesh,
-        (P(axis), P(axis), P(axis), _vspec(a.ndim, axis), P()),
-        _vspec(a.ndim - 1, axis),
-    )(parts.diag, left_h, right_h, a, c)
-
-
-def dist_cheb_apply_gram(
-    mesh: Mesh,
-    parts: BandedPartition,
-    x: Array,
-    coeffs: np.ndarray,
-    lmax: float,
-    axis: str = "graph",
-    exchange_dtype: str = "f32",
-    error_feedback: bool = True,
-    fault_spec=None,
-    degradation: str = "zero_fill",
-) -> Array:
-    """Sharded Phi~*Phi~ x via product coefficients (Section IV-C).
-    x: (..., n_padded) -> (..., n_padded)."""
-    d = jnp.asarray(cheb.gram_coeffs(coeffs), dtype=x.dtype)
-    nl, h = parts.n_local, parts.halo
-    left_h, right_h = parts.boundary_couplings()
-
-    def run(diag, left, right, xl, d):
-        mv = _halo_matvec(diag[0], left[0], right[0], nl, h, axis,
-                          exchange_dtype, error_feedback,
-                          fault_spec, degradation)
-        return cheb.cheb_apply(mv, xl, d, lmax)
-
-    return _sharded(
-        run, mesh,
-        (P(axis), P(axis), P(axis), _vspec(x.ndim, axis), P()),
-        _vspec(x.ndim, axis),
-    )(parts.diag, left_h, right_h, x, d)
-
-
-def dist_lasso(
-    mesh: Mesh,
-    parts: BandedPartition,
-    y: Array,
-    coeffs: np.ndarray,
-    lmax: float,
-    mu: Array,
-    gamma: float = 0.2,
-    n_iters: int = 300,
-    axis: str = "graph",
-    exchange_dtype: str = "f32",
-    error_feedback: bool = True,
-    fault_spec=None,
-    degradation: str = "zero_fill",
-) -> Tuple[Array, Array]:
-    """Fully sharded Algorithm 3 (distributed lasso).
-
-    y: (..., n_padded) — batched signals share every exchange round; mu:
-    scalar, (eta,) per-scale weights, or (..., eta) per-signal weights.
-    Returns (a_*, y_*) with a_*: (..., eta, n_padded) wavelet coefficients,
-    y_*: (..., n_padded) denoised signals. The entire ISTA loop lives
-    inside one shard_map — per soft-thresholding iteration, the only
-    communication is the 4K halo exchanges of Phi~ Phi~* (Section VI's
-    communication analysis), regardless of batch size.
-    """
-    from ...core.lasso import _mu_threshold
-
-    c = jnp.asarray(coeffs, dtype=y.dtype)
-    eta = c.shape[0]
-    thresh = _mu_threshold(mu, eta, y.dtype, gamma)
-    nl, h = parts.n_local, parts.halo
-    left_h, right_h = parts.boundary_couplings()
-
-    def run(diag, left, right, yl, c, thresh):
-        mv = _halo_matvec(diag[0], left[0], right[0], nl, h, axis,
-                          exchange_dtype, error_feedback,
-                          fault_spec, degradation)
-        phi_y = cheb.cheb_apply(mv, yl, c, lmax)  # Alg. 3 line 3
-
-        def body(a, _):
-            gram_a = cheb.cheb_apply(
-                mv, cheb.cheb_apply_adjoint(mv, a, c, lmax), c, lmax,
-            )
-            a_new = soft_threshold(a + gamma * (phi_y - gram_a), thresh)
-            return a_new, None
-
-        a0 = jnp.zeros_like(phi_y)
-        a_star, _ = jax.lax.scan(body, a0, None, length=n_iters)
-        y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
-        return a_star, y_star
-
-    return _sharded(
-        run, mesh,
-        (P(axis), P(axis), P(axis), _vspec(y.ndim, axis), P(), P()),
-        (_vspec(y.ndim + 1, axis), _vspec(y.ndim, axis)),
-    )(parts.diag, left_h, right_h, y, c, thresh)
+    return cheb.LocalMatvec(mv, init_state=init_state)
 
 
 def halo_bytes_per_apply(parts: BandedPartition, K: int, eta: int = 1,
@@ -513,7 +333,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
     ``error_feedback`` (int8 only) threads the per-tile quantization
     residual across the K orders.
     """
-    from ..operator import ExecutionPlan
+    from ..operator import LocalForm, plan_from_form
     from ..partition import build_general_plan, resolve_partition_arg
 
     quantize.validate_exchange_dtype(exchange_dtype)
@@ -547,93 +367,38 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
     parts = partition
     n = parts.n
     nl, h = parts.n_local, parts.halo
-    coeffs = op.coeffs
-    lmax = op.lmax
 
-    def apply(f: Array) -> Array:
-        out = dist_cheb_apply(mesh, parts, pad_signal(f, parts),
-                              jnp.atleast_2d(jnp.asarray(coeffs, f.dtype)),
-                              lmax, axis, exchange_dtype, error_feedback,
-                              fault_spec, degradation)
-        return out[..., :n]
+    def local(mine):
+        diag, left, right = mine
+        return _halo_matvec(diag, left, right, nl, h, axis, exchange_dtype,
+                            error_feedback, fault_spec, degradation)
 
-    def apply_adjoint(a: Array) -> Array:
-        return dist_cheb_apply_adjoint(
-            mesh, parts, pad_signal(a, parts), coeffs, lmax, axis,
-            exchange_dtype, error_feedback, fault_spec, degradation)[..., :n]
-
-    def apply_gram(f: Array) -> Array:
-        return dist_cheb_apply_gram(
-            mesh, parts, pad_signal(f, parts), coeffs, lmax, axis,
-            exchange_dtype, error_feedback, fault_spec, degradation)[..., :n]
-
-    def solve_lasso(y, mu, gamma, n_iters):
-        from ...core.lasso import LassoResult
-
-        a_star, y_star = dist_lasso(mesh, parts, pad_signal(y, parts),
-                                    coeffs, lmax, mu, gamma=gamma,
-                                    n_iters=n_iters, axis=axis,
-                                    exchange_dtype=exchange_dtype,
-                                    error_feedback=error_feedback,
-                                    fault_spec=fault_spec,
-                                    degradation=degradation)
-        return LassoResult(coeffs=a_star[..., :n], signal=y_star[..., :n],
-                           objective=jnp.nan, n_iters=n_iters, fused=True)
-
-    def matvec_runner(fn, signals, consts=()):
-        # Backend-generic iteration primitive (the Section-V solver
-        # substrate): run `fn` inside ONE shard_map with the ring-halo
-        # matvec; vertex-last signals shard on the vertex axis (zero-padded
-        # tails stay zero — solver bodies use reciprocal-diagonal updates),
-        # consts replicate, outputs crop back to the logical n.
-        padded = tuple(pad_signal(jnp.asarray(s), parts) for s in signals)
-        local = tuple(
-            jax.ShapeDtypeStruct(s.shape[:-1] + (parts.n_local,), s.dtype)
-            for s in padded)
-        out_sds = jax.eval_shape(lambda *a: fn(lambda v: v, *a),
-                                 *local, *consts)
-        in_specs = ((P(axis),) * 3
-                    + tuple(_vspec(s.ndim, axis) for s in padded)
-                    + tuple(P() for _ in consts))
-        out_specs = jax.tree.map(lambda sd: _vspec(len(sd.shape), axis),
-                                 out_sds)
-
-        def run(diag, left, right, *rest):
-            mv = _halo_matvec(diag[0], left[0], right[0], nl, h, axis,
-                              exchange_dtype, error_feedback,
-                              fault_spec, degradation)
-            return fn(mv, *rest)
-
-        left_h, right_h = parts.boundary_couplings()
-        outs = _sharded(run, mesh, in_specs, out_specs)(
-            parts.diag, left_h, right_h, *padded, *consts)
-        return jax.tree.map(lambda o: o[..., :n], outs)
-
-    return ExecutionPlan(
-        op=op, backend="halo",
-        apply=apply, apply_adjoint=apply_adjoint, apply_gram=apply_gram,
-        solve_lasso_fn=solve_lasso,
-        matvec_runner=matvec_runner,
-        info={
-            "mesh_axis": axis,
-            "n_shards": n_shards,
-            "n_local": nl,
-            "halo_width": h,
-            "partition": "banded",
-            "partition_leak": leak,
-            # one exchange round = the left+right ppermute pair (commstats
-            # divides the measured ppermute tally by this)
-            "exchange_collectives_per_round": 2,
-            "exchange_dtype": exchange_dtype,
-            "error_feedback": bool(error_feedback),
-            "fault_spec": faults.spec_info(fault_spec),
-            "degradation": degradation,
-            "fault_key": faults.fault_key(fault_spec, degradation),
-            # forward/gram ship an eta-independent (..., h) tile per order;
-            # only the adjoint's iterate carries the eta streams
-            "halo_bytes_per_apply": halo_bytes_per_apply(
-                parts, op.K, 1, exchange_dtype=exchange_dtype),
-            "halo_bytes_per_adjoint": halo_bytes_per_apply(
-                parts, op.K, op.eta, exchange_dtype=exchange_dtype),
-        },
-    )
+    # zero-padded tails stay zero: solver bodies use reciprocal-diagonal
+    # updates
+    form = LocalForm(local=local, recurrence=cheb.cheb_apply,
+                     arrays=(parts.diag, *parts.boundary_couplings()),
+                     glob_in=lambda x: pad_signal(x, parts),
+                     glob_out=lambda y: ops.crop(y, n),
+                     mesh=mesh, axis=axis)
+    return plan_from_form(op, "halo", form, info={
+        "mesh_axis": axis,
+        "n_shards": n_shards,
+        "n_local": nl,
+        "halo_width": h,
+        "partition": "banded",
+        "partition_leak": leak,
+        # one exchange round = the left+right ppermute pair (commstats
+        # divides the measured ppermute tally by this)
+        "exchange_collectives_per_round": 2,
+        "exchange_dtype": exchange_dtype,
+        "error_feedback": bool(error_feedback),
+        "fault_spec": faults.spec_info(fault_spec),
+        "degradation": degradation,
+        "fault_key": faults.fault_key(fault_spec, degradation),
+        # forward/gram ship an eta-independent (..., h) tile per order;
+        # only the adjoint's iterate carries the eta streams
+        "halo_bytes_per_apply": halo_bytes_per_apply(
+            parts, op.K, 1, exchange_dtype=exchange_dtype),
+        "halo_bytes_per_adjoint": halo_bytes_per_apply(
+            parts, op.K, op.eta, exchange_dtype=exchange_dtype),
+    })

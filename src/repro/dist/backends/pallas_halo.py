@@ -41,30 +41,30 @@ arrival, so the exchange hides behind interior compute instead of
 serializing in front of it.  The whole per-shard recurrence runs on the
 shard's Block-ELL padded domain (padded once on entry, cropped once on
 exit — no per-order pad/crop traffic), and on a 1-shard mesh, where the
-exchange is a no-op, the matvec is tagged for the single-launch
-`cheb_sweep` kernel so the entire K-order loop collapses into one
-`pallas_call`.
+exchange is a no-op, the matvec record carries its Block-ELL for the
+single-launch `cheb_sweep` kernel so the entire K-order loop collapses
+into one `pallas_call`.  :func:`build` hands this as a local form to the
+plan body (`repro.dist.operator.plan_from_form`).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from ... import obs
 from ...core import chebyshev as cheb
 from ...core import graph as graphmod
-from ...core.lasso import soft_threshold
 from ...kernels import ops
 from .. import faults, quantize
-from ..sharding import ShardingRules, auto_mesh, make_rules
+from ..sharding import auto_mesh
 from . import register_backend
-from .halo import (BandedPartition, _coupling_bandwidth, _sharded,
-                   pad_signal, partition_banded)
+from .halo import (BandedPartition, _coupling_bandwidth, pad_signal,
+                   partition_banded)
 
 Array = jax.Array
 
@@ -199,20 +199,21 @@ def _halo_row_matvec(local_A: graphmod.BlockELL, left: Array, right: Array,
        widen back to the compute dtype, then two small (pnl, h) dense
        products.
 
-    Under ``exchange_dtype="int8"`` with ``error_feedback=True`` on a
-    real multi-shard axis, the closure follows the dual-signature
-    stateful protocol (see `halo._halo_matvec`): ``mv(x)`` stays
-    stateless (plain quantize), ``mv(x, state) -> (y, state)`` threads
-    the per-tile quantization residuals across orders, and
-    ``mv.init_state(x)`` builds the zero residuals.
+    Returns a `core.chebyshev.LocalMatvec`.  Under
+    ``exchange_dtype="int8"`` with ``error_feedback=True`` on a real
+    multi-shard axis, it follows the dual-signature stateful protocol
+    (see `halo._halo_matvec`): ``mv(x)`` stays stateless (plain
+    quantize), ``mv(x, state) -> (y, state)`` threads the per-tile
+    quantization residuals across orders, and its ``init_state(x)``
+    builds the zero residuals.
 
     The ring wraps; the first/last shard's out-of-range contribution is
     killed by the zero left/right coupling blocks.  On a 1-shard mesh the
-    exchange is a no-op and the returned closure is tagged with
-    ``mv.block_ell`` so `ops.fused_cheb_recurrence` / the Section-V
-    solvers collapse the whole iteration into a single-launch sweep
-    kernel (the couplings are identically zero there); ``mv.sweep_dtype``
-    forwards the mixed-precision scratch mode to those sweep kernels.
+    exchange is a no-op and the record carries `local_A` as its
+    `block_ell`, so `ops.fused_cheb_recurrence` / the Section-V solvers
+    collapse the whole iteration into a single-launch sweep kernel (the
+    couplings are identically zero there), with `vmem_budget` and
+    `sweep_dtype` (the mixed-precision scratch mode) for those kernels.
     """
     size = n_shards if n_shards is not None else jax.lax.axis_size(axis)
     dt = quantize.validate_exchange_dtype(exchange_dtype)
@@ -275,13 +276,6 @@ def _halo_row_matvec(local_A: graphmod.BlockELL, left: Array, right: Array,
             y = y + jnp.einsum("ij,...j->...i", right, from_right)
         return y, new_state
 
-    def mv(x, state=None):
-        if state is None:
-            if inj is not None:
-                return _run(x, mv.init_state(x))[0]
-            return _run(x, None)[0]
-        return _run(x, state)
-
     if inj is not None:
         def init_state(x):
             tail = x[..., nl - h:nl]
@@ -289,19 +283,23 @@ def _halo_row_matvec(local_A: graphmod.BlockELL, left: Array, right: Array,
             ef0 = ((quantize.ef_init(tail), quantize.ef_init(head))
                    if use_ef else None)
             return (inj.init_round(), inj.init_carried((tail, head)), ef0)
-
-        mv.init_state = init_state
     elif use_ef:
         def init_state(x):
             return (quantize.ef_init(x[..., nl - h:nl]),
                     quantize.ef_init(x[..., :h]))
+    else:
+        init_state = None
 
-        mv.init_state = init_state
-    if size == 1:
-        mv.block_ell = local_A
-        mv.vmem_budget = vmem_budget
-        mv.sweep_dtype = sweep_dtype
-    return mv
+    def mv(x, state=None):
+        if state is None:
+            if inj is not None:
+                return _run(x, init_state(x))[0]
+            return _run(x, None)[0]
+        return _run(x, state)
+
+    return cheb.LocalMatvec(mv, block_ell=local_A if size == 1 else None,
+                            vmem_budget=vmem_budget, sweep_dtype=sweep_dtype,
+                            init_state=init_state)
 
 
 def pallas_halo_bytes_per_apply(parts: ShardedBlockELL, K: int, eta: int = 1,
@@ -358,8 +356,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
     ``sweep_vmem_bytes`` guard value is recomputed from the actual
     scratch dtype, so bf16 roughly doubles the admissible tile.
     """
-    from ..operator import ExecutionPlan
-
+    from ..operator import LocalForm, plan_from_form
     from ..partition import build_general_plan, resolve_partition_arg
 
     quantize.validate_exchange_dtype(exchange_dtype)
@@ -405,27 +402,44 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
         raise ValueError(f"partition has {parts.n_shards} shards but mesh "
                          f"axis {axis!r} has {n_shards}")
     n, nl, h = parts.n, parts.n_local, parts.halo
-    # the shard's Block-ELL padded domain: the whole recurrence runs here,
-    # padded once on entry and cropped once on exit (no per-order pads)
     pnl = parts.blocks.shape[1] * parts.blocks.shape[3]
     left_p = ops.pad_trailing(parts.left.swapaxes(-1, -2),
                               pnl).swapaxes(-1, -2)
     right_p = ops.pad_trailing(parts.right.swapaxes(-1, -2),
                                pnl).swapaxes(-1, -2)
-    coeffs = op.coeffs
-    lmax = op.lmax
     band = graphmod.block_ell_band(parts.indices, parts.mask,
                                    parts.blocks.shape[3:])
 
-    def _mk_mv(blocks, indices, mask, left, right):
-        local_A = graphmod.BlockELL(blocks=blocks[0], indices=indices[0],
-                                    mask=mask[0], n=nl, band=band)
-        return _halo_row_matvec(local_A, left[0], right[0], nl, h, axis,
+    def local(mine):
+        blocks, indices, mask, left, right = mine
+        local_A = graphmod.BlockELL(blocks=blocks, indices=indices,
+                                    mask=mask, n=nl, band=band)
+        return _halo_row_matvec(local_A, left, right, nl, h, axis,
                                 use_pallas, vmem_budget, n_shards,
                                 exchange_dtype, error_feedback, sweep_dtype,
                                 fault_spec, degradation)
 
-    info = {
+    # The whole per-shard recurrence runs on the shard's Block-ELL padded
+    # domain: padded once on entry and cropped once on exit (padded rows
+    # stay zero: zero signal, zero blocks, zero couplings).  A 1-shard mesh
+    # needs no collectives and no shard_map: its matvec, built once on the
+    # concrete local Block-ELL, carries it for the single-launch sweep.
+    if n_shards == 1:
+        places = dict(enter=lambda x, mine: ops.pad_trailing(x, pnl),
+                      leave=lambda y, mine: ops.crop(y, n))
+    else:
+        places = dict(glob_in=lambda x: pad_signal(x, parts),
+                      enter=lambda x, mine: ops.pad_trailing(x, pnl),
+                      leave=lambda y, mine: ops.crop(y, nl),
+                      glob_out=lambda y: ops.crop(y, n),
+                      mesh=mesh, axis=axis)
+    form = LocalForm(
+        local=local,
+        recurrence=functools.partial(ops.fused_cheb_recurrence,
+                                     use_pallas=use_pallas),
+        arrays=(parts.blocks, parts.indices, parts.mask, left_p, right_p),
+        **places)
+    return plan_from_form(op, "pallas_halo", form, info={
         "mesh_axis": axis,
         "n_shards": n_shards,
         "n_local": nl,
@@ -452,217 +466,7 @@ def build(op, *, mesh=None, partition=None, axis: Optional[str] = None,
             parts, op.K, 1, exchange_dtype=exchange_dtype),
         "halo_bytes_per_adjoint": pallas_halo_bytes_per_apply(
             parts, op.K, op.eta, exchange_dtype=exchange_dtype),
-    }
-
-    if n_shards == 1:
-        # A 1-shard mesh needs no collectives and no shard_map: build the
-        # plan directly on the (concrete) local Block-ELL — the matvec's
-        # `block_ell` tag holds plan-time constants, so the single-launch
-        # sweep dispatch (and its eager-dense CPU oracle) engages exactly
-        # as in the `pallas` backend, minus the shard_map trace overhead.
-        return _build_single_shard(op, parts, pnl, left_p, right_p,
-                                   use_pallas, vmem_budget, info,
-                                   sweep_dtype)
-
-    # PartitionSpecs through the logical-axis rules: every per-shard tensor
-    # is sharded on its leading "vertex"-block dimension.  The shared _BASE
-    # vocabulary maps "vertex" to the conventional "graph" mesh axis; a
-    # mesh with a differently-named axis gets a local override.  Signals
-    # carry leading batch dims ((..., N) contract), so their specs are
-    # built per input rank: batch/eta axes replicate, vertex axis shards.
-    rules = (make_rules(mesh) if axis == "graph"
-             else ShardingRules(mapping={"vertex": axis}, mesh=mesh))
-    vspec = rules.spec("vertex")
-    mats = (parts.blocks, parts.indices, parts.mask, left_p, right_p)
-    mat_specs = (vspec,) * 5
-
-    def _sig_spec(ndim: int) -> P:
-        return rules.spec(*([None] * (ndim - 1)), "vertex")
-
-    def apply(f: Array) -> Array:
-        def run(blocks, indices, mask, left, right, xl, c):
-            mv = _mk_mv(blocks, indices, mask, left, right)
-            out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, pnl),
-                                            c, lmax, use_pallas=use_pallas)
-            return ops.crop(out, nl)
-
-        c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
-        out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
-                       _sig_spec(f.ndim + 1))(*mats,
-                                              pad_signal(f, parts),
-                                              c2)
-        return ops.crop(out, n)
-
-    def apply_adjoint(a: Array) -> Array:
-        def run(blocks, indices, mask, left, right, al, c):
-            mv = _mk_mv(blocks, indices, mask, left, right)
-            out = cheb.cheb_apply_adjoint(mv, ops.pad_trailing(al, pnl),
-                                          c, lmax)
-            return ops.crop(out, nl)
-
-        c = jnp.asarray(coeffs, a.dtype)
-        out = _sharded(run, mesh, mat_specs + (_sig_spec(a.ndim), P()),
-                       _sig_spec(a.ndim - 1))(*mats, pad_signal(a, parts), c)
-        return ops.crop(out, n)
-
-    def apply_gram(f: Array) -> Array:
-        def run(blocks, indices, mask, left, right, xl, d):
-            mv = _mk_mv(blocks, indices, mask, left, right)
-            out = ops.fused_cheb_recurrence(mv, ops.pad_trailing(xl, pnl),
-                                            d, lmax, use_pallas=use_pallas)
-            return ops.crop(out[..., 0, :], nl)
-
-        d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
-        out = _sharded(run, mesh, mat_specs + (_sig_spec(f.ndim), P()),
-                       _sig_spec(f.ndim))(*mats, pad_signal(f, parts), d)
-        return ops.crop(out, n)
-
-    def solve_lasso(y, mu, gamma, n_iters):
-        from ...core.lasso import LassoResult, _mu_threshold
-
-        def run(blocks, indices, mask, left, right, yl, c, thresh):
-            mv = _mk_mv(blocks, indices, mask, left, right)
-            # the whole ISTA loop runs on the padded Block-ELL domain;
-            # padded rows stay identically zero (zero signal, zero blocks,
-            # zero couplings), cropped once on the way out
-            phi_y = ops.fused_cheb_recurrence(mv, ops.pad_trailing(yl, pnl),
-                                              c, lmax, use_pallas=use_pallas)
-
-            def body(a, _):
-                back = cheb.cheb_apply_adjoint(mv, a, c, lmax)
-                gram_a = ops.fused_cheb_recurrence(mv, back, c, lmax,
-                                                   use_pallas=use_pallas)
-                a_new = soft_threshold(a + gamma * (phi_y - gram_a), thresh)
-                return a_new, None
-
-            a0 = jnp.zeros_like(phi_y)
-            a_star, _ = jax.lax.scan(body, a0, None, length=n_iters)
-            y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
-            return a_star[..., :nl], y_star[..., :nl]
-
-        c = jnp.asarray(coeffs, y.dtype)
-        thresh = _mu_threshold(mu, op.eta, y.dtype, gamma)
-        a_star, y_star = _sharded(
-            run, mesh, mat_specs + (_sig_spec(y.ndim), P(), P()),
-            (_sig_spec(y.ndim + 1), _sig_spec(y.ndim)),
-        )(*mats, pad_signal(y, parts), c, thresh)
-        return LassoResult(coeffs=a_star[..., :n], signal=y_star[..., :n],
-                           objective=jnp.nan, n_iters=n_iters, fused=True)
-
-    def matvec_runner(fn, signals, consts=()):
-        # Section-V solver substrate: one shard_map running `fn` against
-        # the per-shard Block-ELL matvec with boundary-rows-only halo
-        # exchange — a solver round costs the same 2·h-row traffic as one
-        # Chebyshev order.  Vertex-last signals shard (zero-padded tails
-        # stay zero under the solvers' reciprocal-diagonal updates) and are
-        # lifted to the shard's Block-ELL padded domain once per call, so
-        # the iteration bodies run pad-free; every output's vertex axis is
-        # cropped per shard, then to the logical n.  On a 1-shard mesh the
-        # matvec carries its `block_ell` tag, so eligible solver bodies
-        # collapse into the single-launch sweep kernels.
-        padded = tuple(pad_signal(jnp.asarray(s), parts) for s in signals)
-        local = tuple(
-            jax.ShapeDtypeStruct(s.shape[:-1] + (pnl,), s.dtype)
-            for s in padded)
-        out_sds = jax.eval_shape(
-            lambda *a: jax.tree.map(
-                lambda o: o[..., :nl], fn(lambda v: v, *a)),
-            *local, *consts)
-        in_specs = (mat_specs
-                    + tuple(_sig_spec(s.ndim) for s in padded)
-                    + tuple(P() for _ in consts))
-        out_specs = jax.tree.map(lambda sd: _sig_spec(len(sd.shape)),
-                                 out_sds)
-
-        def run(blocks, indices, mask, left, right, *rest):
-            mv = _mk_mv(blocks, indices, mask, left, right)
-            sigs = tuple(ops.pad_trailing(s, pnl) for s in rest[:len(padded)])
-            outs = fn(mv, *sigs, *rest[len(padded):])
-            return jax.tree.map(lambda o: ops.crop(o, nl), outs)
-
-        outs = _sharded(run, mesh, in_specs, out_specs)(
-            *mats, *padded, *consts)
-        return jax.tree.map(lambda o: ops.crop(o, n), outs)
-
-    return ExecutionPlan(
-        op=op, backend="pallas_halo",
-        apply=apply, apply_adjoint=apply_adjoint, apply_gram=apply_gram,
-        solve_lasso_fn=solve_lasso,
-        matvec_runner=matvec_runner,
-        info=info,
-    )
-
-
-def _build_single_shard(op, parts, pnl, left_p, right_p, use_pallas,
-                        vmem_budget, info, sweep_dtype=None):
-    """The 1-shard degenerate of the pallas_halo plan: same partition, same
-    matvec (the zero boundary couplings included, so `plan.info` and the
-    byte models stay comparable), but no shard_map and a concrete
-    Block-ELL — the single-launch sweep path of `kernels.ops` applies."""
-    from ...core.lasso import LassoResult, _mu_threshold
-    from ..operator import ExecutionPlan
-
-    n, nl, h = parts.n, parts.n_local, parts.halo
-    coeffs = op.coeffs
-    lmax = op.lmax
-    local_A = graphmod.BlockELL(blocks=parts.blocks[0],
-                                indices=parts.indices[0],
-                                mask=parts.mask[0], n=nl,
-                                band=info["spmv_band"])
-    mv = _halo_row_matvec(local_A, left_p[0], right_p[0], nl, h,
-                          info["mesh_axis"], use_pallas, vmem_budget,
-                          n_shards=1, sweep_dtype=sweep_dtype)
-
-    def _pad(x):
-        return ops.pad_trailing(jnp.asarray(x), pnl)
-
-    def apply(f: Array) -> Array:
-        c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
-        out = ops.fused_cheb_recurrence(mv, _pad(f), c2, lmax,
-                                        use_pallas=use_pallas)
-        return ops.crop(out, n)
-
-    def apply_adjoint(a: Array) -> Array:
-        c = jnp.asarray(coeffs, a.dtype)
-        return ops.crop(cheb.cheb_apply_adjoint(mv, _pad(a), c, lmax), n)
-
-    def apply_gram(f: Array) -> Array:
-        d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
-        out = ops.fused_cheb_recurrence(mv, _pad(f), d, lmax,
-                                        use_pallas=use_pallas)
-        return ops.crop(out[..., 0, :], n)
-
-    def solve_lasso(y, mu, gamma, n_iters):
-        c = jnp.asarray(coeffs, y.dtype)
-        thresh = _mu_threshold(mu, op.eta, y.dtype, gamma)
-        phi_y = ops.fused_cheb_recurrence(mv, _pad(y), c, lmax,
-                                          use_pallas=use_pallas)
-
-        def body(a, _):
-            back = cheb.cheb_apply_adjoint(mv, a, c, lmax)
-            gram_a = ops.fused_cheb_recurrence(mv, back, c, lmax,
-                                               use_pallas=use_pallas)
-            a_new = soft_threshold(a + gamma * (phi_y - gram_a), thresh)
-            return a_new, None
-
-        a_star, _ = jax.lax.scan(body, jnp.zeros_like(phi_y), None,
-                                 length=n_iters)
-        y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
-        return LassoResult(coeffs=a_star[..., :n], signal=y_star[..., :n],
-                           objective=jnp.nan, n_iters=n_iters, fused=True)
-
-    def matvec_runner(fn, signals, consts=()):
-        padded = tuple(_pad(s) for s in signals)
-        outs = fn(mv, *padded, *consts)
-        return jax.tree.map(lambda o: ops.crop(o, n), outs)
-
-    return ExecutionPlan(
-        op=op, backend="pallas_halo",
-        apply=apply, apply_adjoint=apply_adjoint, apply_gram=apply_gram,
-        solve_lasso_fn=solve_lasso,
-        matvec_runner=matvec_runner,
-        info=info,
-    )
+    })
 
 
 def _banded_to_dense(parts: BandedPartition) -> np.ndarray:

@@ -210,8 +210,7 @@ def _ring_matvec(axis: str, *, quantize: bool = False,
     def init_state(x):
         return (inj.init_round(), inj.init_carried((x, x)))
 
-    mv.init_state = init_state
-    return mv
+    return cheb.LocalMatvec(mv, init_state=init_state)
 
 
 def gossip_mean(x: Array, axis: str, coeffs, *, quantize: bool = False,

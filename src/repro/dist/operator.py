@@ -31,11 +31,15 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 
 from .. import obs
+from ..core.chebyshev import LocalMatvec
 from ..core.multiplier import UnionMultiplier
 
 Array = jax.Array
 
 logger = logging.getLogger(__name__)
+
+#: The matvec a runner's body is shape-probed with: P = I.
+_PROBE = LocalMatvec(lambda v: v)
 
 
 def canonical_kwarg(v) -> Any:
@@ -125,8 +129,9 @@ class ExecutionPlan:
     #: Backend-generic distributed-iteration primitive (the Section-V solver
     #: substrate): ``matvec_runner(fn, signals, consts=()) -> outputs`` runs
     #: the jit-compatible body ``fn(mv, *signals, *consts)`` against this
-    #: backend's distributed matvec ``mv`` (applies P along the last axis on
-    #: the backend's padded/sharded domain).  `signals` are (..., N) arrays
+    #: backend's distributed matvec ``mv``, a `core.chebyshev.LocalMatvec`
+    #: (applies P along the last axis on the backend's padded/sharded
+    #: domain).  `signals` are (..., N) arrays
     #: with the vertex axis LAST — the runner pads them on the way in,
     #: shards them on the vertex axis, and crops every output back to the
     #: logical N; `consts` are small replicated arrays (coefficients).
@@ -389,6 +394,188 @@ class ExecutionPlan:
                 self.backend, sorted(blocking))
         return _lasso.distributed_lasso(self, y, mu=mu, gamma=gamma,
                                         n_iters=n_iters, **kwargs)
+
+
+def _vspec(ndim: int, axis: str):
+    """PartitionSpec sharding only the last of `ndim` axes on `axis` —
+    batch / eta axes replicate, the vertex axis splits across shards."""
+    from jax.sharding import PartitionSpec as P
+
+    return P(*((None,) * (ndim - 1) + (axis,)))
+
+
+def _same(x, *_):
+    return x
+
+
+@dataclasses.dataclass(frozen=True)
+class LocalForm:
+    """What a backend hands the plan body (:func:`plan_from_form`): its
+    local operator and the way signals reach it.  The body writes apply,
+    adjoint, gram, the fused lasso and the solver runner once over it.
+
+    local: ``local(mine) -> LocalMatvec``, the matvec over one shard's
+        arrays (`arrays` with their shard axis indexed away).  Without a
+        mesh it is called once, at plan build, on shard 0's arrays.
+    recurrence: ``(mv, x, coeffs, lmax) -> (..., eta, n)`` for (eta, K+1)
+        coeffs, the forward recurrence of this form.
+    arrays: per-shard arrays, leading axis the shard, split on `axis`.
+    structure: the arrays are the plan's `structure`, arguments of every
+        compiled program; otherwise they are closed over as constants.
+    glob_in / glob_out: a whole signal into / out of the body (outside
+        any shard_map): padding, or the gather into partition order.
+    enter / leave: ``(x, mine) -> x`` on a shard (on the device, without
+        a mesh): into the local domain and back out.
+    mesh / axis: the body runs in a shard_map over `axis` of `mesh`; no
+        mesh runs it directly.
+    count: an `obs` counter every entry adds once per trace.
+    lasso: the plan runs `solve_lasso` in the body; otherwise the generic
+        ISTA over apply / apply_adjoint runs.
+    host_coeffs: apply and gram hand `recurrence` their coefficients as
+        host arrays, for it to convert (the single-device sweep does);
+        otherwise they are converted before the signals enter.
+    """
+
+    local: Callable
+    recurrence: Callable
+    arrays: Tuple[Any, ...] = ()
+    structure: bool = False
+    glob_in: Callable = _same
+    glob_out: Callable = _same
+    enter: Callable = _same
+    leave: Callable = _same
+    mesh: Any = None
+    axis: Optional[str] = None
+    count: Optional[str] = None
+    lasso: bool = True
+    host_coeffs: bool = False
+
+
+def plan_from_form(op, backend: str, form: LocalForm,
+                   info: Dict[str, Any]) -> ExecutionPlan:
+    """The ExecutionPlan of `form`: every entry written once, here."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..core import chebyshev as cheb
+    from ..core.lasso import LassoResult, _mu_threshold, soft_threshold
+
+    coeffs, lmax = op.coeffs, op.lmax
+    if form.mesh is None:
+        mine0 = tuple(a[0] for a in form.arrays)
+        mv0 = form.local(mine0)
+
+    def run(arrays, inner, signals, consts, out_ndim):
+        """``inner(mv, mine, *signals, *consts)`` on every shard."""
+        if form.count:
+            obs.count(form.count)
+        sigs = tuple(form.glob_in(jnp.asarray(s)) for s in signals)
+        if form.mesh is None:
+            outs = inner(mv0, mine0, *sigs, *consts)
+        else:
+            from jax.sharding import PartitionSpec as P
+
+            na, axis = len(arrays), form.axis
+
+            def shard(*args):
+                mine = tuple(a[0] for a in args[:na])
+                return inner(form.local(mine), mine, *args[na:])
+
+            if callable(out_ndim):
+                out_ndim = out_ndim(sigs)
+            outs = jax.shard_map(
+                shard, mesh=form.mesh,
+                in_specs=((P(axis),) * na
+                          + tuple(_vspec(s.ndim, axis) for s in sigs)
+                          + (P(),) * len(consts)),
+                out_specs=jax.tree.map(lambda d: _vspec(d, axis), out_ndim),
+                check_vma=False)(*arrays, *sigs, *consts)
+        return jax.tree.map(form.glob_out, outs)
+
+    def apply(arrays, f):
+        def inner(mv, mine, x, c):
+            return form.leave(form.recurrence(mv, form.enter(x, mine), c,
+                                              lmax), mine)
+
+        c = (np.atleast_2d(coeffs) if form.host_coeffs
+             else jnp.atleast_2d(jnp.asarray(coeffs, f.dtype)))
+        return run(arrays, inner, (f,), (c,), f.ndim + 1)
+
+    def apply_adjoint(arrays, a):
+        def inner(mv, mine, x, c):
+            return form.leave(cheb.cheb_apply_adjoint(
+                mv, form.enter(x, mine), c, lmax), mine)
+
+        c = jnp.asarray(coeffs, a.dtype)
+        return run(arrays, inner, (a,), (c,), a.ndim - 1)
+
+    def apply_gram(arrays, f):
+        # Phi~* Phi~ by the product coefficients (Section IV-C)
+        def inner(mv, mine, x, d):
+            out = form.recurrence(mv, form.enter(x, mine), d, lmax)
+            return form.leave(out[..., 0, :], mine)
+
+        d = (cheb.gram_coeffs(coeffs)[None] if form.host_coeffs
+             else jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None])
+        return run(arrays, inner, (f,), (d,), f.ndim)
+
+    def solve_lasso(arrays, y, mu, gamma, n_iters):
+        # Algorithm 3 in the body: the whole ISTA loop on the local domain
+        # (padded rows stay zero), left once on the way out
+        def inner(mv, mine, yl, c, thresh):
+            phi_y = form.recurrence(mv, form.enter(yl, mine), c, lmax)
+
+            def body(a, _):
+                back = cheb.cheb_apply_adjoint(mv, a, c, lmax)
+                gram_a = form.recurrence(mv, back, c, lmax)
+                return soft_threshold(a + gamma * (phi_y - gram_a),
+                                      thresh), None
+
+            a_star, _ = jax.lax.scan(body, jnp.zeros_like(phi_y), None,
+                                     length=n_iters)
+            y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
+            return form.leave(a_star, mine), form.leave(y_star, mine)
+
+        c = jnp.asarray(coeffs, y.dtype)
+        thresh = _mu_threshold(mu, op.eta, y.dtype, gamma)
+        a_star, y_star = run(arrays, inner, (y,), (c, thresh),
+                             (y.ndim + 1, y.ndim))
+        return LassoResult(coeffs=a_star, signal=y_star, objective=jnp.nan,
+                           n_iters=n_iters, fused=True)
+
+    def matvec_runner(arrays, fn, signals, consts=()):
+        ns = len(signals)
+
+        def inner(mv, mine, *args):
+            outs = fn(mv, *(form.enter(s, mine) for s in args[:ns]),
+                      *args[ns:])
+            return jax.tree.map(lambda o: form.leave(o, mine), outs)
+
+        def out_ndim(sigs):
+            probe = jax.eval_shape(lambda *a: fn(_PROBE, *a), *sigs, *consts)
+            return jax.tree.map(lambda sd: sd.ndim, probe)
+
+        return run(arrays, inner, tuple(signals), tuple(consts), out_ndim)
+
+    def entries(arrays):
+        def bind(fn):
+            return functools.partial(fn, arrays)
+
+        return dict(apply=bind(apply), apply_adjoint=bind(apply_adjoint),
+                    apply_gram=bind(apply_gram),
+                    solve_lasso_fn=bind(solve_lasso) if form.lasso else None,
+                    matvec_runner=bind(matvec_runner))
+
+    if not form.structure:
+        return ExecutionPlan(op=op, backend=backend, info=info,
+                             **entries(form.arrays))
+
+    def over(structure):
+        return ExecutionPlan(op=op, backend=backend, info=info,
+                             structure=structure, over=over,
+                             **entries(structure))
+
+    return over(form.arrays)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -60,15 +60,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..core import chebyshev as cheb
-from ..core.lasso import soft_threshold
 from ..core import graph as graphmod
 from ..kernels import ops
 from . import faults, quantize
-from .sharding import ShardingRules, make_rules
 
 Array = jax.Array
-
-shard_map = jax.shard_map
 
 
 class OverfullSlotsError(ValueError):
@@ -877,10 +873,11 @@ def make_exchange_matvec(interior, sends, couplings, axis: str, size: int,
     3. received tiles decode and scatter-add into the output rows
        (`y.at[rows].add(vals * tile[cols])` — duplicate rows accumulate).
 
-    Under ``exchange_dtype="int8"`` with error feedback the closure follows
-    the dual-signature stateful protocol of `core.chebyshev`
-    (``mv(x, state) -> (y, state)``, ``mv.init_state``), threading one
-    quantization residual per offset tile across the K orders.
+    Returns a `core.chebyshev.LocalMatvec`.  Under
+    ``exchange_dtype="int8"`` with error feedback it follows the
+    dual-signature stateful protocol of `core.chebyshev` (``mv(x, state)
+    -> (y, state)``, its ``init_state``), threading one quantization
+    residual per offset tile across the K orders.
 
     With an *active* ``fault_spec`` (see `repro.dist.faults`) the state
     additionally carries the round counter and one last-delivered tile
@@ -952,28 +949,27 @@ def make_exchange_matvec(interior, sends, couplings, axis: str, size: int,
                     vals.astype(x.dtype) * jnp.take(rv, cols, axis=-1))
         return y, new_state
 
-    def mv(x, state=None):
-        if state is None:
-            if inj is not None:
-                return _run(x, mv.init_state(x))[0]
-            return _run(x, None)[0]
-        return _run(x, state)
-
     if inj is not None:
         def init_state(x):
             tiles = tuple(jnp.take(x, idx, axis=-1) for idx, _ in sends)
             ef0 = (tuple(quantize.ef_init(t) for t in tiles)
                    if use_ef else None)
             return (inj.init_round(), inj.init_carried(tiles), ef0)
-
-        mv.init_state = init_state
     elif use_ef:
         def init_state(x):
             return tuple(quantize.ef_init(jnp.take(x, idx, axis=-1))
                          for idx, _ in sends)
+    else:
+        init_state = None
 
-        mv.init_state = init_state
-    return mv
+    def mv(x, state=None):
+        if state is None:
+            if inj is not None:
+                return _run(x, init_state(x))[0]
+            return _run(x, None)[0]
+        return _run(x, state)
+
+    return cheb.LocalMatvec(mv, init_state=init_state)
 
 
 # ---------------------------------------------------------------------------
@@ -1009,11 +1005,6 @@ def resolve_partition_arg(op, partition, n_shards: int,
     return None
 
 
-def _sharded(fn, mesh, in_specs, out_specs):
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)
-
-
 def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
                        interior: str = "block_ell",
                        use_pallas: Optional[bool] = None,
@@ -1030,20 +1021,21 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
     Block-ELL domain) or "dense" (the `halo` backend's per-shard dense
     diagonal einsum, small-n only).  Everything else — signatures, the
     exchange codec, the Section-V `matvec_runner` substrate, the fused
-    in-shard_map lasso — matches the banded builders; signals are permuted
-    into partition order on entry and back on exit, so callers never see
-    the relabeling (solver state like Jacobi's 1/diag travels as signals
-    and is permuted consistently).
+    lasso — is the plan body's (`repro.dist.operator.plan_from_form`);
+    signals are permuted into partition order on entry and back on exit,
+    so callers never see the relabeling (solver state like Jacobi's
+    1/diag travels as signals and is permuted consistently).
 
     On several shards, signals enter and leave in vertex order sharded
     over `axis`.  Where S divides n, :func:`sharded_reorder` moves them to
     partition order and back inside the shard_map (counted once per trace
     as ``reorder.sharded``); otherwise the whole signal is gathered
     (``reorder.gather``).  The structure is laid out on the mesh and is
-    the plan's `structure`: compiled entries take it as arguments.
+    the plan's `structure`: compiled entries take it as arguments.  On
+    one shard the structure is closed over and the matvec, with no
+    exchange, carries its Block-ELL for the single-launch sweep.
     """
-    from .operator import ExecutionPlan
-    from ..core.lasso import LassoResult, _mu_threshold
+    from .operator import LocalForm, plan_from_form
 
     quantize.validate_exchange_dtype(exchange_dtype)
     faults.validate_degradation(degradation)
@@ -1052,7 +1044,6 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
         raise ValueError(f"unknown interior {interior!r}")
     S, n, nl = parts.n_shards, parts.n, parts.n_local
     dl = parts.n_local_padded if interior == "block_ell" else nl
-    coeffs, lmax = op.coeffs, op.lmax
     offsets = parts.offsets
     n_off = len(offsets)
 
@@ -1069,9 +1060,11 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
         ex_mats += [parts.send_idx[k], parts.cpl_rows[k],
                     parts.cpl_cols[k], parts.cpl_vals[k]]
     mats = base_mats + tuple(ex_mats)
+    nmats = len(mats)
 
-    def _mk_mv(local_mats, size):
-        base, ex = local_mats[:nbase], local_mats[nbase:]
+    def local(mine):
+        """A shard's matvec from its structure (reorder arrays after)."""
+        base, ex = mine[:nbase], mine[nbase:nmats]
         if interior == "block_ell":
             local_A = graphmod.BlockELL(blocks=base[0], indices=base[1],
                                         mask=base[2], n=nl, band=band)
@@ -1079,7 +1072,6 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             def interior_mv(x):
                 return ops.spmv(local_A, x, use_pallas=use_pallas)
         else:
-            local_A = None
             dg = base[0]
 
             def interior_mv(x):
@@ -1088,15 +1080,15 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
         sends = tuple((ex[4 * k], offsets[k]) for k in range(n_off))
         coupl = tuple((ex[4 * k + 1], ex[4 * k + 2], ex[4 * k + 3])
                       for k in range(n_off))
-        mv = make_exchange_matvec(interior_mv, sends, coupl, axis, size,
+        mv = make_exchange_matvec(interior_mv, sends, coupl, axis, S,
                                   exchange_dtype, error_feedback,
                                   fault_spec, degradation)
-        if size == 1 and interior == "block_ell":
-            # no exchange on a 1-shard mesh: tag for the single-launch
-            # sweep kernel, exactly like the banded 1-shard path
-            mv.block_ell = local_A
-            mv.vmem_budget = vmem_budget
-            mv.sweep_dtype = sweep_dtype
+        if S == 1 and interior == "block_ell":
+            # no exchange on a 1-shard mesh: the single-launch sweep
+            # kernel may run, exactly like the banded 1-shard path
+            mv = dataclasses.replace(mv, block_ell=local_A,
+                                     vmem_budget=vmem_budget,
+                                     sweep_dtype=sweep_dtype)
         return mv
 
     info = {
@@ -1135,81 +1127,24 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
                 scratch_dtype=sweep_dtype),
         })
 
-    def _pin(x):
-        """Vertex order -> partition order, padded to the global S*nl."""
-        return ops.pad_trailing(
-            parts.to_partition_order(jnp.asarray(x)), S * nl)
+    def pad(x, mine):
+        return ops.pad_trailing(x, dl)
 
-    def _pout(y):
-        """Partition order (padded) -> vertex order (logical n)."""
-        return parts.from_partition_order(ops.crop(y, n))
-
+    recurrence = functools.partial(ops.fused_cheb_recurrence,
+                                   use_pallas=use_pallas)
     if S == 1:
-        mv = _mk_mv(tuple(m[0] for m in mats), 1)
+        form = LocalForm(local=local, recurrence=recurrence, arrays=mats,
+                         glob_in=parts.to_partition_order, enter=pad,
+                         leave=lambda y, mine: ops.crop(y, n),
+                         glob_out=parts.from_partition_order)
+        return plan_from_form(op, backend_name, form, info)
 
-        def _pad1(x):
-            return ops.pad_trailing(parts.to_partition_order(
-                jnp.asarray(x)), dl)
-
-        def apply(f: Array) -> Array:
-            c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
-            out = ops.fused_cheb_recurrence(mv, _pad1(f), c2, lmax,
-                                            use_pallas=use_pallas)
-            return _pout(out)
-
-        def apply_adjoint(a: Array) -> Array:
-            c = jnp.asarray(coeffs, a.dtype)
-            return _pout(cheb.cheb_apply_adjoint(mv, _pad1(a), c, lmax))
-
-        def apply_gram(f: Array) -> Array:
-            d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
-            out = ops.fused_cheb_recurrence(mv, _pad1(f), d, lmax,
-                                            use_pallas=use_pallas)
-            return _pout(out[..., 0, :])
-
-        def solve_lasso(y, mu, gamma, n_iters):
-            c = jnp.asarray(coeffs, y.dtype)
-            thresh = _mu_threshold(mu, op.eta, y.dtype, gamma)
-            phi_y = ops.fused_cheb_recurrence(mv, _pad1(y), c, lmax,
-                                              use_pallas=use_pallas)
-
-            def body(a, _):
-                back = cheb.cheb_apply_adjoint(mv, a, c, lmax)
-                gram_a = ops.fused_cheb_recurrence(mv, back, c, lmax,
-                                                   use_pallas=use_pallas)
-                a_new = soft_threshold(a + gamma * (phi_y - gram_a), thresh)
-                return a_new, None
-
-            a_star, _ = jax.lax.scan(body, jnp.zeros_like(phi_y), None,
-                                     length=n_iters)
-            y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
-            return LassoResult(coeffs=_pout(a_star), signal=_pout(y_star),
-                               objective=jnp.nan, n_iters=n_iters,
-                               fused=True)
-
-        def matvec_runner(fn, signals, consts=()):
-            padded = tuple(_pad1(s) for s in signals)
-            outs = fn(mv, *padded, *consts)
-            return jax.tree.map(_pout, outs)
-
-        return ExecutionPlan(op=op, backend=backend_name, apply=apply,
-                             apply_adjoint=apply_adjoint,
-                             apply_gram=apply_gram,
-                             solve_lasso_fn=solve_lasso,
-                             matvec_runner=matvec_runner, info=info)
-
-    rules = (make_rules(mesh) if axis == "graph"
-             else ShardingRules(mapping={"vertex": axis}, mesh=mesh))
     # the structure lives sharded on the mesh; every entry reads it as
     # its first argument, so compiled programs take it as arguments
     rd = parts.reorder
-    sharding = NamedSharding(mesh, rules.spec("vertex"))
+    sharding = NamedSharding(mesh, P(axis))
     structure = tuple(jax.device_put(m, sharding)
                       for m in mats + (rd.arrays if rd is not None else ()))
-    nmats, ns = len(mats), len(structure)
-    struct_specs = (rules.spec("vertex"),) * ns
-    r_off = rd.offsets if rd is not None else ()
-    nr = len(r_off)
     if rd is not None:
         info.update({
             "reorder": "sharded",
@@ -1218,150 +1153,30 @@ def build_general_plan(op, parts: GeneralPartition, mesh, axis: str, *,
             "reorder_bytes_per_apply":
                 4 * (1 + op.eta) * sum(rd.tile_widths),
         })
-        glob_in, glob_out = jnp.asarray, (lambda y: y)
+        r_off, nr = rd.offsets, len(rd.offsets)
+
+        def enter(x, mine):
+            """Vertex order -> partition order (padded to dl), on a shard."""
+            rl = mine[nmats:]
+            x = sharded_reorder(x, rl[:nr], rl[2 * nr], r_off, axis, S)
+            return ops.pad_trailing(x, dl)
+
+        def leave(y, mine):
+            """Partition order -> vertex order (logical nl), on a shard."""
+            rl = mine[nmats:]
+            return sharded_reorder(ops.crop(y, nl), rl[nr:2 * nr],
+                                   rl[2 * nr + 1], r_off, axis, S,
+                                   back=True)
+
+        places = dict(enter=enter, leave=leave, count="reorder.sharded")
     else:
         info["reorder"] = "gather"
-        glob_in, glob_out = _pin, _pout
-
-    def _count():
-        obs.count("reorder.sharded" if rd is not None else "reorder.gather")
-
-    def _local(args):
-        """A shard's matvec and reorder arrays from its structure."""
-        mine = tuple(a[0] for a in args)
-        return _mk_mv(mine[:nmats], S), mine[nmats:]
-
-    def _enter(x, rl):
-        """Vertex order -> partition order (padded to dl), on a shard."""
-        if rd is not None:
-            x = sharded_reorder(x, rl[:nr], rl[2 * nr], r_off, axis, S)
-        return ops.pad_trailing(x, dl)
-
-    def _leave(y, rl):
-        """Partition order -> vertex order (logical nl), on a shard."""
-        y = ops.crop(y, nl)
-        if rd is not None:
-            y = sharded_reorder(y, rl[nr:2 * nr], rl[2 * nr + 1], r_off,
-                                axis, S, back=True)
-        return y
-
-    def _sig_spec(ndim: int) -> P:
-        return rules.spec(*([None] * (ndim - 1)), "vertex")
-
-    def apply(structure, f: Array) -> Array:
-        _count()
-
-        def run(*args):
-            mv, rl = _local(args[:ns])
-            xl, c = args[ns:]
-            out = ops.fused_cheb_recurrence(mv, _enter(xl, rl), c, lmax,
-                                            use_pallas=use_pallas)
-            return _leave(out, rl)
-
-        c2 = jnp.atleast_2d(jnp.asarray(coeffs, f.dtype))
-        out = _sharded(run, mesh, struct_specs + (_sig_spec(f.ndim), P()),
-                       _sig_spec(f.ndim + 1))(*structure, glob_in(f), c2)
-        return glob_out(out)
-
-    def apply_adjoint(structure, a: Array) -> Array:
-        _count()
-
-        def run(*args):
-            mv, rl = _local(args[:ns])
-            al, c = args[ns:]
-            out = cheb.cheb_apply_adjoint(mv, _enter(al, rl), c, lmax)
-            return _leave(out, rl)
-
-        c = jnp.asarray(coeffs, a.dtype)
-        out = _sharded(run, mesh, struct_specs + (_sig_spec(a.ndim), P()),
-                       _sig_spec(a.ndim - 1))(*structure, glob_in(a), c)
-        return glob_out(out)
-
-    def apply_gram(structure, f: Array) -> Array:
-        _count()
-
-        def run(*args):
-            mv, rl = _local(args[:ns])
-            xl, d = args[ns:]
-            out = ops.fused_cheb_recurrence(mv, _enter(xl, rl), d, lmax,
-                                            use_pallas=use_pallas)
-            return _leave(out[..., 0, :], rl)
-
-        d = jnp.asarray(cheb.gram_coeffs(coeffs), f.dtype)[None]
-        out = _sharded(run, mesh, struct_specs + (_sig_spec(f.ndim), P()),
-                       _sig_spec(f.ndim))(*structure, glob_in(f), d)
-        return glob_out(out)
-
-    def solve_lasso(structure, y, mu, gamma, n_iters):
-        _count()
-
-        def run(*args):
-            mv, rl = _local(args[:ns])
-            yl, c, thresh = args[ns:]
-            phi_y = ops.fused_cheb_recurrence(mv, _enter(yl, rl), c, lmax,
-                                              use_pallas=use_pallas)
-
-            def body(a, _):
-                back = cheb.cheb_apply_adjoint(mv, a, c, lmax)
-                gram_a = ops.fused_cheb_recurrence(mv, back, c, lmax,
-                                                   use_pallas=use_pallas)
-                a_new = soft_threshold(a + gamma * (phi_y - gram_a), thresh)
-                return a_new, None
-
-            a0 = jnp.zeros_like(phi_y)
-            a_star, _ = jax.lax.scan(body, a0, None, length=n_iters)
-            y_star = cheb.cheb_apply_adjoint(mv, a_star, c, lmax)
-            return _leave(a_star, rl), _leave(y_star, rl)
-
-        c = jnp.asarray(coeffs, y.dtype)
-        thresh = _mu_threshold(mu, op.eta, y.dtype, gamma)
-        a_star, y_star = _sharded(
-            run, mesh, struct_specs + (_sig_spec(y.ndim), P(), P()),
-            (_sig_spec(y.ndim + 1), _sig_spec(y.ndim)),
-        )(*structure, glob_in(y), c, thresh)
-        return LassoResult(coeffs=glob_out(a_star), signal=glob_out(y_star),
-                           objective=jnp.nan, n_iters=n_iters, fused=True)
-
-    def matvec_runner(structure, fn, signals, consts=()):
-        # Section-V solver substrate under the general partition: signals
-        # (incl. vertex-indexed solver state such as Jacobi's 1/diag) are
-        # moved into partition order and padded on each shard; outputs
-        # crop and move back — so solver bodies are partition-agnostic.
-        _count()
-        pinned = tuple(glob_in(s) for s in signals)
-        local = tuple(
-            jax.ShapeDtypeStruct(s.shape[:-1] + (dl,), s.dtype)
-            for s in pinned)
-        out_sds = jax.eval_shape(
-            lambda *a: jax.tree.map(
-                lambda o: o[..., :nl], fn(lambda v: v, *a)),
-            *local, *consts)
-        in_specs = (struct_specs
-                    + tuple(_sig_spec(s.ndim) for s in pinned)
-                    + tuple(P() for _ in consts))
-        out_specs = jax.tree.map(lambda sd: _sig_spec(len(sd.shape)),
-                                 out_sds)
-
-        def run(*args):
-            mv, rl = _local(args[:ns])
-            rest = args[ns:]
-            sigs = tuple(_enter(s, rl) for s in rest[:len(pinned)])
-            outs = fn(mv, *sigs, *rest[len(pinned):])
-            return jax.tree.map(lambda o: _leave(o, rl), outs)
-
-        outs = _sharded(run, mesh, in_specs, out_specs)(
-            *structure, *pinned, *consts)
-        return jax.tree.map(glob_out, outs)
-
-    def over(structure):
-        def bind(fn):
-            return functools.partial(fn, structure)
-
-        return ExecutionPlan(op=op, backend=backend_name, apply=bind(apply),
-                             apply_adjoint=bind(apply_adjoint),
-                             apply_gram=bind(apply_gram),
-                             solve_lasso_fn=bind(solve_lasso),
-                             matvec_runner=bind(matvec_runner), info=info,
-                             structure=structure, over=over)
-
-    return over(structure)
+        places = dict(
+            glob_in=lambda x: ops.pad_trailing(parts.to_partition_order(x),
+                                               S * nl),
+            enter=pad, leave=lambda y, mine: ops.crop(y, nl),
+            glob_out=lambda y: parts.from_partition_order(ops.crop(y, n)),
+            count="reorder.gather")
+    form = LocalForm(local=local, recurrence=recurrence, arrays=structure,
+                     structure=True, mesh=mesh, axis=axis, **places)
+    return plan_from_form(op, backend_name, form, info)

@@ -1,8 +1,8 @@
 """Logical-axis sharding rules: one mapping from logical tensor axes to mesh
-axes, consumed everywhere (models, launch, dist backends).
+axes, consumed by the LM substrate (models, launch).
 
 A :class:`ShardingRules` turns logical axis names ("batch", "embed",
-"vertex", ...) into :class:`~jax.sharding.PartitionSpec` entries against a
+...) into :class:`~jax.sharding.PartitionSpec` entries against a
 concrete mesh.  The mapping is scheme-based: ``_BASE`` holds the
 tensor-parallel default and ``_SCHEMES`` holds named overrides (fsdp, ...).
 Rules are pure metadata — constructing them never touches device state, and
@@ -15,12 +15,10 @@ Usage::
     w_spec = rules.spec("embed", "ffn")        # PartitionSpec for a weight
     x = rules.constrain(x, "batch", None, "embed")   # sharding constraint
 
-Two consumer families share this vocabulary: the LM substrate (models /
-launch, axes like "batch"/"embed"/"heads") and the sharded graph backend
-`repro.dist.backends.pallas_halo`, which resolves the "vertex" axis — one
-contiguous block of graph vertices per device — through `make_rules` for
-the conventional 1-D "graph" mesh (and builds a local override for meshes
-whose axis is named differently).
+The sharded graph plans do not use it: their one plan body
+(`repro.dist.operator.plan_from_form`) splits every per-shard array on its
+leading axis and every signal on its vertex (last) axis over the mesh's
+1-D axis.  :func:`auto_mesh` below is theirs.
 """
 from __future__ import annotations
 
@@ -37,9 +35,6 @@ AxisTarget = Union[str, Tuple[str, ...], None]
 # (megatron-style) defaults: batch over the data axes, weight matrices
 # column/row split over 'model', everything else replicated.
 _BASE: Dict[str, AxisTarget] = {
-    # graph signals (dist backends: one contiguous vertex block per device
-    # on the 1-D "graph" mesh; see repro.dist.backends.halo / pallas_halo)
-    "vertex": "graph",
     # activations
     "batch": ("pod", "data"),
     "seq": None,

@@ -41,8 +41,8 @@ boundary-only exchanges of one matvec — measured, not assumed, by
 (out-of-tree registrations) fall back to the single-device reference
 matvec, logged at INFO.
 
-Single-launch fast path: a runner matvec tagged with ``mv.block_ell``
-(a purely local Block-ELL product — `pallas`; `pallas_halo` on one
+Single-launch fast path: a runner matvec record with its ``block_ell``
+set (a purely local Block-ELL product — `pallas`; `pallas_halo` on one
 shard) collapses a whole Jacobi / accelerated-Jacobi solve into ONE
 `kernels.cheb_sweep.jacobi_sweep` kernel launch (the Chebyshev method
 rides the same upgrade inside `ops.fused_cheb_recurrence`), VMEM-guarded
@@ -144,21 +144,15 @@ def poly_matvec(mv, coeffs: Tuple[float, ...], x: Array) -> Array:
 
 
 def _poly_matvec_protocol(mv, coeffs: Tuple[float, ...]):
-    """:func:`poly_matvec` as a stateful-protocol matvec.
+    """:func:`poly_matvec` as a matvec record of the same protocol.
 
-    When `mv` carries the dual-signature error-feedback protocol
-    (``mv.init_state``; see `repro.core.chebyshev._stateful_matvec`), the
-    returned ``p(P)``-matvec forwards it so the iteration loops can thread
-    the quantizer residual through every Horner step.  Plain matvecs come
-    back as the plain closure.
+    When the record `mv` carries the dual-signature error-feedback
+    protocol (its ``init_state``; see
+    `repro.core.chebyshev._stateful_matvec`), the returned
+    ``p(P)``-matvec forwards it so the iteration loops can thread the
+    quantizer residual through every Horner step.
     """
-    init = getattr(mv, "init_state", None)
-    if init is None:
-        def pmv(x):
-            return poly_matvec(mv, coeffs, x)
-        return pmv
-
-    def pmv2(x, state=None):
+    def pmv(x, state=None):
         if state is None:
             return poly_matvec(mv, coeffs, x)
         acc = coeffs[-1] * x
@@ -167,8 +161,7 @@ def _poly_matvec_protocol(mv, coeffs: Tuple[float, ...]):
             acc = h + c * x
         return acc, state
 
-    pmv2.init_state = init
-    return pmv2
+    return cheb.LocalMatvec(pmv, init_state=mv.init_state)
 
 
 def _poly_diag(P_dense: np.ndarray, coeffs: Sequence[float]) -> np.ndarray:
@@ -225,35 +218,12 @@ def _estimate_rho(op, den: Tuple[float, ...], inv_d: np.ndarray,
 
 
 def _fallback_runner(plan):
-    mv = plan.op.matvec
+    mv = cheb.LocalMatvec(plan.op.matvec)
 
     def runner(fn, signals, consts=()):
         return fn(mv, *signals, *consts)
 
     return runner
-
-
-def _with_budget(mv, vmem_budget):
-    """Re-tag a runner matvec with a per-solve sweep VMEM budget.
-
-    The single-launch paths read the ``mv.block_ell`` / ``mv.vmem_budget``
-    tags (see `kernels.ops.fused_cheb_recurrence`); a per-call
-    ``vmem_budget=`` must reach them *without* mutating the backend's
-    shared matvec object — other plans and cached solves read the same
-    tags — so wrap the callable and stamp the override on the wrapper.
-    No-op for untagged matvecs: the budget only governs the Block-ELL
-    sweep launch.
-    """
-    if vmem_budget is None or getattr(mv, "block_ell", None) is None:
-        return mv
-
-    def wrapped(x):
-        return mv(x)
-
-    wrapped.block_ell = mv.block_ell
-    wrapped.vmem_budget = int(vmem_budget)
-    wrapped.sweep_dtype = getattr(mv, "sweep_dtype", None)
-    return wrapped
 
 
 def _op_solver_cache(op) -> Dict[Any, Any]:
@@ -350,6 +320,17 @@ def solve_plan(
             plan.backend)
         runner = _fallback_runner(plan)
 
+    if vmem_budget is not None:
+        # the per-call sweep budget rides on the runner's matvec record
+        plain = runner
+
+        def runner(fn, signals, consts=()):
+            def budgeted(mv, *args):
+                return fn(dataclasses.replace(mv, vmem_budget=int(
+                    vmem_budget)), *args)
+
+            return plain(budgeted, signals, consts)
+
     y = jnp.asarray(y)
     info: Dict[str, Any] = {"num": num, "den": den}
     check_every = int(check_every)
@@ -358,10 +339,9 @@ def solve_plan(
 
     if method == "chebyshev":
         res = _solve_chebyshev(plan, runner, y, num, den, K, history,
-                               use_pallas, vmem_budget, info)
+                               use_pallas, info)
         if check_every > 0:
-            _post_solve_check(res, runner, y, num, den, use_pallas,
-                              vmem_budget, check_every)
+            _post_solve_check(res, runner, y, num, den, check_every)
         return res
     if den is None and not (method == "arma" and poles is not None):
         raise ValueError(
@@ -373,18 +353,16 @@ def solve_plan(
                 if method == "arma" else ""))
     if method == "jacobi" and check_every > 0 and not history:
         return _solve_jacobi_guarded(plan, runner, y, num, den, K, rho,
-                                     den_diag, x0, use_pallas, vmem_budget,
-                                     check_every, info)
+                                     den_diag, x0, use_pallas, check_every,
+                                     info)
     if method in ("jacobi", "cheb_jacobi"):
         res = _solve_jacobi(plan, runner, y, num, den, K, method, rho,
-                            den_diag, x0, history, use_pallas, vmem_budget,
-                            info)
+                            den_diag, x0, history, use_pallas, info)
     else:
         res = _solve_arma(plan, runner, y, num, den, K, poles, residues,
                           const, x0, history, info)
     if check_every > 0:
-        _post_solve_check(res, runner, y, num, den, use_pallas, vmem_budget,
-                          check_every)
+        _post_solve_check(res, runner, y, num, den, check_every)
     return res
 
 
@@ -398,14 +376,13 @@ def solve_plan(
 _DIVERGENCE_FACTOR = 2.0
 
 
-def _solve_residual(runner, y, x, num, den, use_pallas, vmem_budget):
+def _solve_residual(runner, y, x, num, den):
     """Relative residual ||num(P) y - den(P) x|| / ||num(P) y|| evaluated
     through the plan's own matvec (the fault-injected one, if any) — the
     number a real deployment could actually measure.  Costs
     deg(num) + deg(den) exchange rounds; callers account for them."""
 
     def fn(mv, yl, xl):
-        mv = _with_budget(mv, vmem_budget)
         return poly_matvec(mv, num, yl), poly_matvec(mv, den, xl)
 
     b, ax = runner(fn, (y, x))
@@ -414,16 +391,14 @@ def _solve_residual(runner, y, x, num, den, use_pallas, vmem_budget):
     return rn / max(bn, 1e-30)
 
 
-def _post_solve_check(res, runner, y, num, den, use_pallas, vmem_budget,
-                      check_every):
+def _post_solve_check(res, runner, y, num, den, check_every):
     """Single residual/NaN check after a completed solve (methods whose
     trajectory cannot restart mid-run: chebyshev, cheb_jacobi, arma, and
     any history-recording run).  Mutates ``res.info`` in place."""
     finite = bool(jnp.all(jnp.isfinite(res.x)))
     residual = None
     if den is not None:
-        residual = _solve_residual(runner, y, res.x, num, den, use_pallas,
-                                   vmem_budget)
+        residual = _solve_residual(runner, y, res.x, num, den)
         res.info["exchange_rounds"] = (
             res.info.get("exchange_rounds", 0)
             + (len(num) - 1) + (len(den) - 1))
@@ -436,7 +411,7 @@ def _post_solve_check(res, runner, y, num, den, use_pallas, vmem_budget,
 
 
 def _solve_jacobi_guarded(plan, runner, y, num, den, K, rho, den_diag, x0,
-                          use_pallas, vmem_budget, check_every, info):
+                          use_pallas, check_every, info):
     """Plain Jacobi in chunks of `check_every` rounds with a residual/NaN
     check between chunks and early exit on divergence.
 
@@ -460,13 +435,11 @@ def _solve_jacobi_guarded(plan, runner, y, num, den, K, rho, den_diag, x0,
     while done < K:
         iters = min(check_every, K - done)
         sub = _solve_jacobi(plan, runner, y, num, den, iters, "jacobi",
-                            rho, den_diag, x, False, use_pallas,
-                            vmem_budget, dict(info))
+                            rho, den_diag, x, False, use_pallas, dict(info))
         x = sub.x
         done += iters
         rounds += iters * deg_den + deg_num
-        res = _solve_residual(runner, y, x, num, den, use_pallas,
-                              vmem_budget)
+        res = _solve_residual(runner, y, x, num, den)
         rounds += deg_den + deg_num
         residuals.append(res)
         if not np.isfinite(res) or res > _DIVERGENCE_FACTOR * max(best, 1.0):
@@ -507,7 +480,7 @@ def _cheb_partial_sums(mv, x, c, alpha):
 
 
 def _solve_chebyshev(plan, runner, y, num, den, K, history, use_pallas,
-                     vmem_budget, info):
+                     info):
     """Section-IV truncated Chebyshev approximation of g at order K."""
     from ..kernels import ops as kops
 
@@ -528,7 +501,6 @@ def _solve_chebyshev(plan, runner, y, num, den, K, history, use_pallas,
     alpha = lmax / 2.0
 
     def fn(mv, yl, c):
-        mv = _with_budget(mv, vmem_budget)
         if history:
             x, hist = _cheb_partial_sums(mv, yl, c, alpha)
             return x, hist
@@ -547,7 +519,7 @@ def _solve_chebyshev(plan, runner, y, num, den, K, history, use_pallas,
 
 
 def _solve_jacobi(plan, runner, y, num, den, K, method, rho, den_diag, x0,
-                  history, use_pallas, vmem_budget, info):
+                  history, use_pallas, info):
     """Jacobi (Eq. (24)) / Chebyshev-accelerated Jacobi (Eq. (25)) on
     den(P) x = num(P) y; deg(den) matvecs per round, deg(num) once for the
     right-hand side."""
@@ -585,17 +557,15 @@ def _solve_jacobi(plan, runner, y, num, den, K, method, rho, den_diag, x0,
     def fn(mv, yl, inv_dl, *rest):
         from ..kernels import ops as kops
 
-        mv = _with_budget(mv, vmem_budget)
         x0l = rest[0] if rest else None
         b = poly_matvec(mv, num, yl)
-        # Single-launch upgrade: a matvec tagged with its local Block-ELL
+        # Single-launch upgrade: a matvec record with its local Block-ELL
         # structure (pallas backend; pallas_halo on a 1-shard mesh) runs
         # the whole Eq. (24)/(25) iteration — deg(den) in-kernel SpMVs +
         # the fused update per round — in ONE jacobi_sweep launch, the
         # weight schedule computed host-side.  History recording needs the
         # per-round iterates in HBM, so it stays on the per-round path.
-        A_local = getattr(mv, "block_ell", None)
-        if A_local is not None and not history:
+        if mv.block_ell is not None and not history:
             if K * deg_den > 256:
                 # the in-kernel round loop unrolls the Horner chain; past
                 # this many SpMVs the trace/compile cost outweighs the
@@ -610,10 +580,9 @@ def _solve_jacobi(plan, runner, y, num, den, K, method, rho, den_diag, x0,
                       if method == "cheb_jacobi"
                       else _jacobi.jacobi_weights(K))
                 return kops.fused_jacobi_sweep(
-                    A_local, b, inv_dl, den, ws, x0=x0l,
-                    use_pallas=use_pallas,
-                    vmem_budget=getattr(mv, "vmem_budget", None),
-                    scratch_dtype=getattr(mv, "sweep_dtype", None))
+                    mv.block_ell, b, inv_dl, den, ws, x0=x0l,
+                    use_pallas=use_pallas, vmem_budget=mv.vmem_budget,
+                    scratch_dtype=mv.sweep_dtype)
 
         a_mv = _poly_matvec_protocol(mv, den)
 

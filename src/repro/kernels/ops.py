@@ -27,8 +27,8 @@ tiles a ``cdiv`` grid and masks the ragged edge).
 
 Single-launch sweep dispatch: when the matvec is a *local* Block-ELL
 product (no collectives — the `pallas` backend always, `pallas_halo` on a
-1-shard mesh), the backend tags its matvec closure with ``mv.block_ell``
-and :func:`fused_cheb_recurrence` upgrades the whole K-order loop to the
+1-shard mesh), the backend's `core.chebyshev.LocalMatvec` record carries
+its `block_ell` and :func:`fused_cheb_recurrence` upgrades the whole K-order loop to the
 persistent `cheb_sweep` kernel: one launch, iterates pinned in VMEM
 across all orders.  The upgrade is guarded by the VMEM footprint model
 :func:`cheb_sweep_vmem_bytes` — oversized problems fall back to the
@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
+from ..core.chebyshev import LocalMatvec
 from ..core.graph import BlockELL
 from . import ref
 from .bcsr_spmv import (SMEM_INDEX_WORDS, block_ell_spmv_batched,
@@ -259,14 +260,14 @@ def fused_cheb_recurrence(
     `pallas_halo` backend passes a halo-exchanging matvec and runs this
     whole function inside a shard_map, where `x` is the per-shard block.
 
-    Single-launch upgrade: a matvec tagged with ``mv.block_ell = A`` (a
-    purely local Block-ELL product, no collectives) routes the whole loop
-    to :func:`fused_cheb_sweep` — one kernel launch for all K orders,
-    VMEM-guarded with a per-order fallback.  The `pallas` backend tags its
-    matvec always; `pallas_halo` only on a 1-shard mesh, where the halo
-    exchange is a no-op.  An optional ``mv.vmem_budget`` overrides the
-    sweep budget, and an optional ``mv.sweep_dtype`` ("bf16") selects the
-    mixed-precision scratch mode of `cheb_sweep`.
+    Single-launch upgrade: a `core.chebyshev.LocalMatvec` whose
+    `block_ell` is set (a purely local Block-ELL product, no collectives)
+    routes the whole loop to :func:`fused_cheb_sweep` — one kernel launch
+    for all K orders, VMEM-guarded with a per-order fallback.  The
+    `pallas` backend sets it always; `pallas_halo` only on a 1-shard
+    mesh, where the halo exchange is a no-op.  The record's `vmem_budget`
+    overrides the sweep budget, and its `sweep_dtype` ("bf16") selects
+    the mixed-precision scratch mode of `cheb_sweep`.
 
     x: (..., n) — any n (`cheb_step` masks its ragged edge tile), and
     leading batch dims take the batched tile paths (one
@@ -274,14 +275,13 @@ def fused_cheb_recurrence(
     coeffs: (eta, K+1) (or (K+1,), treated as eta=1).
     Returns (..., eta, n).
     """
-    A_local = getattr(matvec, "block_ell", None)
-    if A_local is not None:
+    if isinstance(matvec, LocalMatvec) and matvec.block_ell is not None:
+        A_local = matvec.block_ell
         n_logical = x.shape[-1]
         out = fused_cheb_sweep(
             A_local, pad_trailing(x, A_local.padded_n), coeffs, lmax,
-            use_pallas=use_pallas,
-            vmem_budget=getattr(matvec, "vmem_budget", None),
-            scratch_dtype=getattr(matvec, "sweep_dtype", None))
+            use_pallas=use_pallas, vmem_budget=matvec.vmem_budget,
+            scratch_dtype=matvec.sweep_dtype)
         return crop(out, n_logical)
     return _cheb_recurrence_loop(matvec, x, coeffs, lmax, use_pallas)
 
@@ -298,8 +298,9 @@ def _cheb_recurrence_loop(
 
     Supports the dual-signature stateful-matvec protocol of
     `core.chebyshev._stateful_matvec` (the int8 error-feedback halo
-    exchange): a matvec exposing ``init_state(x)`` threads its state
-    through the scan carry; plain matvecs get an empty-state shim.
+    exchange): a `core.chebyshev.LocalMatvec` with an ``init_state``
+    threads its state through the scan carry; plain matvecs get an
+    empty-state shim.
     """
     with obs.scope("recurrence"):
         return _recurrence(matvec, x, coeffs, lmax, use_pallas)

@@ -326,3 +326,75 @@ def test_general_partition_matches_dense_8shards():
     batch-invariant rounds, and halve wire bytes under bf16 exchange."""
     out = run_payload(GENERAL_PAYLOAD, n_devices=8)
     assert "GENERAL OK" in out
+
+
+# ---------------------------------------------------------------------------
+# The matvec a runner hands its body, and where the sweep may run
+# ---------------------------------------------------------------------------
+RUNNER_PAYLOAD = r"""
+import json
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import AxisType
+from repro.core.chebyshev import LocalMatvec
+from repro.core.wavelets import sgwt_multipliers
+from repro.dist import GraphOperator, available_backends
+
+n = 64
+L = (np.diag(np.r_[1.0, np.full(n - 2, 2.0), 1.0]) - np.eye(n, k=1)
+     - np.eye(n, k=-1)).astype(np.float32)
+op = GraphOperator(P=L, multipliers=sgwt_multipliers(4.0, 1), lmax=4.0, K=3)
+y = jnp.asarray(np.random.RandomState(0).randn(2, n), jnp.float32)
+out = {}
+for backend in available_backends():
+    for shards in (1, 2):
+        mesh = jax.make_mesh((shards,), ("graph",),
+                             devices=jax.devices()[:shards],
+                             axis_types=(AxisType.Auto,))
+        for part in (("banded", "general") if "halo" in backend else ("-",)):
+            kw = {"mesh": mesh}
+            if part != "-":
+                kw["partition"] = part
+            if "pallas" in backend:
+                kw.update(block=(8, 8), use_pallas=False)
+            plan = op.plan(backend, **kw)
+            seen = []
+
+            def body(mv, v):
+                seen.append(mv)
+                return mv(v)
+
+            got = plan.matvec_runner(body, (y,))
+            err = float(jnp.abs(got - y @ L.T).max())
+            out[f"{backend}/{shards}/{part}"] = {
+                "record": all(isinstance(mv, LocalMatvec) for mv in seen),
+                "block_ell": [mv.block_ell is not None for mv in seen],
+                "err": err}
+print("RUNNERS " + json.dumps(out))
+"""
+
+RUNNER_CASES = [(b, s, p) for b in available_backends() for s in (1, 2)
+                for p in (("banded", "general") if "halo" in b else ("-",))]
+
+
+@pytest.fixture(scope="module")
+def runner_records():
+    out = run_payload(RUNNER_PAYLOAD, n_devices=2)
+    line = [ln for ln in out.splitlines() if ln.startswith("RUNNERS ")][-1]
+    import json
+
+    return json.loads(line[len("RUNNERS "):])
+
+
+@pytest.mark.parametrize("backend,shards,partition", RUNNER_CASES)
+def test_runner_hands_a_record_with_the_sweep_where_local(
+        runner_records, backend, shards, partition):
+    """Every plan's `matvec_runner` hands its body a `LocalMatvec` that
+    applies P, and the record carries its Block-ELL — the licence for the
+    single-launch sweep kernels — exactly where the product is purely
+    local: `pallas` always, `pallas_halo` on one shard (banded and
+    general); never where the matvec exchanges or gathers."""
+    rec = runner_records[f"{backend}/{shards}/{partition}"]
+    assert rec["record"]
+    assert rec["err"] < 1e-5
+    local = backend == "pallas" or (backend == "pallas_halo" and shards == 1)
+    assert rec["block_ell"] and all(b == local for b in rec["block_ell"])

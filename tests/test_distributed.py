@@ -7,50 +7,47 @@ from _subproc import run_payload
 PAYLOAD = r"""
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import graph, multiplier, wavelets, lasso
-from repro.core import distributed as dist
+from repro.dist import GraphOperator
 
 key = jax.random.PRNGKey(1)
 g, key = graph.connected_sensor_graph(key, n=600, theta=0.07, kappa=0.07)
 gs, _ = graph.spatial_sort(g)
 L = gs.laplacian()
 lmax = gs.lambda_max_bound()
-parts, leak = dist.partition_banded(np.asarray(L), 8)
-assert leak == 0.0, leak
 mesh = jax.make_mesh((8,), ("graph",),
                      axis_types=(jax.sharding.AxisType.Auto,))
 y = jax.random.normal(key, (g.n_vertices,))
-ypad = dist.pad_signal(y, parts)
 mults = wavelets.sgwt_multipliers(lmax, J=3)
 uop = multiplier.UnionMultiplier(P=L, multipliers=mults, lmax=lmax, K=15)
-coeffs = uop.coeffs
+plan = GraphOperator(P=L, multipliers=mults, lmax=lmax,
+                     K=15).plan("halo", mesh=mesh)
+assert plan.info["partition_leak"] == 0.0, plan.info["partition_leak"]
 n = g.n_vertices
 
-out_d = dist.dist_cheb_apply(mesh, parts, ypad, coeffs, lmax)
+out_d = plan.apply(y)
 out_c = uop.apply(y)
 assert float(jnp.abs(out_d[:, :n] - out_c).max()) < 1e-4
 
 a = out_c
-apad = dist.pad_signal(a, parts)  # pads the trailing vertex axis
-adj_d = dist.dist_cheb_apply_adjoint(mesh, parts, apad, coeffs, lmax)
+adj_d = plan.apply_adjoint(a)
 assert float(jnp.abs(adj_d[:n] - uop.apply_adjoint(a)).max()) < 1e-4
 
-gram_d = dist.dist_cheb_apply_gram(mesh, parts, ypad, coeffs, lmax)
+gram_d = plan.apply_gram(y)
 assert float(jnp.abs(gram_d[:n] - uop.apply_gram(y)).max()) < 1e-4
 
 mu = jnp.array([0.01, 0.75, 0.75, 0.75])
 gamma = lasso.ista_step_size(uop)
-a_d, y_d = dist.dist_lasso(mesh, parts, ypad, coeffs, lmax, mu,
-                           gamma=gamma, n_iters=25)
+res_d = plan.solve_lasso(y, mu, gamma=gamma, n_iters=25)
+assert res_d.fused
 res_c = lasso.distributed_lasso(uop, y, mu=mu, gamma=gamma, n_iters=25)
-assert float(jnp.abs(y_d[:n] - res_c.signal).max()) < 1e-4
+assert float(jnp.abs(res_d.signal[:n] - res_c.signal).max()) < 1e-4
 
 # allgather fallback on an unsorted (non-banded) graph
 L2 = g.laplacian()
-n_pad = 8 * (-(-g.n_vertices // 8))
-L2p = jnp.asarray(np.pad(np.asarray(L2), ((0, n_pad - n), (0, n_pad - n))))
-y2 = jnp.pad(y, (0, n_pad - n))
 uop2 = multiplier.UnionMultiplier(P=L2, multipliers=mults, lmax=lmax, K=15)
-out_ag = dist.dist_cheb_apply_allgather(mesh, L2p, y2, uop2.coeffs, lmax)
+plan2 = GraphOperator(P=L2, multipliers=mults, lmax=lmax,
+                      K=15).plan("allgather", mesh=mesh)
+out_ag = plan2.apply(y)
 assert float(jnp.abs(out_ag[:, :n] - uop2.apply(y)).max()) < 1e-4
 print("DIST OK")
 """

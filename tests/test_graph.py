@@ -57,7 +57,7 @@ def test_block_ell_roundtrip_and_matvec(sensor120):
 
 
 def test_spatial_sort_banded_partition(sensor_banded):
-    from repro.core.distributed import partition_banded
+    from repro.dist.backends.halo import partition_banded
 
     L = np.asarray(sensor_banded.laplacian())
     parts, leak = partition_banded(L, 8)

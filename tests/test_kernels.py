@@ -209,8 +209,7 @@ def error_feedback_matvec(n):
         q = jnp.round(v * 64.0) / 64.0
         return mv(q), v - q
 
-    stateful.init_state = jnp.zeros_like
-    return stateful
+    return cheb.LocalMatvec(stateful, init_state=jnp.zeros_like)
 
 
 def assert_same_sum(got, want):

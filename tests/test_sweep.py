@@ -207,9 +207,10 @@ def test_vmem_footprint_model_bf16_and_measured_ratio(block_ell_500):
 
 
 def test_sweep_dtype_tag_survives_with_budget(op120):
-    """`solvers._with_budget` re-tags without dropping the sweep_dtype tag,
-    and the single-shard pallas_halo build stamps it on its matvec."""
-    from repro.dist import solvers as dsolv
+    """A per-solve VMEM budget (`dataclasses.replace` on the runner's
+    matvec record, as `plan.solve(vmem_budget=...)` does) keeps the
+    record's sweep_dtype and structure, and both single-device builds
+    put the sweep_dtype on their record."""
     g, op = op120
     plan = op.plan("pallas_halo", sweep_dtype="bf16")
     assert plan.info["sweep_dtype"] == "bf16"
@@ -220,22 +221,20 @@ def test_sweep_dtype_tag_survives_with_budget(op120):
     pplan = op.plan("pallas", use_pallas=False, sweep_dtype="bf16")
     assert pplan.info["sweep_dtype"] == "bf16"
     tag = pplan.matvec_runner(
-        lambda mv, v: v + (getattr(mv, "sweep_dtype", None) == "bf16"),
-        (jnp.zeros(3),))
+        lambda mv, v: v + (mv.sweep_dtype == "bf16"), (jnp.zeros(3),))
     assert float(tag[0]) == 1.0  # the solve path sees the bf16 tag
     assert pplan.info["sweep_vmem_bytes"] < op.plan(
         "pallas", use_pallas=False).info["sweep_vmem_bytes"]
 
-    def mv(v):
-        return v
-
-    mv.block_ell = object()
-    mv.vmem_budget = None
-    mv.sweep_dtype = "bf16"
-    wrapped = dsolv._with_budget(mv, 123456)
-    assert wrapped.vmem_budget == 123456
-    assert wrapped.sweep_dtype == "bf16"
-    assert wrapped.block_ell is mv.block_ell
+    for p in (plan, pplan):
+        seen = []
+        p.matvec_runner(lambda mv, v: seen.append(mv) or v, (jnp.zeros(3),))
+        mv = seen[-1]
+        assert mv.sweep_dtype == "bf16" and mv.block_ell is not None
+        budgeted = dataclasses.replace(mv, vmem_budget=123456)
+        assert budgeted.vmem_budget == 123456
+        assert budgeted.sweep_dtype == "bf16"
+        assert budgeted.block_ell is mv.block_ell
 
 
 # ---------------------------------------------------------------------------
